@@ -63,8 +63,8 @@ from . import incubate  # noqa: E402
 from . import sparse  # noqa: E402
 from . import device  # noqa: E402
 
-# persistent XLA compilation cache (FLAGS_compile_cache_dir / env
-# PADDLE_TPU_COMPILE_CACHE_DIR): applied once at import, before any
+# persistent XLA compilation cache (JAX_COMPILATION_CACHE_DIR where set,
+# else <checkout>/.jax_cache): applied once at import, before any
 # program compiles
 device.setup_compile_cache()
 from . import framework  # noqa: E402

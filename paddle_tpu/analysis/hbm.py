@@ -52,7 +52,7 @@ def _jaxpr_peak(jaxpr, donated_invars=frozenset(),
                 const_bytes: int = 0) -> Tuple[int, int]:
     """(peak_bytes, boundary_bytes) of one jaxpr. ``donated_invars`` are
     flat invar INDICES whose buffers may die at last use."""
-    from jax.core import Var
+    from jax.extend.core import Var
 
     last_use: Dict[object, int] = {}
     for i, eqn in enumerate(jaxpr.eqns):

@@ -20,7 +20,7 @@ def repo_root() -> str:
 
 
 def _jaxpr_types():
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     return ClosedJaxpr, Jaxpr
 
@@ -73,15 +73,12 @@ def aval_bytes(aval) -> int:
 def eqn_anchor(eqn) -> Tuple[Optional[str], Optional[int]]:
     """(path, line) of the user frame that built this equation —
     repo-relative when inside the repo — or (None, None)."""
-    try:
-        from jax._src import source_info_util
+    from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is None:
-            return None, None
-        path, line = frame.file_name, int(frame.start_line)
-    except Exception:
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
         return None, None
+    path, line = frame.file_name, int(frame.start_line)
     root = repo_root()
     if path.startswith(root + os.sep):
         path = os.path.relpath(path, root)

@@ -60,7 +60,7 @@ class ProgramSite:
 @dataclasses.dataclass
 class TracedProgram:
     site: ProgramSite
-    closed: object                    # jax.core.ClosedJaxpr
+    closed: object                    # jax.extend.core.ClosedJaxpr
     donated_invars: frozenset         # flat invar indices that may die
 
 

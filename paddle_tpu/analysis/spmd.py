@@ -51,8 +51,13 @@ _HLO_COLLECTIVE_RE = re.compile(
 
 #: jaxpr-level collective primitives (for the branch-symmetry check)
 _COLLECTIVE_PRIMS = ("psum", "pmax", "pmin", "ppermute", "pgather",
-                     "all_to_all", "all_gather", "reduce_scatter",
-                     "psum_scatter")
+                     "all_to_all", "all_gather", "reduce_scatter")
+
+#: what ``lax.psum`` / ``lax.all_gather`` trace to inside a shard_map
+#: that checks varying-ness (the default): same wire collective, so the
+#: census reports it under the plain name
+_VMA_ALIASES = {"psum_invariant": "psum",
+                "all_gather_invariant": "all_gather"}
 
 
 @dataclasses.dataclass
@@ -112,9 +117,10 @@ def _collective_seq(jaxpr) -> List[Tuple[str, str]]:
     order matters: it is the device's collective schedule."""
     seq: List[Tuple[str, str]] = []
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name in _COLLECTIVE_PRIMS:
+        prim = _VMA_ALIASES.get(eqn.primitive.name, eqn.primitive.name)
+        if prim in _COLLECTIVE_PRIMS:
             axes = eqn.params.get("axes", eqn.params.get("axis_name"))
-            seq.append((eqn.primitive.name, str(axes)))
+            seq.append((prim, str(axes)))
         for sj in sub_jaxprs(eqn):
             seq += _collective_seq(sj)
     return seq
@@ -134,7 +140,7 @@ def trace_census(fn, *args) -> List[Tuple[str, str]]:
 
 
 def _check_branch_symmetry(jaxpr, site, findings):
-    from jax.core import ClosedJaxpr
+    from jax.extend.core import ClosedJaxpr
 
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "cond":
@@ -267,11 +273,8 @@ def _build_ring_attention():
         ra._ring_attention_sharded, axis_name="sep", causal=True,
         scale=8.0 ** -0.5, axis_size=VIRTUAL_MESH_DEVICES)
     pspec = P(None, "sep", None, None)
-    kwargs = {}
-    if getattr(jax.lax, "pcast", None) is None:
-        kwargs["check_rep"] = False
-    fn = ra._shard_map()(body, mesh=mesh, in_specs=(pspec,) * 3,
-                         out_specs=pspec, **kwargs)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(pspec,) * 3,
+                       out_specs=pspec)
     q = jax.device_put(
         jnp.ones((1, 2 * VIRTUAL_MESH_DEVICES, 2, 8), jnp.float32),
         NamedSharding(mesh, pspec))

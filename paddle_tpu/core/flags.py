@@ -131,13 +131,6 @@ define_flag("decode_prefetch", True,
             "final grid phase, overlapping its weight DMA with layer "
             "l's FFN compute; off = a separate streamed QKV call per "
             "layer (2 streamed calls/layer instead of 1)")
-define_flag("compile_cache_dir", "",
-            "persistent XLA compilation-cache directory (also settable "
-            "via env PADDLE_TPU_COMPILE_CACHE_DIR): applied to "
-            "jax_compilation_cache_dir at import by "
-            "device.setup_compile_cache(), so recompiles of unchanged "
-            "programs (e.g. the 25-min s2048 flash-attention backward) "
-            "are served from disk across processes")
 define_flag("check_donation", False,
             "use-after-donate poison mode (paddle_tpu.analysis.donation): "
             "buffers donated by the compiled-forward fast path are "

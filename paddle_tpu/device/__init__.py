@@ -9,6 +9,8 @@ allocator bookkeeping.
 """
 from __future__ import annotations
 
+import os
+
 import jax
 
 from ..core.place import (  # noqa: F401
@@ -32,39 +34,53 @@ __all__ = [
 ]
 
 
-def setup_compile_cache(path=None):
-    """Wire the persistent XLA compilation cache.
+#: where the persistent compile cache lives when nothing outside says
+#: otherwise: one fixed path inside the checkout. The path is part of
+#: the cache key, so it is never a temp name, a pid or a timestamp.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-    ``path`` (or ``FLAGS_compile_cache_dir`` / env
-    ``PADDLE_TPU_COMPILE_CACHE_DIR`` when omitted) becomes jax's
-    ``jax_compilation_cache_dir``: compiled executables are written to
-    disk and re-loaded by later processes, so a warm run skips the
-    multi-minute XLA compiles the cold run paid (the s2048 rung's
-    flash-attention backward alone measured ~25 min cold, r5).
-    Called automatically at ``import paddle_tpu``; call again after
-    ``set_flags({"FLAGS_compile_cache_dir": ...})`` to re-point it.
-    Returns the applied path, or None when no path is configured.
-    The ``compile.persistent_cache`` gauge records whether a cache dir
-    is active, so bench telemetry shows which regime — cold or
-    cache-warm — a compile-seconds histogram was measured under."""
-    from ..core.flags import flag
+
+def _default_cache_dir(platforms):
+    """The in-code default directory for a run whose ``jax_platforms``
+    setting is ``platforms``: ``DEFAULT_COMPILE_CACHE_DIR``, except for
+    a run pinned to the CPU backend (tests, rehearsals) — XLA:CPU
+    reloads its cached AOT results with machine-feature errors
+    ("could lead to execution errors such as SIGILL", observed PR 24)
+    and a CPU compile is nothing a chip run can reuse."""
+    return None if platforms == "cpu" else DEFAULT_COMPILE_CACHE_DIR
+
+
+def setup_compile_cache():
+    """Turn on JAX's persistent compilation cache; returns its directory
+    (None where no cache is placed).
+
+    The directory is placed from OUTSIDE: where the standard
+    ``JAX_COMPILATION_CACHE_DIR`` variable is set, JAX already uses it
+    and nothing is set in code. Where it is not,
+    ``DEFAULT_COMPILE_CACHE_DIR`` (``<checkout>/.jax_cache``, listed in
+    ``.gitignore``) is used — see ``_default_cache_dir`` for the one
+    exception. Compiled executables are written to disk and re-loaded
+    by later processes, so a warm run skips the XLA compiles the cold
+    run paid. Called once at ``import paddle_tpu``. The
+    ``compile.persistent_cache`` gauge records whether a cache is
+    active, so a compile-seconds histogram says which regime — cold or
+    cache-warm — it was measured under."""
     from ..profiler import stats as _stats
 
-    path = path or flag("compile_cache_dir")
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
-        _stats.set_gauge("compile.persistent_cache", 0)
-        return None
-    jax.config.update("jax_compilation_cache_dir", str(path))
+        path = _default_cache_dir(jax.config.jax_platforms)
+        if path is None:
+            _stats.set_gauge("compile.persistent_cache", 0)
+            return None
+        jax.config.update("jax_compilation_cache_dir", path)
     # cache even fast-compiling programs: the decode/prefill serving
-    # programs are individually cheap but numerous, and CI correctness
-    # runs recompile them every process
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-    except AttributeError:  # older jax: flag absent — defaults apply
-        pass
+    # programs are individually cheap but numerous
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     _stats.set_gauge("compile.persistent_cache", 1)
-    return str(path)
+    return path
 
 
 def _resolve(device=None) -> jax.Device:

@@ -67,8 +67,7 @@ HBM_RESERVE_BYTES = 1 * GiB
 #: kernel's declared blocks/scratch consume
 VMEM_RESERVE_BYTES = 28 * MiB
 
-#: the serving generation the hand-tuned kernel geometry targets (the
-#: chip every BENCH_r* number was measured on)
+#: the serving generation the hand-tuned kernel geometry targets
 DEFAULT_GENERATION = "v5e"
 
 #: the vmem_limit_bytes every repo Pallas kernel declares: generation
@@ -82,49 +81,31 @@ KERNEL_VMEM_LIMIT_BYTES = (
 #: (xla_tpu_scoped_vmem_limit_kib = 16384)
 MOSAIC_DEFAULT_VMEM_LIMIT_BYTES = 16 * MiB
 
-#: jax device_kind strings -> generation keys (prefix match, checked
-#: longest-first so "v5 lite" beats "v5")
-_DEVICE_KIND_MAP = (
-    ("tpu v6 lite", "v6e"),
-    ("tpu v6e", "v6e"),
-    ("tpu v5 lite", "v5e"),
-    ("tpu v5e", "v5e"),
-    ("tpu v5p", "v5p"),
-    ("tpu v5", "v5p"),
-    ("tpu v4", "v4"),
-    ("tpu v3", "v3"),
-    ("tpu v2", "v2"),
-)
-
-
 def detect_generation(default: str = DEFAULT_GENERATION) -> str:
-    """TPU generation of the attached accelerator, or ``default`` when
-    running off-TPU (CPU CI analyses against the serving target)."""
-    try:
-        import jax
+    """TPU generation of the attached accelerator. Off-TPU the static
+    analyses validate against ``default``, the serving target they are
+    asked about (CPU CI lints for the chip it will deploy on); ON a TPU
+    the device must be in ``device.chip.CHIPS`` — an unknown
+    ``device_kind`` raises instead of being budgeted as another chip."""
+    import jax
 
-        if jax.default_backend() != "tpu":
-            return default
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
+    from .chip import chip_spec
+
+    if jax.default_backend() != "tpu":
         return default
-    for prefix, gen in _DEVICE_KIND_MAP:
-        if kind.startswith(prefix):
-            return gen
-    return default
+    return chip_spec(jax.devices()[0]).generation
 
 
 def vmem_budget_bytes(generation: str | None = None) -> int:
     """Physical VMEM budget for ``generation`` (auto-detected when
-    None). Unknown generations fall back to the conservative 16 MiB."""
+    None); a generation that is not in the table raises."""
     gen = generation or detect_generation()
-    return VMEM_BUDGET_BYTES.get(gen, 16 * MiB)
+    return VMEM_BUDGET_BYTES[gen]
 
 
 def hbm_budget_bytes(generation: str | None = None) -> int:
     """Usable HBM for ``generation`` (auto-detected when None): the
-    physical capacity minus the runtime reserve. Unknown generations
-    fall back to the conservative v5e 16 GiB."""
+    physical capacity minus the runtime reserve; a generation that is
+    not in the table raises."""
     gen = generation or detect_generation()
-    return (HBM_BUDGET_BYTES.get(gen, HBM_BUDGET_BYTES["v5e"])
-            - HBM_RESERVE_BYTES)
+    return HBM_BUDGET_BYTES[gen] - HBM_RESERVE_BYTES

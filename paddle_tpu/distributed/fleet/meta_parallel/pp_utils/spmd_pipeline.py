@@ -47,32 +47,13 @@ __all__ = ["pipeline_1f1b_grads", "pipeline_interleaved_1f1b_grads",
            "circular_pipeline_fwd", "ring_perm"]
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-               check_vma=None):
-    """shard_map across jax versions: jax >= 0.7 has ``jax.shard_map``
-    with ``axis_names``/``check_vma``; 0.4.x only has the experimental
-    home whose equivalents are ``auto`` (the complement of the manual
-    axes) and ``check_rep``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return sm(f, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as esm
-
-    kw = {}
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kw["auto"] = auto
-    if check_vma is not None:
-        kw["check_rep"] = bool(check_vma)
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               **kw)
+def _stable_vjp(stage_fn):
+    """``jax.vjp(stage_fn, ...)`` traced ONCE: the residual ring stores
+    the vjp closure's leaves tick after tick, so every tick must yield
+    the same leaf list. Separate ``jax.vjp`` traces do not promise that
+    (constants may or may not be hoisted into residuals); one jitted
+    wrapper is traced on its first call and reused from jit's cache."""
+    return jax.jit(lambda p, x: jax.vjp(stage_fn, p, x))
 
 
 def ring_perm(n: int, shift: int = 1):
@@ -127,6 +108,8 @@ def pipeline_1f1b_grads(
     R = 2 * Pn  # residual ring depth — the O(pp) 1F1B bound
     T = M + 2 * Pn - 1  # macro-ticks
 
+    stage_vjp = _stable_vjp(stage_fn)
+
     def body(stacked, head_p, h_all_, labels_):
         stage_p = jax.tree_util.tree_map(lambda a: a[0], stacked)
         s = jax.lax.axis_index(pp_axis)
@@ -154,7 +137,7 @@ def pipeline_1f1b_grads(
             f_valid = (mf >= 0) & (mf < M)
             mf_c = jnp.clip(mf, 0, M - 1)
             x_in = jnp.where(is_first, h_all_[min(t, M - 1)], x_recv)
-            y, vjp_f = jax.vjp(stage_fn, stage_p, x_in)
+            y, vjp_f = stage_vjp(stage_p, x_in)
             leaves, td = jax.tree_util.tree_flatten(vjp_f)
             if ring is None:
                 vjp_treedef = td
@@ -201,7 +184,7 @@ def pipeline_1f1b_grads(
             lambda a: a[None], d_stage), jax.tree_util.tree_map(
             lambda a: a[None], d_head), dh_all[None])
 
-    shard = _shard_map(
+    shard = jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: P(pp_axis),
                                          stacked_params), P(), P(), P()),
@@ -277,6 +260,8 @@ def pipeline_interleaved_1f1b_grads(
     T = (delta + g_max * L + (v - 1) * Pn
          + (M - 1 - g_max * Pn) + (Pn - 1) + 1)
 
+    stage_vjp = _stable_vjp(stage_fn)
+
     def body(stacked, head_p, h_all_, labels_):
         # local chunk params: [v, ...]; slot j = virtual stage j*P + s
         s = jax.lax.axis_index(pp_axis)
@@ -319,7 +304,7 @@ def pipeline_interleaved_1f1b_grads(
             x_src = jax.lax.dynamic_index_in_dim(h_all_, mf_c,
                                                  keepdims=False)
             x_in = jnp.where(is_first & (jf_c == 0), x_src, x_recv)
-            y, vjp_f = jax.vjp(stage_fn, chunk_p, x_in)
+            y, vjp_f = stage_vjp(chunk_p, x_in)
             leaves, td = jax.tree_util.tree_flatten(vjp_f)
             if ring is None:
                 vjp_treedef = td
@@ -375,7 +360,7 @@ def pipeline_interleaved_1f1b_grads(
         return (loss_acc[None], d_stacked, jax.tree_util.tree_map(
             lambda a: a[None], d_head), dh_all[None])
 
-    shard = _shard_map(
+    shard = jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: P(pp_axis),
                                          stacked_params), P(), P(), P()),
@@ -482,7 +467,7 @@ def circular_pipeline_fwd(
 
         return y_all[None]
 
-    shard = _shard_map(
+    shard = jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(
             lambda _: P(pp_axis), stacked_params), P()),

@@ -36,19 +36,9 @@ import dataclasses
 from typing import Any, Optional
 
 __all__ = ["split_kv_heads", "serving_mesh", "TPContext",
-           "shard_map_fn", "axis_extent", "ring_chunk_reduce",
+           "axis_extent", "ring_chunk_reduce",
            "ring_reduce", "reduce_over_axis", "ring_census",
            "resolve_overlap"]
-
-
-def shard_map_fn():
-    """shard_map across jax versions (jax >= 0.7 promotes it out of
-    experimental; 0.4.x only has the experimental home)."""
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm
 
 
 def split_kv_heads(num_kv_heads: int, mp: int):

@@ -202,11 +202,6 @@ class MoELayer(Layer):
 
         from jax.sharding import PartitionSpec as P
 
-        try:
-            from jax.experimental.shard_map import shard_map
-        except ImportError:  # jax >= 0.7 moved it
-            from jax import shard_map
-
         mesh, axis = self._ep_mesh
         jmesh = mesh.jax_mesh() if hasattr(mesh, "jax_mesh") else mesh
         ep = jmesh.shape[axis]
@@ -265,7 +260,7 @@ class MoELayer(Layer):
                         jax.lax.psum(drop, axis),
                         jax.lax.psum(cnt, axis))
 
-            y, aux, drop, cnt = shard_map(
+            y, aux, drop, cnt = jax.shard_map(
                 body, mesh=jmesh,
                 in_specs=(x_spec, P(), w_spec, w_spec, w_spec, w_spec),
                 out_specs=(x_spec, P(), P(), P()))(xa, wg, w1, b1, w2,

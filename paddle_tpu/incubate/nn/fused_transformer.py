@@ -423,7 +423,7 @@ class FusedMultiTransformer(Layer):
         exactly one psum) and ``ep_axis`` set when ep > 1 (each MoE
         layer contributes exactly the all_to_all dispatch/combine pair
         plus the replicated-hidden all_gather)."""
-        from ...distributed.tp import resolve_overlap, shard_map_fn
+        from ...distributed.tp import resolve_overlap
 
         overlap = resolve_overlap(overlap)
         if cache is None:
@@ -469,12 +469,12 @@ class FusedMultiTransformer(Layer):
                 w, xb, PagedKV(ck, cv), tbl, *extras, cos, sin, **kw)
             return h, cache2.k, cache2.v
 
-        fn = shard_map_fn()(
+        fn = jax.shard_map(
             body, mesh=tp.mesh,
             in_specs=(wspecs, rep, kv, kv, rep, rep, rep)
             + (rep,) * len(rep_args)
             + ((aspecs,) if adaptered else ()),
-            out_specs=(rep, kv, kv), check_rep=False)
+            out_specs=(rep, kv, kv), check_vma=False)
         h, nk, nv = fn(weights, x, cache.k, cache.v, tables,
                        cos_t, sin_t, *rep_args,
                        *((adapters,) if adaptered else ()))
@@ -738,9 +738,9 @@ class FusedMultiTransformer(Layer):
         # layer-independent: compute ONCE per decode step, share across
         # the 24-layer loop
         from ...core.flags import flag
+        from ...device import chip as _chip
         from ...nn.functional.paged_attention import (
-            _on_tpu, build_pool_ownership,
-            paged_decode_attention_inplace_q)
+            build_pool_ownership, paged_decode_attention_inplace_q)
 
         quantized_kv = isinstance(cache.k, tuple)
         fused_stream = False
@@ -752,7 +752,8 @@ class FusedMultiTransformer(Layer):
                 self._pool_page_size(cache))
         else:
             backend = flag("paged_attention_backend")
-            fused_stream = (backend in ("auto", "stream") and _on_tpu()
+            fused_stream = (backend in ("auto", "stream")
+                            and _chip.on_tpu()
                             and self.head_dim % 128 == 0)
             if fused_stream:
                 # fused append+attend kernel masks with seq_lens

@@ -224,9 +224,9 @@ class TrainStep:
 
         # first call = trace + XLA compile (+ run): record its wall
         # seconds so bench telemetry carries cold-vs-warm compile time
-        # — with FLAGS_compile_cache_dir set (persistent cache, see
-        # device.setup_compile_cache) a warm process's first call drops
-        # to executable-load time, and the histogram shows it
+        # — with the persistent cache warm (device.setup_compile_cache)
+        # a process's first call drops to executable-load time, and
+        # the histogram shows it
         first = not getattr(self, "_first_call_done", False)
         if first:
             import time as _time

@@ -57,9 +57,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ...device import chip as _chip
 from ...device.vmem import KERNEL_VMEM_LIMIT_BYTES
-from .paged_attention import (_enable_x64, _pltpu_compiler_params,
-                              _pltpu_memspace)
+from .paged_attention import _enable_x64
 
 __all__ = [
     "varlen_block_map", "flash_varlen_packed", "paged_prefill_attention",
@@ -68,21 +68,17 @@ __all__ = [
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
+#: every in-kernel dot pins its precision: the package-wide default
+#: (jax_default_matmul_precision="high") is one Mosaic refuses
+#: ("Unsupported dot precision: HIGH")
+_PREC = jax.lax.Precision.DEFAULT
 _NEG = -1e30          # python literal: jnp scalars would be captured consts
 _NEG_SAFE = -5e29     # lse clamp floor: exp(_NEG - _NEG_SAFE) underflows to 0
 
 
-@functools.lru_cache(maxsize=1)
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
 def _resolve_backend(backend: str) -> str:
     if backend == "auto":
-        return "pallas" if _on_tpu() else "xla"
+        return "pallas" if _chip.on_tpu() else "xla"
     if backend not in ("pallas", "interpret", "xla"):
         raise ValueError(
             f"flash_varlen backend={backend!r}: expected 'auto', "
@@ -296,6 +292,7 @@ def _packed_fwd_pallas(qt, kt, vt, bm: BlockMap, scale: float,
             vf = vbuf[slot].astype(jnp.float32)
             lg = jax.lax.dot_general(
                 qf, kf, (((2,), (2,)), ((0,), (0,))),
+                precision=_PREC,
                 preferred_element_type=jnp.float32)      # [h, bq, bk]
             interior = jnp.logical_and(
                 jnp.logical_and(uniform_q, kslo[j] == kshi[j]),
@@ -320,6 +317,7 @@ def _packed_fwd_pallas(qt, kt, vt, bm: BlockMap, scale: float,
             l = l * alpha + p.sum(-1)
             pv = jax.lax.dot_general(
                 p, vf, (((2,), (1,)), ((0,), (0,))),
+                precision=_PREC,
                 preferred_element_type=jnp.float32)      # [h, bq, d]
             acc = acc * alpha[..., None] + pv
             return pm, l, acc
@@ -337,9 +335,9 @@ def _packed_fwd_pallas(qt, kt, vt, bm: BlockMap, scale: float,
         in_specs=[
             pl.BlockSpec((2, bq), lambda i, *_: (0, i)),
             pl.BlockSpec((h, bq, d), lambda i, *_: (0, i, 0)),
-            pl.BlockSpec(memory_space=_pltpu_memspace(pltpu).ANY),
-            pl.BlockSpec(memory_space=_pltpu_memspace(pltpu).ANY),
-            pl.BlockSpec(memory_space=_pltpu_memspace(pltpu).ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
         out_specs=[
             pl.BlockSpec((h, bq, d), lambda i, *_: (0, i, 0)),
@@ -361,7 +359,7 @@ def _packed_fwd_pallas(qt, kt, vt, bm: BlockMap, scale: float,
                 jax.ShapeDtypeStruct((h, tq, d), jnp.float32),
                 jax.ShapeDtypeStruct((h, tq), jnp.float32),
             ],
-            compiler_params=_pltpu_compiler_params(pltpu)(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
             interpret=interpret,
         )(bm.kstart, bm.klen, bm.qslo, bm.qshi, bm.qpos0,
@@ -430,6 +428,7 @@ def _packed_dq_pallas(qt, kt, vt, dot_, lse, delta, bm: BlockMap,
             vf = vbuf[slot].astype(jnp.float32)
             lg = jax.lax.dot_general(
                 qf, kf, (((2,), (2,)), ((0,), (0,))),
+                precision=_PREC,
                 preferred_element_type=jnp.float32)
             interior = jnp.logical_and(
                 jnp.logical_and(uniform_q, kslo[j] == kshi[j]),
@@ -451,10 +450,12 @@ def _packed_dq_pallas(qt, kt, vt, dot_, lse, delta, bm: BlockMap,
             p = jnp.exp(lg - lse_t[..., None]) * mskf[None]
             dp = jax.lax.dot_general(
                 dof, vf, (((2,), (2,)), ((0,), (0,))),
+                precision=_PREC,
                 preferred_element_type=jnp.float32)      # [h, bq, bk]
             ds = p * (dp - delta_t[..., None])
             return dq + jax.lax.dot_general(
                 ds, kf, (((2,), (1,)), ((0,), (0,))),
+                precision=_PREC,
                 preferred_element_type=jnp.float32)
 
         dq = jax.lax.fori_loop(jnp.int32(0), kl, body,
@@ -469,9 +470,9 @@ def _packed_dq_pallas(qt, kt, vt, dot_, lse, delta, bm: BlockMap,
             pl.BlockSpec((h, bq, d), lambda i, *_: (0, i, 0)),
             pl.BlockSpec((h, bq, d), lambda i, *_: (0, i, 0)),
             pl.BlockSpec((2, h, bq), lambda i, *_: (0, 0, i)),
-            pl.BlockSpec(memory_space=_pltpu_memspace(pltpu).ANY),
-            pl.BlockSpec(memory_space=_pltpu_memspace(pltpu).ANY),
-            pl.BlockSpec(memory_space=_pltpu_memspace(pltpu).ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
         out_specs=pl.BlockSpec((h, bq, d), lambda i, *_: (0, i, 0)),
         scratch_shapes=[
@@ -488,7 +489,7 @@ def _packed_dq_pallas(qt, kt, vt, dot_, lse, delta, bm: BlockMap,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((h, tq, d), jnp.float32),
-            compiler_params=_pltpu_compiler_params(pltpu)(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
             interpret=interpret,
         )(bm.kstart, bm.klen, bm.qslo, bm.qshi, bm.qpos0,
@@ -563,6 +564,7 @@ def _packed_dkv_pallas(qt, kt, vt, dot_, lse, delta, bm: BlockMap,
             delta_t = ldbuf[slot, 1]
             lg = jax.lax.dot_general(
                 qf, kf, (((2,), (2,)), ((0,), (0,))),
+                precision=_PREC,
                 preferred_element_type=jnp.float32)      # [h, bq, bk]
             interior = jnp.logical_and(
                 jnp.logical_and(uniform_k, qslo[t] == qshi[t]),
@@ -584,13 +586,16 @@ def _packed_dkv_pallas(qt, kt, vt, dot_, lse, delta, bm: BlockMap,
             p = jnp.exp(lg - lse_t[..., None]) * mskf[None]
             dv = dv + jax.lax.dot_general(
                 p, dof, (((1,), (1,)), ((0,), (0,))),
+                precision=_PREC,
                 preferred_element_type=jnp.float32)      # [h, bk, d]
             dp = jax.lax.dot_general(
                 dof, vf, (((2,), (2,)), ((0,), (0,))),
+                precision=_PREC,
                 preferred_element_type=jnp.float32)      # [h, bq, bk]
             ds = p * (dp - delta_t[..., None])
             dk = dk + jax.lax.dot_general(
                 ds, qf, (((1,), (1,)), ((0,), (0,))),
+                precision=_PREC,
                 preferred_element_type=jnp.float32)      # [h, bk, d]
             return dk, dv
 
@@ -608,10 +613,10 @@ def _packed_dkv_pallas(qt, kt, vt, dot_, lse, delta, bm: BlockMap,
             pl.BlockSpec((2, bk), lambda j, *_: (0, j)),
             pl.BlockSpec((h, bk, d), lambda j, *_: (0, j, 0)),
             pl.BlockSpec((h, bk, d), lambda j, *_: (0, j, 0)),
-            pl.BlockSpec(memory_space=_pltpu_memspace(pltpu).ANY),
-            pl.BlockSpec(memory_space=_pltpu_memspace(pltpu).ANY),
-            pl.BlockSpec(memory_space=_pltpu_memspace(pltpu).ANY),
-            pl.BlockSpec(memory_space=_pltpu_memspace(pltpu).ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
         out_specs=[
             pl.BlockSpec((h, bk, d), lambda j, *_: (0, j, 0)),
@@ -636,7 +641,7 @@ def _packed_dkv_pallas(qt, kt, vt, dot_, lse, delta, bm: BlockMap,
                 jax.ShapeDtypeStruct((h, tk, d), jnp.float32),
                 jax.ShapeDtypeStruct((h, tk, d), jnp.float32),
             ],
-            compiler_params=_pltpu_compiler_params(pltpu)(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
             interpret=interpret,
         )(bm.qstart2, bm.qlen2, bm.qslo, bm.qshi, bm.qpos0,
@@ -829,7 +834,7 @@ def _packed_core_fwd(q, k, v, cu_q, cu_k, causal, scale, bq, bk,
         outp, lse = _packed_fwd_pallas(qt, kt, vt, bm, scale, causal,
                                        bq, bk,
                                        interpret=(backend == "interpret"
-                                                  or not _on_tpu()))
+                                                  or not _chip.on_tpu()))
     out = jnp.swapaxes(outp[:, :tq], 0, 1).astype(q.dtype)
     return out, (q, k, v, cu_q, cu_k, out, lse)
 
@@ -849,7 +854,7 @@ def _packed_core_bwd(causal, scale, bq, bk, backend, res, g):
         dq, dk, dv = _packed_bwd_xla(qt, kt, vt, dot_, lse, delta, bm,
                                      scale, causal, bq, bk)
     else:
-        interp = backend == "interpret" or not _on_tpu()
+        interp = backend == "interpret" or not _chip.on_tpu()
         dq = _packed_dq_pallas(qt, kt, vt, dot_, lse, delta, bm, scale,
                                causal, bq, bk, interp)
         dk, dv = _packed_dkv_pallas(qt, kt, vt, dot_, lse, delta, bm,
@@ -968,6 +973,7 @@ def _paged_fwd_pallas(qt, key_cache, value_cache, tables, start, klen,
                 .astype(jnp.float32)
             lg = jax.lax.dot_general(
                 q3, kt, (((2,), (2,)), ((0,), (0,))),
+                precision=_PREC,
                 preferred_element_type=jnp.float32)   # [n_kv, g*c, bk]
             interior = (j + 1) * bk - 1 <= st
 
@@ -993,6 +999,7 @@ def _paged_fwd_pallas(qt, key_cache, value_cache, tables, start, klen,
             l = l * alpha + p.sum(-1)
             pv = jax.lax.dot_general(
                 p, vt, (((2,), (1,)), ((0,), (0,))),
+                precision=_PREC,
                 preferred_element_type=jnp.float32)   # [n_kv, g*c, d]
             acc = acc * alpha[..., None] + pv
             return pm, l, acc
@@ -1007,8 +1014,8 @@ def _paged_fwd_pallas(qt, key_cache, value_cache, tables, start, klen,
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, n_q, c, d), lambda i, *_: (i, 0, 0, 0)),
-            pl.BlockSpec(memory_space=_pltpu_memspace(pltpu).ANY),
-            pl.BlockSpec(memory_space=_pltpu_memspace(pltpu).ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
         out_specs=pl.BlockSpec((1, n_q, c, d),
                                lambda i, *_: (i, 0, 0, 0)),
@@ -1023,7 +1030,7 @@ def _paged_fwd_pallas(qt, key_cache, value_cache, tables, start, klen,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, n_q, c, d), jnp.float32),
-            compiler_params=_pltpu_compiler_params(pltpu)(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
             interpret=interpret,
         )(tables.reshape(-1).astype(jnp.int32),
@@ -1135,5 +1142,5 @@ def paged_prefill_attention(q, key_cache, value_cache, block_tables,
         out = _paged_fwd_pallas(
             qt, key_cache, value_cache, block_tables, start, klen,
             scale, n_kv, bk,
-            interpret=(backend == "interpret" or not _on_tpu()))
+            interpret=(backend == "interpret" or not _chip.on_tpu()))
     return jnp.swapaxes(out, 1, 2).astype(q.dtype)     # [b, c, n_q, d]

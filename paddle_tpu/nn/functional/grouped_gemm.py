@@ -59,8 +59,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ...device import chip as _chip
 from ...device.vmem import KERNEL_VMEM_LIMIT_BYTES
-from .paged_attention import _enable_x64, _pltpu_compiler_params
+from .paged_attention import _enable_x64
 from .stream_linear import _apply_activation, _pick_bn
 
 __all__ = [
@@ -70,14 +71,6 @@ __all__ = [
 
 #: row-tile height: one MXU-friendly sublane-aligned token block
 DEFAULT_BLOCK_ROWS = 128
-
-
-@functools.lru_cache(maxsize=1)
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 #: numpy (not jnp) on purpose: this module is imported lazily
@@ -96,7 +89,7 @@ def _cdiv(a, b):
 
 def _resolve_backend(backend: str, geometry_ok: bool) -> str:
     if backend == "auto":
-        backend = "pallas" if _on_tpu() else "xla"
+        backend = "pallas" if _chip.on_tpu() else "xla"
     if backend not in ("pallas", "interpret", "xla"):
         raise ValueError(
             f"grouped_gemm backend={backend!r}: expected 'auto', "
@@ -219,7 +212,7 @@ def _grouped_fwd_pallas(x_pad, w3, b3, gids, tids, lo, hi, bm, bn,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((t_pad, N), jnp.float32),
-            compiler_params=_pltpu_compiler_params(pltpu)(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
             interpret=interpret,
         )(gids, tids, lo, hi, x_pad, w3, b3)
@@ -360,9 +353,9 @@ def _grouped_dw(x_pad, dz_pad, E, gids, tids, lo, hi, bm, bn, backend):
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((E, K, N), jnp.float32),
-            compiler_params=_pltpu_compiler_params(pltpu)(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
-            interpret=(backend == "interpret" or not _on_tpu()),
+            interpret=(backend == "interpret" or not _chip.on_tpu()),
         )(gids, tids, lo, hi, x_pad, dz_pad)
 
 
@@ -404,7 +397,7 @@ def _raw_grouped(x, w, b, offsets, activation, backend):
     else:
         out = _grouped_fwd_pallas(
             x_pad, w, b3, gids, tids, lo, hi, bm, bn, activation,
-            interpret=(backend == "interpret" or not _on_tpu()))
+            interpret=(backend == "interpret" or not _chip.on_tpu()))
     return out[:T]
 
 

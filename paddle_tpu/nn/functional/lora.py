@@ -40,11 +40,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ...device import chip as _chip
 from ...device.vmem import KERNEL_VMEM_LIMIT_BYTES
-from .grouped_gemm import (_I0, _cdiv, _geometry, _i32, _on_tpu,
+from .grouped_gemm import (_I0, _cdiv, _geometry, _i32,
                            _pad_rows, _resolve_backend,
                            DEFAULT_BLOCK_ROWS, grouped_work_map)
-from .paged_attention import _enable_x64, _pltpu_compiler_params
+from .paged_attention import _enable_x64
 from .stream_linear import _INT8_SUBLANES, _SUBLANES
 
 __all__ = ["lora_delta", "sort_by_adapter", "inverse_order",
@@ -153,7 +154,7 @@ def _lora_fwd_pallas(x_pad, a3, b3, gids, tids, lo, hi, bm, bn,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((t_pad, N), jnp.float32),
-            compiler_params=_pltpu_compiler_params(pltpu)(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
             interpret=interpret,
         )(gids, tids, lo, hi, x_pad, a3, b3)
@@ -252,6 +253,6 @@ def lora_delta(x, a, b, offsets, *, out_dtype=None, backend="auto"):
     else:
         out = _lora_fwd_pallas(
             x_pad, a, b, gids, tids, lo, hi, bm, bn,
-            interpret=(backend == "interpret" or not _on_tpu()))
+            interpret=(backend == "interpret" or not _chip.on_tpu()))
     out = out[:T]
     return out if out_dtype is None else out.astype(out_dtype)

@@ -26,24 +26,10 @@ from ...ops.dispatch import as_tensor_args, eager_apply
 __all__ = ["ring_attention", "ring_flash_attention"]
 
 
-def _shard_map():
-    """shard_map across jax versions (jax >= 0.7 promotes it out of
-    experimental; 0.4.x only has the experimental home)."""
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm
-
-
 def _mark_varying(t, axis_name):
-    """lax.pcast(..., to='varying') where available (newer jax tracks
-    per-axis replication); on jax without pcast the shard_map below runs
-    with check_rep=False, so the marking is a no-op."""
-    pcast = getattr(lax, "pcast", None)
-    if pcast is None:
-        return t
-    return pcast(t, (axis_name,), to="varying")
+    """Mark a replicated value device-varying over ``axis_name`` so it
+    can be a scan carry next to the rotating K/V blocks."""
+    return lax.pcast(t, (axis_name,), to="varying")
 
 
 def _ring_attention_sharded(q, k, v, axis_name: str, causal: bool,
@@ -117,7 +103,6 @@ def ring_attention(q, k, v, mesh=None, seq_axis: str = "sep",
     ``seq_axis`` (or dense, in which case they're sharded here). Output is
     sharded the same way.
     """
-    shard_map = _shard_map()
 
     from ...distributed.auto_parallel.placement import (
         ProcessMesh, Replicate, Shard,
@@ -142,13 +127,8 @@ def ring_attention(q, k, v, mesh=None, seq_axis: str = "sep",
     body = functools.partial(_ring_attention_sharded, axis_name=seq_axis,
                              causal=causal, scale=scale,
                              axis_size=axis_size)
-    kwargs = {}
-    if getattr(lax, "pcast", None) is None:
-        # no pcast -> no way to mark the scan carries device-varying, so
-        # replication checking must be off (jax 0.4.x)
-        kwargs["check_rep"] = False
-    fn = shard_map(body, mesh=jmesh, in_specs=(pspec, pspec, pspec),
-                   out_specs=pspec, **kwargs)
+    fn = jax.shard_map(body, mesh=jmesh, in_specs=(pspec, pspec, pspec),
+                       out_specs=pspec)
     jit_fn = jax.jit(fn)
 
     placements = [Replicate()] * mesh.ndim

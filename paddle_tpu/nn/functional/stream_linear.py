@@ -76,9 +76,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ...device import chip as _chip
 from ...device.vmem import KERNEL_VMEM_LIMIT_BYTES
-from .paged_attention import (_enable_x64, _on_tpu,
-                              _pltpu_compiler_params)
+from .paged_attention import _enable_x64
 
 __all__ = ["stream_linear", "stream_layer_tail"]
 
@@ -158,7 +158,7 @@ def _stream_linear_a8w8(x_q, x_scale, w3, s3, b3, layer, activation,
     N = w3.shape[-1]
     bn = _pick_bn(K, N, 1)
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not _chip.on_tpu()
     # pad the (tiny) activation block up to the int8 sublane tile
     Mp = -(-M // _INT8_SUBLANES) * _INT8_SUBLANES
     if Mp != M:
@@ -177,6 +177,7 @@ def _stream_linear_a8w8(x_q, x_scale, w3, s3, b3, layer, activation,
         acc = jax.lax.dot_general(
             x_ref[...], w_ref[0],
             (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.DEFAULT,
             preferred_element_type=jnp.int32)          # [Mp, bn] int32
         acc = acc.astype(jnp.float32) * xs_ref[...] \
             * s_ref[0].astype(jnp.float32)
@@ -207,7 +208,7 @@ def _stream_linear_a8w8(x_q, x_scale, w3, s3, b3, layer, activation,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
-            compiler_params=_pltpu_compiler_params(pltpu)(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
             interpret=interpret,
         )(lidx, *operands)
@@ -226,7 +227,7 @@ def _stream_linear_act_quant(x, w, layer, bias, scale, activation,
     K = x.shape[1]
     N = w.shape[-1]
     x_q, x_s = dynamic_act_quant(x)
-    if _on_tpu() and _pick_bn(K, N, 1) and K % 128 == 0:
+    if _chip.on_tpu() and _pick_bn(K, N, 1) and K % 128 == 0:
         w3 = w if stacked else w[None]
         s3 = (scale if stacked else scale[None]) \
             .reshape(w3.shape[0], 1, N).astype(jnp.float32)
@@ -360,7 +361,7 @@ def stream_linear(x, w, layer=None, bias=None, scale=None,
             x, w, layer, bias, scale, activation, out_dtype,
             stacked=stacked)
     bn = _pick_bn(K, N, w.dtype.itemsize)
-    if bn == 0 or K % 128 != 0 or not _on_tpu():
+    if bn == 0 or K % 128 != 0 or not _chip.on_tpu():
         # fallback: plain XLA dot (CPU tests, odd shapes)
         wl = w[layer] if stacked else w
         out = jax.lax.dot_general(
@@ -438,7 +439,7 @@ def stream_linear(x, w, layer=None, bias=None, scale=None,
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
-            compiler_params=_pltpu_compiler_params(pltpu)(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
         )(lidx, *operands)
     return out[:M] if Mp != M else out
@@ -632,9 +633,12 @@ def _stream_layer_tail_kernel(att, h, wo3, w13, w23, so3, s13, s23,
         operands.append(s23)
     in_specs.append(pl.BlockSpec((1, 1, d), lambda j, l: (l[0], 0, 0)))
     operands.append(b23)
-    in_specs.append(pl.BlockSpec((1, d), lambda j, l: (l[0], 0)))
+    # LN scale/bias ride as [L, 1, d] like the biases: a one-row block
+    # of a 2-D [L, d] array is refused by the TPU tiling for L > 1
+    # (second-to-last block dim must be a multiple of 8 or the whole dim)
+    in_specs.append(pl.BlockSpec((1, 1, d), lambda j, l: (l[0], 0, 0)))
     operands.append(ln2s)
-    in_specs.append(pl.BlockSpec((1, d), lambda j, l: (l[0], 0)))
+    in_specs.append(pl.BlockSpec((1, 1, d), lambda j, l: (l[0], 0, 0)))
     operands.append(ln2b)
     out_shapes = [jax.ShapeDtypeStruct((Mp, d), out_dtype)]
     out_specs = [pl.BlockSpec((Mp, d), lambda j, l: (0, 0))]
@@ -650,9 +654,11 @@ def _stream_layer_tail_kernel(att, h, wo3, w13, w23, so3, s13, s23,
         in_specs.append(pl.BlockSpec((1, 1, bn_q),
                                      lambda j, l: (l[1], 0, q_idx(j))))
         operands.append(qg["b"])
-        in_specs.append(pl.BlockSpec((1, d), lambda j, l: (l[1], 0)))
+        in_specs.append(pl.BlockSpec((1, 1, d),
+                                     lambda j, l: (l[1], 0, 0)))
         operands.append(qg["ln_s"])
-        in_specs.append(pl.BlockSpec((1, d), lambda j, l: (l[1], 0)))
+        in_specs.append(pl.BlockSpec((1, 1, d),
+                                     lambda j, l: (l[1], 0, 0)))
         operands.append(qg["ln_b"])
         out_shapes.append(jax.ShapeDtypeStruct((Mp, nq_n), out_dtype))
         out_specs.append(pl.BlockSpec((Mp, bn_q),
@@ -673,7 +679,7 @@ def _stream_layer_tail_kernel(att, h, wo3, w13, w23, so3, s13, s23,
             kernel,
             grid_spec=grid_spec,
             out_shape=out_shapes,
-            compiler_params=_pltpu_compiler_params(pltpu)(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
             interpret=interpret,
         )(lidx, *operands)
@@ -806,7 +812,7 @@ def stream_layer_tail(att, h, wo, w1, w2, layer=None, *, bo, b1, b2,
     dff = w1.shape[-1]
     nq_n = next_qkv["w"].shape[-1] if next_qkv is not None else 0
     bns = _tail_geometry(Ka, d, dff, nq_n, wo.dtype.itemsize)
-    use_kernel = bns is not None and (interpret is True or _on_tpu())
+    use_kernel = bns is not None and (interpret is True or _chip.on_tpu())
     if not use_kernel:
         return _tail_fallback(
             att, h, wo, w1, w2,
@@ -815,7 +821,7 @@ def stream_layer_tail(att, h, wo, w1, w2, layer=None, *, bo, b1, b2,
             activation, next_qkv, out_dtype, stacked)
 
     interpret = bool(interpret) if interpret is not None \
-        else not _on_tpu()
+        else not _chip.on_tpu()
     L = wo.shape[0] if stacked else 1
 
     def norm_w(a):
@@ -825,7 +831,7 @@ def stream_layer_tail(att, h, wo, w1, w2, layer=None, *, bo, b1, b2,
         return (a if stacked else a[None]).reshape(L, 1, n)
 
     def norm_ln(a):
-        return (a if stacked else a[None]).reshape(L, d)
+        return a.reshape(-1, 1, d)
 
     qg = None
     lq = 0

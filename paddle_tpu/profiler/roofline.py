@@ -19,8 +19,9 @@ Three cooperating pieces:
   (jit/static_function.py, jit/train_step.py) and the inference decode
   step call this automatically at compile time via ``AotProgram``.
 - ``analyze(name, wall_s)`` — fold a measured wall time into achieved
-  rates against the device peak table (TPU generations + CPU fallback,
-  env-overridable) and publish ``roofline.*`` gauges.
+  rates against the one chip table (``device/chip.py``, keyed by
+  ``device_kind``, env-overridable) and publish ``roofline.*`` gauges.
+  Off-TPU there is no peak: utilizations are None, rates are kept.
 - ``AotProgram`` — a thin wrapper that turns a ``jax.jit`` function
   into an explicitly compiled executable (``lower().compile()``) so the
   cost model is captured WITHOUT a second compilation; falls back to
@@ -35,6 +36,7 @@ in BENCH_*.json.
 """
 from __future__ import annotations
 
+import functools
 import os
 import time
 from typing import Dict, Optional
@@ -44,27 +46,10 @@ import jax
 from . import stats as _stats
 
 __all__ = [
-    "PEAKS", "CPU_PEAK", "device_peaks", "program_cost",
+    "device_peaks", "program_cost",
     "record_program", "analyze", "observe_wall", "report", "reset",
     "RooflineResult", "AotProgram", "format_report",
 ]
-
-#: device_kind substring -> (peak bf16 FLOP/s, peak HBM bytes/s).
-#: Same provenance as bench.py's PEAK_BF16/HBM_BW tables (public TPU
-#: spec sheets); first substring match wins.
-PEAKS = {
-    "v5 lite": (197e12, 819e9),
-    "v5e": (197e12, 819e9),
-    "v5p": (459e12, 2765e9),
-    "v4": (275e12, 1228e9),
-    "v6": (918e12, 1640e9),
-    "v3": (123e12, 900e9),
-}
-
-#: CPU fallback so roofline math stays exercised in CI: a rough
-#: single-socket figure (order-of-magnitude only — override via env for
-#: anything quantitative on CPU).
-CPU_PEAK = (200e9, 50e9)
 
 #: env overrides (floats, FLOP/s and bytes/s) — let a deployment pin
 #: the exact part's numbers without a code change
@@ -76,40 +61,40 @@ ENV_PEAK_HBM_BW = "PADDLE_TPU_PEAK_HBM_BW"
 _PROGRAMS: Dict[str, dict] = {}
 
 
-_DEFAULT_DEVICE = None
+@functools.lru_cache(maxsize=1)
+def _default_device():
+    # analyze() runs once per jitted call (observe_wall): look it up once
+    return jax.devices()[0]
 
 
 def device_peaks(device=None):
-    """(peak FLOP/s, peak HBM bytes/s) for the device, resolved as:
-    env override > device_kind table match > CPU fallback > v5e."""
+    """(peak FLOP/s, peak HBM bytes/s) for the device: env override,
+    else the one chip table (``paddle_tpu.device.chip.CHIPS``, keyed by
+    ``device_kind``). A device that is not in the table — a CPU
+    included — raises: no other chip's peaks are assumed for it."""
+    from ..device.chip import chip_spec
+
     env_f = os.environ.get(ENV_PEAK_FLOPS)
     env_b = os.environ.get(ENV_PEAK_HBM_BW)
     if env_f and env_b:
         return float(env_f), float(env_b)
+    spec = chip_spec(device if device is not None else _default_device())
+    return (float(env_f) if env_f else spec.peak_bf16_flops,
+            float(env_b) if env_b else spec.hbm_bytes_per_s)
+
+
+def _peaks_or_none(device):
+    """Peaks for ``analyze``: off-TPU (the CPU test hosts) there is no
+    roofline to compare with, so utilizations are reported as None and
+    only the achieved rates are kept — unless both env overrides pin
+    the peaks. On a TPU an unknown chip raises (``device_peaks``)."""
     if device is None:
-        global _DEFAULT_DEVICE
-        if _DEFAULT_DEVICE is None:
-            try:
-                _DEFAULT_DEVICE = jax.devices()[0]
-            except Exception:
-                pass
-        device = _DEFAULT_DEVICE
-    kind = getattr(device, "device_kind", "").lower()
-    platform = getattr(device, "platform", "").lower()
-    peak = None
-    for k, v in PEAKS.items():
-        if k in kind:
-            peak = v
-            break
-    if peak is None:
-        peak = CPU_PEAK if platform == "cpu" or kind == "cpu" \
-            else PEAKS["v5e"]
-    flops, bw = peak
-    if env_f:
-        flops = float(env_f)
-    if env_b:
-        bw = float(env_b)
-    return flops, bw
+        device = _default_device()
+    pinned = os.environ.get(ENV_PEAK_FLOPS) \
+        and os.environ.get(ENV_PEAK_HBM_BW)
+    if device.platform != "tpu" and not pinned:
+        return None, None
+    return device_peaks(device)
 
 
 def program_cost(compiled) -> Optional[dict]:
@@ -175,10 +160,12 @@ class RooflineResult:
         self.peak_bw = peak_bw
         self.achieved_flops_per_s = flops / wall_s if wall_s > 0 else 0.0
         self.achieved_bytes_per_s = nbytes / wall_s if wall_s > 0 else 0.0
+        # None, not 0.0, where the device has no known peak: a
+        # utilization against nothing is not a small utilization
         self.mfu = (self.achieved_flops_per_s / peak_flops
-                    if peak_flops else 0.0)
+                    if peak_flops else None)
         self.bw_util = (self.achieved_bytes_per_s / peak_bw
-                        if peak_bw else 0.0)
+                        if peak_bw else None)
 
     def as_dict(self) -> dict:
         return {
@@ -187,18 +174,26 @@ class RooflineResult:
             "wall_s": round(self.wall_s, 6),
             "achieved_flops_per_s": round(self.achieved_flops_per_s, 1),
             "achieved_bytes_per_s": round(self.achieved_bytes_per_s, 1),
-            "mfu": round(self.mfu, 4),
-            "bw_util": round(self.bw_util, 4),
+            "mfu": None if self.mfu is None else round(self.mfu, 4),
+            "bw_util": None if self.bw_util is None
+            else round(self.bw_util, 4),
         }
 
     def format(self) -> str:
-        return (f"roofline[{self.name}]: "
-                f"{self.achieved_flops_per_s / 1e9:.1f} GFLOP/s "
-                f"(MFU {100 * self.mfu:.1f}%) | "
-                f"{self.achieved_bytes_per_s / 1e9:.1f} GB/s "
-                f"({100 * self.bw_util:.1f}% of HBM roofline) | "
-                f"cost: {self.flops:.3g} flops, {self.bytes:.3g} bytes "
-                f"@ {self.wall_s * 1e3:.3f} ms")
+        return (_format_rates(self.name, self.achieved_flops_per_s,
+                              self.mfu, self.achieved_bytes_per_s,
+                              self.bw_util)
+                + f" | cost: {self.flops:.3g} flops, "
+                  f"{self.bytes:.3g} bytes @ {self.wall_s * 1e3:.3f} ms")
+
+
+def _format_rates(name, flops_s, mfu, bytes_s, bw_util) -> str:
+    def pct(v):
+        return "not measured" if v is None else f"{100 * v:.1f}%"
+
+    return (f"roofline[{name}]: {flops_s / 1e9:.1f} GFLOP/s "
+            f"(MFU {pct(mfu)}) | {bytes_s / 1e9:.1f} GB/s "
+            f"({pct(bw_util)} of HBM roofline)")
 
 
 def analyze(name: str, wall_s: float, *, calls: int = 1,
@@ -213,7 +208,7 @@ def analyze(name: str, wall_s: float, *, calls: int = 1,
     if not entry or wall_s <= 0 or "flops" not in entry:
         return None
     per_call = wall_s / max(calls, 1)
-    peak_flops, peak_bw = device_peaks(device)
+    peak_flops, peak_bw = _peaks_or_none(device)
     res = RooflineResult(name, entry["flops"], entry["bytes"],
                          per_call, peak_flops, peak_bw)
     entry.update(res.as_dict())
@@ -221,8 +216,9 @@ def analyze(name: str, wall_s: float, *, calls: int = 1,
                      res.achieved_flops_per_s)
     _stats.set_gauge("roofline.achieved_bytes_per_s",
                      res.achieved_bytes_per_s)
-    _stats.set_gauge("roofline.mfu", res.mfu)
-    _stats.set_gauge("roofline.bw_util", res.bw_util)
+    if res.mfu is not None:
+        _stats.set_gauge("roofline.mfu", res.mfu)
+        _stats.set_gauge("roofline.bw_util", res.bw_util)
     return res
 
 
@@ -249,12 +245,9 @@ def format_report() -> str:
     lines = []
     for name, e in _PROGRAMS.items():
         if "mfu" in e:
-            lines.append(
-                f"roofline[{name}]: "
-                f"{e['achieved_flops_per_s'] / 1e9:.1f} GFLOP/s "
-                f"(MFU {100 * e['mfu']:.1f}%) | "
-                f"{e['achieved_bytes_per_s'] / 1e9:.1f} GB/s "
-                f"({100 * e['bw_util']:.1f}% of HBM roofline)")
+            lines.append(_format_rates(
+                name, e["achieved_flops_per_s"], e["mfu"],
+                e["achieved_bytes_per_s"], e["bw_util"]))
         else:
             lines.append(f"roofline[{name}]: cost {e['flops']:.3g} flops"
                          f" / {e['bytes']:.3g} bytes (no timing yet)")

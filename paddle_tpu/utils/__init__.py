@@ -121,8 +121,7 @@ class dlpack:
         """Returns a DLPack-protocol object (has __dlpack__ /
         __dlpack_device__ — the modern exchange form consumers like
         np/torch/jax from_dlpack expect). Falls back through host
-        memory on PJRT transports without external buffer references
-        (e.g. tunneled chips)."""
+        memory on PJRT transports without external buffer references."""
         from ..core.tensor import Tensor
 
         arr = tensor._data if isinstance(tensor, Tensor) else tensor

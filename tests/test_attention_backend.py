@@ -5,11 +5,12 @@ import numpy as np
 
 import paddle_tpu as paddle
 import paddle_tpu.nn.functional as F
+from paddle_tpu.device import chip
 from paddle_tpu.nn.functional import attention as attn_mod
 
 
 def test_pallas_gate_accepts_common_head_dims(monkeypatch):
-    monkeypatch.setattr(attn_mod, "_on_tpu", lambda: True)
+    monkeypatch.setattr(chip, "on_tpu", lambda: True)
     for hd in (64, 96, 128, 256):
         assert attn_mod._use_pallas(hd, 512, 512, False), hd
     # misaligned head dim, short/unaligned seqs, bias → XLA fallback
@@ -19,7 +20,7 @@ def test_pallas_gate_accepts_common_head_dims(monkeypatch):
 
 
 def test_gate_off_tpu(monkeypatch):
-    monkeypatch.setattr(attn_mod, "_on_tpu", lambda: False)
+    monkeypatch.setattr(chip, "on_tpu", lambda: False)
     assert not attn_mod._use_pallas(128, 512, 512, False)
 
 

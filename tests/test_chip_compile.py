@@ -1,0 +1,172 @@
+"""Compile rehearsals: every main-path Pallas kernel, compiled for a
+DESCRIBED TPU v5e (no chip attached) with ``interpret=False``.
+
+Interpret-mode tests cannot see what the chip's compiler refuses — a
+dot precision Mosaic does not lower, a block the tiling rejects, more
+fast memory than a kernel may use. These compiles can, in about two
+seconds each, at the 1.3B serving/training widths (d_model 2048, ffn
+8192, 16 heads x 128, page 16), under the package's real config (x64
+on, ``jax_default_matmul_precision="high"``).
+
+Rules this file keeps (see the on-chip-measurement guide):
+- the topology is described inside a module-scoped fixture, never at
+  import, in a ``skipif``, in ``parametrize`` arguments or in conftest —
+  only the xdist worker that is handed this file loads the TPU library;
+- everything compiles in the test's own process;
+- ONE file holds all of them (a second file could land on a worker that
+  cannot load the library and would skip in silence);
+- the persistent compile cache is off around them (a compile for a
+  described device is written but cannot be read back without a chip).
+
+A compile that passes is not a chip run: nothing here says the kernels
+are right or fast.
+"""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu  # noqa: F401  (the package's jax config is part of the test)
+
+#: static list — parametrize arguments must not touch the topology
+KERNELS = [
+    "stream_linear.bf16",
+    "stream_linear.a8w8",
+    "stream_layer_tail.bf16",
+    "stream_layer_tail.bf16.next_qkv",
+    "stream_layer_tail.int8",
+    "stream_layer_tail.int8.next_qkv",
+    "paged_attention.stream",
+    "paged_attention.decode_inplace",
+    "paged_attention.decode_inplace_q",
+    "flash_varlen.packed_fwd",
+    "flash_varlen.packed_bwd",
+    "flash_varlen.paged",
+    "flash_varlen.paged.c256",
+    "flash_varlen.paged.c512",
+    "grouped_gemm.fwd",
+    "grouped_gemm.bwd",
+    "lora.delta",
+    "attention.flash",
+]
+
+L, D, DFF, NQ, HEADS, HEAD_DIM, PAGE = 24, 2048, 8192, 3 * 2048, 16, 128, 16
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _layer_tail(int8: bool, next_qkv: bool):
+    """stream_layer_tail at the real depth (L=24): stacked weights, a
+    traced layer index, bf16 or weight-only-int8 stacks (so/s1/s2
+    dequant scales), with and without the cross-layer QKV prefetch."""
+    import paddle_tpu.nn.functional.stream_linear as sl
+
+    bf, f32 = jnp.bfloat16, jnp.float32
+    wdt = jnp.int8 if int8 else bf
+
+    def fn(att, h, wo, w1, w2, bo, b1, b2, ln2s, ln2b, so, s1, s2,
+           wq, bq, sq, ln1s, ln1b, layer):
+        nq = None
+        if next_qkv:
+            nq = {"w": wq, "b": bq, "ln_s": ln1s, "ln_b": ln1b,
+                  "layer": jnp.minimum(layer + 1, L - 1)}
+            if int8:
+                nq["s"] = sq
+        scales = dict(so=so, s1=s1, s2=s2) if int8 else {}
+        return sl.stream_layer_tail(
+            att, h, wo, w1, w2, layer=layer, bo=bo, b1=b1, b2=b2,
+            ln2_scale=ln2s, ln2_bias=ln2b, epsilon=1e-5,
+            activation="gelu", next_qkv=nq, **scales)
+
+    args = (_sds((8, D), bf), _sds((8, D), bf),
+            _sds((L, D, D), wdt), _sds((L, D, DFF), wdt),
+            _sds((L, DFF, D), wdt),
+            _sds((L, D), bf), _sds((L, DFF), bf), _sds((L, D), bf),
+            _sds((L, D), bf), _sds((L, D), bf),
+            _sds((L, D), f32), _sds((L, DFF), f32), _sds((L, D), f32),
+            _sds((L, D, NQ), wdt), _sds((L, NQ), bf), _sds((L, NQ), f32),
+            _sds((L, D), bf), _sds((L, D), bf),
+            _sds((), jnp.int32))
+    return fn, args
+
+
+def _paged_prefill(chunk: int):
+    """The default chunked-prefill attention at the serving engine's own
+    geometry: one row, 16 heads x 128, page 16, the 8 x 1088-token pool
+    of chip_smoke.py (576 pages x 24 layers), 68 pages per sequence."""
+    from paddle_tpu.nn.functional.flash_varlen import (
+        paged_prefill_attention)
+
+    def fn(q, kc, vc, tables, start):
+        return paged_prefill_attention(q, kc, vc, tables, start,
+                                       n_kv=HEADS, backend="pallas")
+
+    pool = _sds((L * 576, HEADS, PAGE, HEAD_DIM), jnp.bfloat16)
+    return fn, (_sds((1, chunk, HEADS, HEAD_DIM), jnp.bfloat16), pool,
+                pool, _sds((1, 68), jnp.int32), _sds((1,), jnp.int32))
+
+
+def _build(name):
+    if name.startswith("stream_layer_tail."):
+        return _layer_tail(int8=".int8" in name,
+                           next_qkv=name.endswith(".next_qkv"))
+    if name.startswith("flash_varlen.paged.c"):
+        return _paged_prefill(int(name.rsplit("c", 1)[1]))
+    from paddle_tpu.analysis.sites import KERNEL_SITES
+
+    # the lint's own inventory (analysis/sites.py) at its 1.3B widths
+    return {s.name: s for s in KERNEL_SITES}[name].build()
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_on_chip(monkeypatch):
+    """Route the kernel modules as on the chip: the one platform probe
+    answers True, so launches take ``interpret=False`` and trace with
+    x64 off (paged_attention._enable_x64) exactly as they do there."""
+    from paddle_tpu.device import chip
+
+    monkeypatch.setattr(chip, "on_tpu", lambda: True)
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_kernel_compiles_for_v5e(name, one_chip, as_on_chip):
+    assert jax.config.jax_enable_x64            # the package's config
+    assert jax.config.jax_default_matmul_precision == "high"
+    fn, args = _build(name)
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), args)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), (
+        f"{name}: compiled without a Pallas kernel — a reference path "
+        f"was taken")

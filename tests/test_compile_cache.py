@@ -1,58 +1,84 @@
 """Persistent XLA compilation cache wiring + cold-vs-warm compile
-telemetry (ISSUE r6 satellite, first step toward the 25-min s2048
-compile).
+telemetry.
 
-- ``FLAGS_compile_cache_dir`` (env ``PADDLE_TPU_COMPILE_CACHE_DIR``)
-  -> ``device.setup_compile_cache()`` -> jax_compilation_cache_dir,
-  with the ``compile.persistent_cache`` gauge recording the regime.
+- ``device.setup_compile_cache()``: the cache directory is placed from
+  outside. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already uses
+  it and the repo sets no directory in code; where it is not, one fixed
+  path inside the checkout (``.jax_cache``, git-ignored) is used. The
+  ``compile.persistent_cache`` gauge records the regime.
 - ``TrainStep`` records its first call's wall seconds (trace + XLA
   compile + run) in the ``compile.train_step_first_call_s`` histogram,
-  which bench.py embeds in its telemetry block — so a cache-warm
-  round's compile-second drop is visible across BENCH_r*.json files.
+  which bench.py embeds in its telemetry block.
 """
+import os
+
 import numpy as np
+import pytest
 
 import jax
 
 import paddle_tpu as paddle
 from paddle_tpu.profiler import stats
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-class TestCompileCacheFlag:
-    def test_setup_applies_flag_dir_and_gauge(self, tmp_path):
-        old = paddle.get_flags("compile_cache_dir")["compile_cache_dir"]
-        try:
-            paddle.set_flags({"FLAGS_compile_cache_dir":
-                              str(tmp_path)})
-            applied = paddle.device.setup_compile_cache()
-            assert applied == str(tmp_path)
-            assert jax.config.jax_compilation_cache_dir == str(tmp_path)
-            assert stats.gauge("compile.persistent_cache").value == 1
-        finally:
-            jax.config.update("jax_compilation_cache_dir", None)
-            paddle.set_flags({"FLAGS_compile_cache_dir": old})
-            stats.set_gauge("compile.persistent_cache",
-                            1 if old else 0)
 
-    def test_no_dir_is_a_noop(self):
-        old = paddle.get_flags("compile_cache_dir")["compile_cache_dir"]
-        prev = jax.config.jax_compilation_cache_dir
-        try:
-            paddle.set_flags({"FLAGS_compile_cache_dir": ""})
-            assert paddle.device.setup_compile_cache() is None
-            assert jax.config.jax_compilation_cache_dir == prev
-            assert stats.gauge("compile.persistent_cache").value == 0
-        finally:
-            paddle.set_flags({"FLAGS_compile_cache_dir": old})
+@pytest.fixture
+def cache_config():
+    """Restore the cache directory the session runs with."""
+    prev = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev)
 
-    def test_explicit_path_wins_over_flag(self, tmp_path):
-        try:
-            applied = paddle.device.setup_compile_cache(
-                str(tmp_path / "explicit"))
-            assert applied == str(tmp_path / "explicit")
-        finally:
-            jax.config.update("jax_compilation_cache_dir", None)
-            stats.set_gauge("compile.persistent_cache", 0)
+
+class TestCompileCachePlacement:
+    def test_env_dir_is_left_to_jax(self, monkeypatch, tmp_path,
+                                    cache_config):
+        """With the standard variable set, no directory is set in
+        code: whatever JAX's config holds is left as it is."""
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", "sentinel")
+        assert paddle.device.setup_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == "sentinel"
+        assert stats.gauge("compile.persistent_cache").value == 1
+
+    def test_default_is_one_fixed_path_in_the_checkout(self):
+        from paddle_tpu.device import _default_cache_dir
+
+        want = os.path.join(REPO, ".jax_cache")
+        # the path is part of the cache key: the same for every run
+        # that may reach the chip, however the platform is (not) named
+        for platforms in (None, "", "tpu", "tpu,cpu"):
+            assert _default_cache_dir(platforms) == want
+        assert paddle.device.DEFAULT_COMPILE_CACHE_DIR == want
+
+    def test_cpu_pinned_run_places_no_cache(self, monkeypatch,
+                                            cache_config):
+        """The one exception (tests, rehearsals): pinned to the CPU
+        backend and with no directory given from outside, nothing is
+        set — XLA:CPU's cached results do not reload cleanly."""
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert jax.config.jax_platforms == "cpu"    # tests/conftest.py
+        jax.config.update("jax_compilation_cache_dir", "sentinel")
+        assert paddle.device.setup_compile_cache() is None
+        assert jax.config.jax_compilation_cache_dir == "sentinel"
+        assert stats.gauge("compile.persistent_cache").value == 0
+
+    def test_every_program_is_cached(self, monkeypatch, tmp_path,
+                                     cache_config):
+        """The serving programs compile in seconds each but are many:
+        the minimum compile time for a cache entry is zero."""
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", 1.0)
+        paddle.device.setup_compile_cache()
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+
+    def test_cache_dir_is_ignored_and_no_private_flag_left(self):
+        with open(os.path.join(REPO, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+        with pytest.raises(ValueError):
+            paddle.get_flags("compile_cache_dir")
 
 
 class TestTrainStepCompileSeconds:

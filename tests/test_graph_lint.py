@@ -284,11 +284,23 @@ class TestMemoryPass:
         temp copies of every weight, which no real TPU run pays."""
         from paddle_tpu.analysis import program_sites as ps
 
+        import re
+
         fn, args = ps.build_decode_program(cast_bf16=False)
         est = peak_live_bytes(jax.make_jaxpr(fn)(*args))
-        ma = jax.jit(fn).lower(*args).compile().memory_analysis()
+        compiled = jax.jit(fn).lower(*args).compile()
+        ma = compiled.memory_analysis()
+        # XLA:CPU re-lays the K/V page pool out for its scatter: one
+        # layout-changing copy of each pool PARAMETER on entry — a
+        # backend temp the program itself never asks for (the jaxpr
+        # keeps the page-major layout), so it is taken off XLA's side
+        relayout = sum(
+            4 * int(np.prod([int(d) for d in dims.split(",")]))
+            for dims in re.findall(
+                r"= f32\[([\d,]+)\]\{[\d,]+\} copy\(%cache_[kv]",
+                compiled.as_text()))
         xla = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-               + ma.temp_size_in_bytes)
+               + ma.temp_size_in_bytes - relayout)
         assert xla > 0
         ratio = est.peak_bytes / xla
         assert 0.8 <= ratio <= 1.2, (est.peak_bytes, xla, ratio)
@@ -340,10 +352,6 @@ class TestSpmdPass:
         assert check_spmd_site(site) == []
 
     def test_asymmetric_branch_collectives_flag_s_match(self):
-        try:
-            from jax.experimental.shard_map import shard_map
-        except ImportError:
-            from jax import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         mesh = analysis.virtual_mesh()
@@ -357,11 +365,11 @@ class TestSpmdPass:
                     return v if asym else jax.lax.psum(v, "x") * 0.5
                 return jax.lax.cond(x.sum() > 0, hot, cold, x)
 
-            kwargs = {}
-            if getattr(jax.lax, "pcast", None) is None:
-                kwargs["check_rep"] = False
-            fn = shard_map(body, mesh=mesh, in_specs=(P("x"),),
-                           out_specs=P("x"), **kwargs)
+            # vma checking off: the asymmetric pair is exactly what a
+            # typed cond refuses (psum output is replicated, the
+            # pass-through is varying) — the lint must see it anyway
+            fn = jax.shard_map(body, mesh=mesh, in_specs=(P("x"),),
+                               out_specs=P("x"), check_vma=False)
             x = jax.device_put(jnp.ones((8, 4)),
                                NamedSharding(mesh, P("x", None)))
             return fn, (x,)

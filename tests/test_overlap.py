@@ -22,7 +22,7 @@ Tier-1 acceptance pins:
   are census-clean, an injected blocking psum inside a ring site is
   caught, census drift is caught, and inline waivers silence;
 - **tooling**: bench_gate directions, ``serve_bench --drain-async``,
-  ``bench.py --all`` and the overlap rungs are wired.
+  the bench overlap rungs are wired (and refuse off-chip).
 """
 import importlib.util
 import os
@@ -42,8 +42,7 @@ from paddle_tpu.analysis.overlap import (OVERLAP_SITES, OverlapSite,
 from paddle_tpu.analysis.spmd import (_build_moe_ep_decode,
                                       _tp_serving_setup)
 from paddle_tpu.distributed.tp import (reduce_over_axis, resolve_overlap,
-                                       ring_census, serving_mesh,
-                                       shard_map_fn)
+                                       ring_census, serving_mesh)
 from paddle_tpu.incubate.nn.fused_transformer import PagedKV
 from paddle_tpu.inference import FusedCausalLM, GenerationEngine
 from paddle_tpu.profiler import (start_span_capture, stats,
@@ -69,11 +68,8 @@ class _flags:
 
 
 def _smap(body, mesh, in_specs, out_specs):
-    kwargs = {}
-    if getattr(jax.lax, "pcast", None) is None:
-        kwargs["check_rep"] = False
-    return shard_map_fn()(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)
 
 
 def _mp_mesh(n):
@@ -432,16 +428,12 @@ class TestOverlapPass:
             "  # tpu-lint: ok(S-OVERLAP) -- census change intended\n"
             "    import jax, jax.numpy as jnp\n"
             "    from jax.sharding import PartitionSpec as P\n"
-            "    from paddle_tpu.distributed.tp import serving_mesh,"
-            " shard_map_fn\n"
+            "    from paddle_tpu.distributed.tp import serving_mesh\n"
             "    mesh = serving_mesh(2,"
             " devices=jax.devices('cpu')[:2])\n"
-            "    kwargs = {}\n"
-            "    if getattr(jax.lax, 'pcast', None) is None:\n"
-            "        kwargs['check_rep'] = False\n"
-            "    fn = shard_map_fn()(lambda v: jax.lax.psum(v, 'mp'),"
+            "    fn = jax.shard_map(lambda v: jax.lax.psum(v, 'mp'),"
             " mesh=mesh, in_specs=(P('mp', None),),"
-            " out_specs=P('mp', None), **kwargs)\n"
+            " out_specs=P('mp', None))\n"
             "    return fn, (jnp.ones((2, 8), jnp.float32),)\n"))
         from paddle_tpu.distributed.tp import ring_census as rc
         site = OverlapSite("t.waived_overlap", mod.build,
@@ -489,12 +481,9 @@ class TestToolingWired:
         for kind in ("--decode-tp-overlap", "--moe-decode-ep-overlap",
                      "--fleet"):
             assert kind in bench.SECONDARY_KINDS, kind
-        # the CPU manifest subset only names real rungs, overlap
-        # rungs included
-        assert set(bench.CPU_KINDS) <= set(bench.SECONDARY_KINDS)
-        assert "--decode-tp-overlap" in bench.CPU_KINDS
-        assert "--moe-decode-ep-overlap" in bench.CPU_KINDS
-        assert "--fleet" in bench.CPU_KINDS
-        with open(os.path.join(REPO, "bench.py")) as f:
-            src = f.read()
-        assert '"--all"' in src and "def _run_all" in src
+        # no CPU manifest: every rung measures the chip, and a process
+        # that finds none refuses instead of emitting toy numbers under
+        # the device key names
+        assert not hasattr(bench, "CPU_KINDS")
+        with pytest.raises(SystemExit, match="needs the TPU"):
+            bench._require_chip()

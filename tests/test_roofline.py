@@ -91,10 +91,30 @@ class TestCostModel:
         monkeypatch.setenv(roofline.ENV_PEAK_HBM_BW, "7e11")
         assert roofline.device_peaks() == (5e12, 7e11)
 
-    def test_device_peaks_cpu_fallback(self):
-        flops, bw = roofline.device_peaks(jax.devices()[0])
-        assert flops == roofline.CPU_PEAK[0]
-        assert bw == roofline.CPU_PEAK[1]
+    def test_device_peaks_unknown_device_is_an_error(self):
+        """No made-up peak for a device that is not in the chip table:
+        the CPU test host included."""
+        with pytest.raises(ValueError, match="not in"):
+            roofline.device_peaks(jax.devices()[0])
+
+    def test_device_peaks_come_from_the_chip_table(self):
+        from paddle_tpu.device import chip
+
+        class V5e:
+            platform, device_kind = "tpu", "TPU v5 lite"
+
+        assert roofline.device_peaks(V5e()) == (197e12, 819e9)
+        assert chip.chip_spec("TPU v5 lite").generation == "v5e"
+        with pytest.raises(ValueError):
+            chip.chip_spec("TPU v99")
+
+    def test_analyze_off_tpu_keeps_rates_without_utilization(self):
+        roofline.record_program("t.cpu", flops=2e9, bytes_accessed=4e8)
+        res = roofline.analyze("t.cpu", wall_s=1e-3)
+        assert res.achieved_flops_per_s == pytest.approx(2e12)
+        assert res.mfu is None and res.bw_util is None
+        assert "not measured" in res.format()
+        assert roofline.report()["t.cpu"]["mfu"] is None
 
 
 class TestJitLayerAutoRecording:

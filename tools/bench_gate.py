@@ -5,8 +5,8 @@ RUNTIME-TELEMETRY side of two bench JSONs — the counters/histograms
 that explain WHY a number moved (retrace storms, cache-hit-rate
 collapse, compile-time blowups, roofline regressions):
 
-    python tools/bench_gate.py BENCH_r05.json BENCH_r06.json
-    python tools/bench_gate.py --tol 0.2 OPBENCH_r05.json OPBENCH_r06.json
+    python tools/bench_gate.py PREV.json CUR.json
+    python tools/bench_gate.py --tol 0.2 PREV_OPBENCH.json CUR_OPBENCH.json
     python tools/bench_gate.py --metrics jit.trace vjp_cache_hit_rate A B
 
 Exits nonzero when any gated metric regressed by more than ``--tol``
@@ -251,7 +251,7 @@ def _scalar_blocks(doc: dict, metrics: Dict[str, str],
                    prefix: str = "") -> Dict[str, dict]:
     """Dicts anywhere in the JSON that carry a gated metric as a direct
     scalar key (bench.py's serving rungs live at the document root, or
-    under a ``parsed`` wrapper in archived BENCH_r*.json files)."""
+    under a ``parsed`` wrapper in an archived record)."""
     out: Dict[str, dict] = {}
     if not isinstance(doc, dict):
         return out

@@ -322,6 +322,31 @@ def _assign_tenants(reqs, args, rng):
             for p, g in reqs]
 
 
+def failed_requests(done, max_new):
+    """Requests a reported result must not hide: terminal state
+    ``error`` (the scheduler contains a step failure per request, so a
+    broken program ends as ``error`` with an empty stream while
+    ``run()`` returns normally), or ``ok`` with a token count other
+    than ``max_new`` (no request here sets an eos). Deadline expiry
+    and shedding are outcomes of the load, not failures."""
+    return [(r.id, r.state, len(r.generated),
+             type(r.error).__name__ if r.error is not None else None)
+            for r in done
+            if r.state == "error"
+            or (r.state == "ok" and len(r.generated) != max_new)]
+
+
+def require_served(done, max_new, what):
+    """Exit non-zero — after the JSON line, never instead of a loud
+    message — when any request failed; see ``failed_requests``."""
+    bad = failed_requests(done, max_new)
+    if bad:
+        print(f"serve_bench {what}: {len(bad)} request(s) failed "
+              f"(id, state, n_tokens, error): {bad[:8]}",
+              file=sys.stderr)
+        sys.exit(1)
+
+
 def drive(eng, reqs, max_new, deadline_ms=None):
     """Submit on a background thread at the Poisson arrival times;
     run the scheduler loop here until every submitted request reaches
@@ -659,6 +684,12 @@ def run_fleet(args):
     out.update(_usage_keys(router=router))
     out.update(tele_out)
     ok = True
+    bad = failed_requests(finished, args.max_new)
+    if bad:
+        print(f"serve_bench --fleet: {len(bad)} request(s) failed "
+              f"(id, state, n_tokens, error): {bad[:8]}",
+              file=sys.stderr)
+        ok = False
     if drainer is not None:
         h = stats.histogram("serve.step.migration_ms")
         st = drainer[1]
@@ -1147,7 +1178,7 @@ def run_chaos(args, reqs, base_rids, base_done, base_goodput):
     return out, ok
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser(
         description="Poisson-load serving benchmark (SLO rungs)")
     ap.add_argument("--streams", type=int, default=8,
@@ -1313,7 +1344,7 @@ def main():
                          "run virtual devices are provisioned")
     ap.add_argument("--no-lint", action="store_true",
                     help="skip the tpu_lint preflight gate")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.long_context and args.prompt_mix == "8,32,96":
         # CPU-sized long mix (a chip run passes its own, e.g.
         # 2048,8192,16384 via bench.py --serve-long); long prompts +
@@ -1358,7 +1389,7 @@ def main():
                   "the grouped delta path is paying per-adapter "
                   "cost)", file=sys.stderr)
             sys.exit(1)
-        return
+        return 0
 
     if args.fleet and args.fleet > 1 and args.disagg:
         out, disagg_ok = run_disagg(args)
@@ -1370,7 +1401,7 @@ def main():
                   "beat the symmetric fleet's TTFT p99 / goodput)",
                   file=sys.stderr)
             sys.exit(1)
-        return
+        return 0
 
     if args.fleet and args.fleet > 1:
         out, fleet_ok = run_fleet(args)
@@ -1383,7 +1414,7 @@ def main():
                   "streamed, decode made no progress during the "
                   "drain, or a request was lost)", file=sys.stderr)
             sys.exit(1)
-        return
+        return 0
 
     eng, lens = build_engine(args)
     rng = np.random.RandomState(args.seed)
@@ -1398,6 +1429,7 @@ def main():
             warm.append((np.full(
                 (args.system_prompt + lens[0],), 1, np.int32), 0.0))
         drive(eng, warm, args.max_new)
+        require_served(eng.finished, args.max_new, "warm-up")
         eng.finished.clear()
         eng.action_log.clear()
         eng.slo_monitor.reset()
@@ -1526,7 +1558,11 @@ def main():
               "(survivor parity / goodput bound / dump survival / "
               "site coverage)", file=sys.stderr)
         sys.exit(1)
+    if not args.chaos:
+        # (--chaos injects faults on purpose; its pins judge that run)
+        require_served(done, args.max_new, "load run")
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())
