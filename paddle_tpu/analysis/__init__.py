@@ -1,9 +1,8 @@
 """paddle_tpu.analysis — static analysis for TPU kernels and traced
 code, runnable entirely on CPU.
 
-Chip time is the scarcest resource in this repo (a cold s2048 compile
-alone is ~25 min); this package proves on CPU the properties that
-otherwise only fail on hardware:
+Chip time is the scarcest resource in this repo; this package proves
+on CPU the properties that otherwise only fail on hardware:
 
 - **Pass 1 — kernel geometry** (:mod:`.geometry` over :mod:`.audit` /
   :mod:`.sites`): every ``pallas_call`` launch spec is shim-recorded

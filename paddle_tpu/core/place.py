@@ -44,14 +44,11 @@ class Place:
         if self.device_type == "cpu":
             devs = _devices_for("cpu")
         else:
-            # a 'tpu' place is the live accelerator platform. With no
-            # accelerator (the virtual-device CPU test harness) it is a
-            # host device: ``set_device("tpu")`` in reference-style user
-            # code must keep working there. Nothing measured reads a
-            # place — benchmarks and chip_smoke.py check
-            # ``jax.devices()[0].platform`` themselves and refuse a CPU.
+            # a 'tpu' place is the live accelerator platform; with no
+            # accelerator there is no device for it (the error below) —
+            # it is never quietly a host device.
             plat = _accelerator_platform()
-            devs = _devices_for(plat) if plat else _devices_for("cpu")
+            devs = _devices_for(plat) if plat else ()
         if not devs:
             raise RuntimeError(f"no devices for place {self}")
         return devs[self.device_id % len(devs)]

@@ -42,44 +42,32 @@ DEFAULT_COMPILE_CACHE_DIR = os.path.join(
         os.path.abspath(__file__)))), ".jax_cache")
 
 
-def _default_cache_dir(platforms):
-    """The in-code default directory for a run whose ``jax_platforms``
-    setting is ``platforms``: ``DEFAULT_COMPILE_CACHE_DIR``, except for
-    a run pinned to the CPU backend (tests, rehearsals) — XLA:CPU
-    reloads its cached AOT results with machine-feature errors
-    ("could lead to execution errors such as SIGILL", observed PR 24)
-    and a CPU compile is nothing a chip run can reuse."""
-    return None if platforms == "cpu" else DEFAULT_COMPILE_CACHE_DIR
-
-
 def setup_compile_cache():
-    """Turn on JAX's persistent compilation cache; returns its directory
-    (None where no cache is placed).
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
     The directory is placed from OUTSIDE: where the standard
     ``JAX_COMPILATION_CACHE_DIR`` variable is set, JAX already uses it
     and nothing is set in code. Where it is not,
     ``DEFAULT_COMPILE_CACHE_DIR`` (``<checkout>/.jax_cache``, listed in
-    ``.gitignore``) is used — see ``_default_cache_dir`` for the one
-    exception. Compiled executables are written to disk and re-loaded
-    by later processes, so a warm run skips the XLA compiles the cold
-    run paid. Called once at ``import paddle_tpu``. The
-    ``compile.persistent_cache`` gauge records whether a cache is
-    active, so a compile-seconds histogram says which regime — cold or
+    ``.gitignore``) is used. Compiled executables are written to disk
+    and re-loaded by later processes, so a warm run skips the XLA
+    compiles the cold run paid. Called once at ``import paddle_tpu``.
+    A run that wants no cache (the tests: ``tests/conftest.py``)
+    switches JAX's own ``jax_enable_compilation_cache`` off; the
+    ``compile.persistent_cache`` gauge records whether the cache is on,
+    so a compile-seconds histogram says which regime — cold or
     cache-warm — it was measured under."""
     from ..profiler import stats as _stats
 
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
-        path = _default_cache_dir(jax.config.jax_platforms)
-        if path is None:
-            _stats.set_gauge("compile.persistent_cache", 0)
-            return None
+        path = DEFAULT_COMPILE_CACHE_DIR
         jax.config.update("jax_compilation_cache_dir", path)
     # cache even fast-compiling programs: the decode/prefill serving
     # programs are individually cheap but numerous
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    _stats.set_gauge("compile.persistent_cache", 1)
+    _stats.set_gauge("compile.persistent_cache",
+                     int(jax.config.jax_enable_compilation_cache))
     return path
 
 

@@ -10,8 +10,10 @@ Must run before jax initializes a backend (the platform is also set
 through jax.config, so an outer JAX_PLATFORMS cannot redirect the tests).
 
 The persistent compile cache (paddle_tpu.device.setup_compile_cache) is
-switched off for the tests: a test run must not depend on what an
-earlier run left in ``.jax_cache``, nor fill the checkout with it.
+switched off for the tests and, through the environment, for every
+child they start: a test run must not depend on what an earlier run
+left in ``.jax_cache``, nor fill the checkout with it (and XLA:CPU
+reloads its cached executables with a screen of machine-feature errors).
 
 Shared mesh fixtures (session-scoped — the mesh objects are immutable
 value types): ``virtual_devices`` (the 8 CPU devices), ``mesh8`` /
@@ -25,11 +27,11 @@ os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
 ).strip()
 os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_enable_compilation_cache", False)
 
 import pytest  # noqa: E402
 

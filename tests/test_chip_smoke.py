@@ -54,3 +54,17 @@ def test_rehearsal_runs_every_phase_and_never_says_ok(chips, phases):
     assert last["device"]["platform"] == "cpu"
     assert last["device"]["count"] == chips
     assert '"ok": true' not in proc.stdout.splitlines()[-1]
+
+
+def test_bench_parent_survives_a_rung_that_times_out(capsys):
+    """A rung child that outlives its limit is killed and recorded as
+    that rung's error — the parent goes on to the next rung, and never
+    imports JAX itself."""
+    sys.path.insert(0, REPO)
+    try:
+        import bench
+    finally:
+        sys.path.remove(REPO)
+    out, err = bench._sub(["--probe"], 0.01)
+    assert out is None and err == "timeout after 0.01s"
+    assert "--probe FAILED" in capsys.readouterr().err

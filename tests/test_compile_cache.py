@@ -27,8 +27,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def cache_config():
     """Restore the cache directory the session runs with."""
     prev = jax.config.jax_compilation_cache_dir
+    on = jax.config.jax_enable_compilation_cache
     yield
     jax.config.update("jax_compilation_cache_dir", prev)
+    jax.config.update("jax_enable_compilation_cache", on)
 
 
 class TestCompileCachePlacement:
@@ -38,30 +40,33 @@ class TestCompileCachePlacement:
         code: whatever JAX's config holds is left as it is."""
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         jax.config.update("jax_compilation_cache_dir", "sentinel")
+        jax.config.update("jax_enable_compilation_cache", True)
         assert paddle.device.setup_compile_cache() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == "sentinel"
         assert stats.gauge("compile.persistent_cache").value == 1
 
-    def test_default_is_one_fixed_path_in_the_checkout(self):
-        from paddle_tpu.device import _default_cache_dir
-
+    def test_default_is_one_fixed_path_in_the_checkout(
+            self, monkeypatch, cache_config):
+        """Nothing given from outside: one fixed path inside the
+        checkout, whatever the platform (this run is pinned to the
+        CPU) — the path is part of the cache key."""
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         want = os.path.join(REPO, ".jax_cache")
-        # the path is part of the cache key: the same for every run
-        # that may reach the chip, however the platform is (not) named
-        for platforms in (None, "", "tpu", "tpu,cpu"):
-            assert _default_cache_dir(platforms) == want
+        for _ in range(2):
+            jax.config.update("jax_compilation_cache_dir", "sentinel")
+            assert paddle.device.setup_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
         assert paddle.device.DEFAULT_COMPILE_CACHE_DIR == want
 
-    def test_cpu_pinned_run_places_no_cache(self, monkeypatch,
-                                            cache_config):
-        """The one exception (tests, rehearsals): pinned to the CPU
-        backend and with no directory given from outside, nothing is
-        set — XLA:CPU's cached results do not reload cleanly."""
-        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-        assert jax.config.jax_platforms == "cpu"    # tests/conftest.py
-        jax.config.update("jax_compilation_cache_dir", "sentinel")
-        assert paddle.device.setup_compile_cache() is None
-        assert jax.config.jax_compilation_cache_dir == "sentinel"
+    def test_cpu_runs_switch_the_cache_off_where_they_are_set_up(
+            self, cache_config):
+        """Not a branch of setup_compile_cache: the tests (and, through
+        the environment, every child they start) run with JAX's own
+        switch off — tests/conftest.py."""
+        assert jax.config.jax_enable_compilation_cache is False
+        assert os.environ["JAX_ENABLE_COMPILATION_CACHE"] == "false"
+        # ... and the gauge says so: this run's compiles are all cold
+        paddle.device.setup_compile_cache()
         assert stats.gauge("compile.persistent_cache").value == 0
 
     def test_every_program_is_cached(self, monkeypatch, tmp_path,

@@ -293,12 +293,20 @@ class TestMemoryPass:
         # XLA:CPU re-lays the K/V page pool out for its scatter: one
         # layout-changing copy of each pool PARAMETER on entry — a
         # backend temp the program itself never asks for (the jaxpr
-        # keeps the page-major layout), so it is taken off XLA's side
-        relayout = sum(
-            4 * int(np.prod([int(d) for d in dims.split(",")]))
-            for dims in re.findall(
-                r"= f32\[([\d,]+)\]\{[\d,]+\} copy\(%cache_[kv]",
-                compiled.as_text()))
+        # keeps the page-major layout), so it is taken off XLA's side.
+        # Exactly those two copies, whole and into a non-default
+        # layout: the allowance must not quietly absorb another temp.
+        pool_k, pool_v = args[-3], args[-2]
+        dims = ",".join(str(d) for d in pool_k.shape)
+        default = ",".join(str(d) for d in reversed(range(pool_k.ndim)))
+        copies = re.findall(
+            r"= f32\[([\d,]+)\]\{([\d,]+)\} copy\(%(cache_[kv])[.\d]*\)",
+            compiled.as_text())
+        assert sorted(c[2] for c in copies) == ["cache_k", "cache_v"], \
+            copies
+        assert all(c[0] == dims and c[1] != default for c in copies), \
+            copies
+        relayout = pool_k.nbytes + pool_v.nbytes
         xla = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                + ma.temp_size_in_bytes - relayout)
         assert xla > 0
