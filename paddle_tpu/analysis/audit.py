@@ -61,6 +61,12 @@ class PallasCallRecord:
     vmem_limit_bytes: Optional[int]
     input_output_aliases: Dict[int, int]
     interpret: bool
+    # the names the device trace can show the launch under: the
+    # ``name=`` it was built with, and the innermost ``jax.named_scope``
+    # open when it was called (the compiled instruction takes the
+    # innermost of the two); None where the site gives none
+    name: Optional[str] = None
+    scope: Optional[str] = None
     # call-time avals, one per operand INCLUDING scalar-prefetch args;
     # None for operands passed as literal None (optional flash inputs)
     operands: Optional[List[Optional[Tuple[Tuple[int, ...], str]]]] = None
@@ -190,7 +196,16 @@ def _capture(kernel, args, kwargs) -> PallasCallRecord:
         input_output_aliases=dict(
             kwargs.get("input_output_aliases") or {}),
         interpret=bool(kwargs.get("interpret", False)),
+        name=kwargs.get("name"),
     )
+
+
+def _innermost_scope() -> Optional[str]:
+    from jax._src import source_info_util
+
+    scopes = [e.name for e in source_info_util.current_name_stack().stack
+              if isinstance(e, source_info_util.Scope)]
+    return scopes[-1] if scopes else None
 
 
 @contextlib.contextmanager
@@ -211,6 +226,7 @@ def record_pallas_calls():
 
         def invoke(*operands):
             rec.operands = [_aval(o) for o in operands]
+            rec.scope = _innermost_scope()
             return inner(*operands)
 
         return invoke

@@ -24,12 +24,18 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-from typing import Callable, List, Optional
+from typing import Callable, List, Optional, Tuple
 
 from .audit import PallasCallRecord, record_pallas_calls
 from .geometry import tile_padded_bytes as _B
 
-__all__ = ["KernelSite", "KERNEL_SITES", "trace_site", "trace_all_sites"]
+__all__ = ["KernelSite", "KERNEL_SITES", "KERNEL_PREFIX", "trace_site",
+           "trace_all_sites"]
+
+
+#: prefix of every kernel name the device trace shows (``name=`` of the
+#: ``pallas_call`` and the ``jax.named_scope`` around it)
+KERNEL_PREFIX = "pt_"
 
 
 @dataclasses.dataclass
@@ -39,6 +45,16 @@ class KernelSite:
     build: Callable           # () -> (fn, args) for jax.eval_shape
     expected_vmem: Optional[Callable[[], int]]  # closed-form footprint
     n_calls: int = 1          # pallas_calls the dry-trace must record
+    # the trace names of the launches the dry-trace records, in order.
+    # One name per ``pallas_call`` in the code, ``pt_`` + the site's
+    # name with dots as underscores; spelled out only where a site's
+    # trace crosses several launches (a backward pass)
+    kernels: Tuple[str, ...] = ()
+
+    def __post_init__(self):
+        if not self.kernels:
+            self.kernels = (KERNEL_PREFIX
+                            + self.name.replace(".", "_"),) * self.n_calls
 
 
 @contextlib.contextmanager
@@ -537,8 +553,9 @@ KERNEL_SITES: List[KernelSite] = [
                _build_decode_inplace_q, _expected_decode_inplace_q),
     # the stock jax flash kernel: geometry-checked but no hand block
     # list (its internals are jax's, not ours)
+    # its forward launch carries the scope opened around JAX's call
     KernelSite("attention.flash", "nn/functional/attention.py",
-               _build_flash, None),
+               _build_flash, None, kernels=("pt_flash_mha_fwd",)),
     KernelSite("flash_varlen.packed_fwd",
                "nn/functional/flash_varlen.py",
                _build_flash_varlen_fwd, _expected_flash_varlen_fwd),
@@ -546,7 +563,10 @@ KERNEL_SITES: List[KernelSite] = [
     KernelSite("flash_varlen.packed_bwd",
                "nn/functional/flash_varlen.py",
                _build_flash_varlen_bwd, _expected_flash_varlen_bwd,
-               n_calls=3),
+               n_calls=3,
+               kernels=("pt_flash_varlen_packed_fwd",
+                        "pt_flash_varlen_packed_dq",
+                        "pt_flash_varlen_packed_dkv")),
     KernelSite("flash_varlen.paged", "nn/functional/flash_varlen.py",
                _build_flash_varlen_paged, _expected_flash_varlen_paged),
     # ragged grouped-GEMM MoE (ISSUE 15): fwd, and the grad trace's
@@ -555,7 +575,9 @@ KERNEL_SITES: List[KernelSite] = [
                _build_grouped_gemm_fwd, _expected_grouped_gemm_fwd),
     KernelSite("grouped_gemm.bwd", "nn/functional/grouped_gemm.py",
                _build_grouped_gemm_bwd, _expected_grouped_gemm_bwd,
-               n_calls=4),
+               n_calls=4,
+               kernels=("pt_grouped_gemm_fwd",) * 3
+               + ("pt_grouped_gemm_dw",)),
     # batched multi-LoRA delta (ISSUE 18): one ragged launch carrying
     # every adapter's x·A·B for an adapter-sorted chunk
     KernelSite("lora.delta", "nn/functional/lora.py",

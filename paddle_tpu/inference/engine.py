@@ -11,6 +11,7 @@ device program per token with zero host round-trips in the stack.
 from __future__ import annotations
 
 import itertools
+import time
 from typing import Optional
 
 import jax
@@ -21,6 +22,7 @@ from ..core.tensor import Tensor
 from ..incubate.nn.fused_transformer import (
     FusedMultiTransformer, PagedKV, rope_table)
 from ..nn.layer_base import Layer
+from ..profiler import RecordEvent
 from ..profiler import roofline as _roofline
 from ..profiler import stats as _stats
 from .kv_cache import BlockKVCacheManager, gather_rows, restore_scatter_jit
@@ -715,6 +717,13 @@ class ContinuousBatchingEngine:
         # token accounting (wasted chunk tails, spec accepts) charges
         # the owning request; None = one attribute test
         self._usage = None
+        # the clock that bounds a step's plan / run / emit phases and
+        # the stamps of the last step that reached its program call,
+        # (run start, run end), None where none did; the serving
+        # frontend installs its clock seam and reads the stamps into
+        # the ``serve.step.{plan,run,emit}_ms`` histograms
+        self._now = time.monotonic
+        self._run_ts = None
         # slot state
         self._slots: list = [None] * self.max_batch   # GenRequest or None
         self._lens = np.zeros((self.max_batch,), np.int64)
@@ -757,6 +766,28 @@ class ContinuousBatchingEngine:
         if self._spec is not None:
             return self._spec_step()
         k = self.decode_chunk
+        with RecordEvent("serve.plan"):
+            plan = self._plan_decode(k)
+        if plan is None:
+            return []
+        active, program, lead, tail = plan
+        t_run0 = self._now()
+        with RecordEvent("serve.run", program=program.name):
+            t0 = time.perf_counter()
+            toks, self._ck, self._cv = program(
+                *lead, self._ck, self._cv, *tail)
+            toks_np = np.asarray(toks)
+        self._run_ts = (t_run0, self._now())
+        # synced by the fetch above — an honest per-chunk roofline
+        _roofline.analyze(program.name, time.perf_counter() - t0)
+        with RecordEvent("serve.emit"):
+            return self._emit_decode(toks_np, active, k)
+
+    def _plan_decode(self, k: int):
+        """The host's work before a decode chunk's program call: page
+        growth (which may evict or preempt), block tables, operands.
+        Returns (active slots, the program, operands before the pool,
+        operands after it), or None where no slot is left to decode."""
         active = [i for i, r in enumerate(self._slots) if r is not None]
         fi = self._faults
         if fi is not None and active:
@@ -782,7 +813,7 @@ class ContinuousBatchingEngine:
                 continue  # slot preempted (serving override)
         active = [i for i in active if self._slots[i] is not None]
         if not active:
-            return []
+            return None
         tables = self._mgr.block_tables(
             [("slot", i) for i in range(self.max_batch)],
             self._pages_per_seq, allow_missing=True)
@@ -797,8 +828,6 @@ class ContinuousBatchingEngine:
 
         cur = np.where([r is not None for r in self._slots],
                        self._lens - 1, 0).astype(np.int64)
-        import time as _time
-
         lnf_s, lnf_b = self._gen._lnf()
         a_slots, a_banks = self._adapter_operands(active)
         adaptered = a_banks is not None
@@ -808,18 +837,17 @@ class ContinuousBatchingEngine:
             # per executed decode step (4 projections x L layers x k)
             _stats.inc("lora.grouped_launches",
                        4 * self.model.stack.num_layers * k)
-        t0 = _time.perf_counter()
-        toks, self._ck, self._cv = self._gen._get_decode_k(
-            k, adaptered=adaptered)(
-            self._gen._weights(), self._gen._embed(),
-            self._gen._head_t, lnf_s, lnf_b,
-            jnp.asarray(self._last_tok, jnp.int32),
-            jnp.asarray(cur, jnp.int32),
-            self._ck, self._cv, tables, *extra)
-        toks_np = np.asarray(toks)
-        # synced by the fetch above — an honest per-chunk roofline
-        _roofline.analyze(self._gen._decode_rung(k, adaptered),
-                          _time.perf_counter() - t0)
+        lead = (self._gen._weights(), self._gen._embed(),
+                self._gen._head_t, lnf_s, lnf_b,
+                jnp.asarray(self._last_tok, jnp.int32),
+                jnp.asarray(cur, jnp.int32))
+        return (active, self._gen._get_decode_k(k, adaptered=adaptered),
+                lead, (tables, *extra))
+
+    def _emit_decode(self, toks_np, active, k: int):
+        """Fetched tokens -> requests: validation, ``on_token``
+        callbacks, finish hooks, page release. Returns the requests
+        that finished."""
         # overridable token filter: runs BEFORE any request mutates,
         # so a validation raise (serving corruption detection) leaves
         # every slot exactly as it was and a chunk re-run is clean
@@ -898,7 +926,13 @@ class ContinuousBatchingEngine:
         active = [i for i in active if self._slots[i] is not None]
         if not active:
             return []
-        return self._spec.run_round(self, active, win)
+        # the round drafts, verifies and emits in one piece: all of it
+        # is booked as the step's run phase
+        t_run0 = self._now()
+        with RecordEvent("serve.run", program=self._spec._rung()):
+            out = self._spec.run_round(self, active, win)
+        self._run_ts = (t_run0, self._now())
+        return out
 
     def run(self):
         """Drain: step until every submitted request finishes."""

@@ -351,9 +351,10 @@ def _packed_fwd_pallas(qt, kt, vt, bm: BlockMap, scale: float,
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ])
-    with _enable_x64(False):
+    with _enable_x64(False), jax.named_scope("pt_flash_varlen_packed_fwd"):
         out, lse = pl.pallas_call(
             kernel,
+            name="pt_flash_varlen_packed_fwd",
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct((h, tq, d), jnp.float32),
@@ -484,9 +485,10 @@ def _packed_dq_pallas(qt, kt, vt, dot_, lse, delta, bm: BlockMap,
             pltpu.SemaphoreType.DMA((2,)),
         ])
     ld = jnp.stack([lse, delta])                         # [2, h, tq]
-    with _enable_x64(False):
+    with _enable_x64(False), jax.named_scope("pt_flash_varlen_packed_dq"):
         dq = pl.pallas_call(
             kernel,
+            name="pt_flash_varlen_packed_dq",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((h, tq, d), jnp.float32),
             compiler_params=pltpu.CompilerParams(
@@ -633,9 +635,10 @@ def _packed_dkv_pallas(qt, kt, vt, dot_, lse, delta, bm: BlockMap,
             pltpu.SemaphoreType.DMA((2,)),
         ])
     ld = jnp.stack([lse, delta])                         # [2, h, tq]
-    with _enable_x64(False):
+    with _enable_x64(False), jax.named_scope("pt_flash_varlen_packed_dkv"):
         dk, dv = pl.pallas_call(
             kernel,
+            name="pt_flash_varlen_packed_dkv",
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct((h, tk, d), jnp.float32),
@@ -1025,9 +1028,10 @@ def _paged_fwd_pallas(qt, key_cache, value_cache, tables, start, klen,
             pltpu.SemaphoreType.DMA((2, npp)),
             pltpu.SemaphoreType.DMA((2, npp)),
         ])
-    with _enable_x64(False):
+    with _enable_x64(False), jax.named_scope("pt_flash_varlen_paged"):
         out = pl.pallas_call(
             kernel,
+            name="pt_flash_varlen_paged",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, n_q, c, d), jnp.float32),
             compiler_params=pltpu.CompilerParams(

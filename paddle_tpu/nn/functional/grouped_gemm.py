@@ -207,9 +207,10 @@ def _grouped_fwd_pallas(x_pad, w3, b3, gids, tids, lo, hi, bm, bn,
         out_specs=pl.BlockSpec((bm, bn),
                                lambda j, u, g, t, lo_, hi_: (t[u], j)),
         scratch_shapes=[])
-    with _enable_x64(False):
+    with _enable_x64(False), jax.named_scope("pt_grouped_gemm_fwd"):
         out = pl.pallas_call(
             kernel,
+            name="pt_grouped_gemm_fwd",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((t_pad, N), jnp.float32),
             compiler_params=pltpu.CompilerParams(
@@ -348,9 +349,10 @@ def _grouped_dw(x_pad, dz_pad, E, gids, tids, lo, hi, bm, bn, backend):
         interpret=(backend == "interpret"))
     from jax.experimental.pallas import tpu as pltpu
 
-    with _enable_x64(False):
+    with _enable_x64(False), jax.named_scope("pt_grouped_gemm_dw"):
         return pl.pallas_call(
             kernel,
+            name="pt_grouped_gemm_dw",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((E, K, N), jnp.float32),
             compiler_params=pltpu.CompilerParams(

@@ -149,9 +149,10 @@ def _lora_fwd_pallas(x_pad, a3, b3, gids, tids, lo, hi, bm, bn,
         out_specs=pl.BlockSpec((bm, bn),
                                lambda j, u, g, t, lo_, hi_: (t[u], j)),
         scratch_shapes=[])
-    with _enable_x64(False):
+    with _enable_x64(False), jax.named_scope("pt_lora_delta"):
         out = pl.pallas_call(
             kernel,
+            name="pt_lora_delta",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((t_pad, N), jnp.float32),
             compiler_params=pltpu.CompilerParams(

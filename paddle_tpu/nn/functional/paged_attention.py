@@ -240,9 +240,10 @@ def _fused_paged(q, key_cache, value_cache, seq_lens, block_tables):
     # x64 off for the whole kernel trace: the package enables x64
     # globally, and weak-typed python scalars become f64/i64 inside the
     # kernel, which Mosaic cannot legalize
-    with _enable_x64(False):
+    with _enable_x64(False), jax.named_scope("pt_paged_attention_fused"):
         out = pl.pallas_call(
             kernel,
+            name="pt_paged_attention_fused",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, n_q, d), jnp.float32),
             compiler_params=pltpu.CompilerParams(
@@ -442,9 +443,10 @@ def _stream_paged(q, key_cache, value_cache, seq_lens, block_tables,
     # x64 off for the whole trace (x64 is on globally; weak-typed
     # python scalars would become f64/i64 inside the kernel); interpret
     # mode off-TPU so the kernel's numerics are testable on CPU
-    with _enable_x64(False):
+    with _enable_x64(False), jax.named_scope("pt_paged_attention_stream"):
         out = pl.pallas_call(
             kernel,
+            name="pt_paged_attention_stream",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((n_kv, bg, d), jnp.float32),
             # double-buffered multi-MB stream chunks overflow the
@@ -729,9 +731,11 @@ def paged_decode_attention_inplace(q, new_k, new_v, key_cache,
             pltpu.SemaphoreType.DMA((b, 2)),
             pltpu.SemaphoreType.DMA((b, 2)),
         ])
-    with _enable_x64(False):
+    with _enable_x64(False), \
+            jax.named_scope("pt_paged_attention_decode_inplace"):
         out, ck, cv = pl.pallas_call(
             kernel,
+            name="pt_paged_attention_decode_inplace",
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct((n_kv, bg, d), jnp.float32),
@@ -1225,9 +1229,11 @@ def paged_decode_attention_inplace_q(q, new_k, new_v, kq_pool, ks_plane,
             pltpu.SemaphoreType.DMA((b, 2)),
             pltpu.SemaphoreType.DMA((b, 2)),
         ])
-    with _enable_x64(False):
+    with _enable_x64(False), \
+            jax.named_scope("pt_paged_attention_decode_inplace_q"):
         out, kq2, vq2, ks2, vs2 = pl.pallas_call(
             kernel,
+            name="pt_paged_attention_decode_inplace_q",
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct((n_kv, bg, d), jnp.float32),

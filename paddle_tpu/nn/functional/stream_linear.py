@@ -203,9 +203,10 @@ def _stream_linear_a8w8(x_q, x_scale, w3, s3, b3, layer, activation,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((Mp, bn), lambda j, l: (0, j)),
         scratch_shapes=[])
-    with _enable_x64(False):
+    with _enable_x64(False), jax.named_scope("pt_stream_linear_a8w8"):
         out = pl.pallas_call(
             kernel,
+            name="pt_stream_linear_a8w8",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
             compiler_params=pltpu.CompilerParams(
@@ -434,9 +435,10 @@ def stream_linear(x, w, layer=None, bias=None, scale=None,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((Mp, bn), lambda j, l: (0, j)),
         scratch_shapes=[])
-    with _enable_x64(False):
+    with _enable_x64(False), jax.named_scope("pt_stream_linear_bf16"):
         out = pl.pallas_call(
             kernel,
+            name="pt_stream_linear_bf16",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((Mp, N), out_dtype),
             compiler_params=pltpu.CompilerParams(
@@ -674,9 +676,10 @@ def _stream_layer_tail_kernel(att, h, wo3, w13, w23, so3, s13, s23,
             pltpu.VMEM((Mp, d), cdtype),   # s_hn: LN'd matmul input
             pltpu.VMEM((Mp, d), f32),      # s_acc: FFN2 accumulator
         ])
-    with _enable_x64(False):
+    with _enable_x64(False), jax.named_scope("pt_stream_linear_layer_tail"):
         outs = pl.pallas_call(
             kernel,
+            name="pt_stream_linear_layer_tail",
             grid_spec=grid_spec,
             out_shape=out_shapes,
             compiler_params=pltpu.CompilerParams(

@@ -6,8 +6,10 @@ python/paddle/profiler/profiler.py — ``Profiler`` with states
 ``export_chrome_tracing:215``; C++ host tracer
 platform/profiler/host_tracer.cc RecordEvent spans). Two layers:
 
-- host spans: ``RecordEvent`` context managers collected into a tree,
-  exported in the chrome-trace JSON format the reference emits;
+- host spans: ``RecordEvent`` context managers, written into the
+  ``jax.profiler`` trace (on the device planes' clock) whenever a
+  session is active and collected for the chrome-trace JSON export the
+  reference emits;
 - device trace: ``jax.profiler`` start/stop around the profiled window
   (XLA's own profiler session → TensorBoard/XPlane dump directory);
 - runtime counters: the process-wide ``profiler.stats`` registry
@@ -25,8 +27,10 @@ import time
 from enum import Enum
 from typing import Callable, List, Optional
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 __all__ = ["Profiler", "ProfilerState", "ProfilerTarget", "RecordEvent",
-           "make_scheduler", "export_chrome_tracing",
+           "SPAN_PREFIX", "make_scheduler", "export_chrome_tracing",
            "load_profiler_result", "dump_rank",
            "start_span_capture", "stop_span_capture"]
 
@@ -98,33 +102,75 @@ def stop_span_capture(sink: List[dict]) -> List[dict]:
     return sink
 
 
-class RecordEvent:
-    """Host span (reference RecordEvent, event_tracing.h): context
-    manager / begin-end pair collected into the chrome trace."""
+#: prefix of every program span in the profiler's trace, so that ONE
+#: prefix picks the program's spans out of a trace that also holds a
+#: harness's own (the benchmark's are ``bench.``)
+SPAN_PREFIX = "pt."
 
-    def __init__(self, name: str, event_type=None):
+
+class RecordEvent:
+    """The program's one span (reference RecordEvent, event_tracing.h):
+    context manager / begin-end pair.
+
+    It lands in two places. Under ANY active ``jax.profiler`` session it
+    is a ``TraceAnnotation`` named ``SPAN_PREFIX + name`` in the
+    ``.xplane.pb``, on the clock of the device planes, with ``ids``
+    (``step=``, ``rid=``, ``program=``) as its arguments; ``step_num=``
+    makes it a ``StepTraceAnnotation`` (the profiler's own step
+    marker). And while a ``Profiler`` window or a
+    ``start_span_capture`` sink is open it is appended there as a
+    chrome-trace "X" event under its bare name. With neither it costs
+    two clock reads and one no-op ``TraceMe``; nothing is appended.
+    ``dur_ms`` is the span's own length, for a histogram that must
+    agree with the span."""
+
+    __slots__ = ("name", "_ids", "_t0", "_t1", "_ann")
+
+    def __init__(self, name: str, event_type=None, **ids):
         self.name = name
-        self._t0 = None
+        self._ids = ids
+        self._t0 = self._t1 = self._ann = None
 
     def begin(self):
+        cls = StepTraceAnnotation if "step_num" in self._ids \
+            else TraceAnnotation
+        self._ann = cls(SPAN_PREFIX + self.name, **self._ids)
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
 
     def end(self):
-        if self._t0 is None or not (_SPANS.enabled or _SINKS):
+        if self._t0 is None:
             return
-        t1 = time.perf_counter_ns()
+        t1 = self._t1 = time.perf_counter_ns()
+        self._ann.__exit__(None, None, None)
+        self._ann = None
+        if not (_SPANS.enabled or _SINKS):
+            return
         ev = {
             "name": self.name, "ph": "X", "pid": os.getpid(),
             "tid": threading.get_ident() % 2 ** 31,
             "ts": self._t0 / 1e3, "dur": (t1 - self._t0) / 1e3,
             "cat": "host",
         }
+        if self._ids:
+            ev["args"] = dict(self._ids)
         if _SPANS.enabled:
             _SPANS.events.append(ev)
         if _SINKS:
             with _SINK_LOCK:
                 for s in _SINKS:
                     s.append(ev)
+
+    def annotate(self, **ids):
+        """Identifiers that are known only once the span is open (the
+        action a step picked)."""
+        self._ids.update(ids)
+        self._ann.set_metadata(**ids)
+
+    @property
+    def dur_ms(self) -> float:
+        """Milliseconds between ``begin()`` and ``end()``."""
+        return (self._t1 - self._t0) / 1e6
 
     def __enter__(self):
         self.begin()

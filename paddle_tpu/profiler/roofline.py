@@ -27,27 +27,29 @@ Three cooperating pieces:
   cost model is captured WITHOUT a second compilation; falls back to
   the plain jitted call path on any AOT mismatch.
 
-Honesty note: rates are only as good as the wall time fed in. The jit
-layers observe per-call dispatch wall time (accurate on the synchronous
-CPU backend and for the chunk-synced decode loop); the bench entry
-points (bench.py, tools/*_profile.py) re-``analyze`` with their
-properly synced timings, which overwrite the gauges and are what lands
-in BENCH_*.json.
+Honesty note: rates are only as good as the wall time fed in, and the
+program never feeds one that is not synced: ``AotProgram`` records cost
+at compile time and opens a ``pt.program:<name>`` span around each call
+(signature + enqueue; the device's time is in the profiler's trace),
+the serving step ``analyze``s with the wall time its token fetch
+synced, and the bench entry points (bench.py, tools/*_profile.py)
+``analyze`` with their own synced timings, which are what lands in
+BENCH_*.json.
 """
 from __future__ import annotations
 
 import functools
 import os
-import time
 from typing import Dict, Optional
 
 import jax
 
 from . import stats as _stats
+from .profiler import RecordEvent
 
 __all__ = [
     "device_peaks", "program_cost",
-    "record_program", "analyze", "observe_wall", "report", "reset",
+    "record_program", "analyze", "report", "reset",
     "RooflineResult", "AotProgram", "format_report",
 ]
 
@@ -63,7 +65,7 @@ _PROGRAMS: Dict[str, dict] = {}
 
 @functools.lru_cache(maxsize=1)
 def _default_device():
-    # analyze() runs once per jitted call (observe_wall): look it up once
+    # analyze() runs once per synced step: look it up once
     return jax.devices()[0]
 
 
@@ -222,17 +224,6 @@ def analyze(name: str, wall_s: float, *, calls: int = 1,
     return res
 
 
-def observe_wall(name: str, wall_s: float, *, calls: int = 1) -> None:
-    """Cheap per-call hook for the jit layers: record the dispatch wall
-    time into a histogram and refresh the roofline gauges. On an async
-    backend this measures dispatch, not execution — bench entry points
-    re-``analyze`` with synced timings (see module docstring)."""
-    if not _stats.is_enabled():
-        return
-    _stats.observe("roofline.wall_us", wall_s * 1e6 / max(calls, 1))
-    analyze(name, wall_s, calls=calls)
-
-
 def report() -> dict:
     """JSON-able copy of the per-program roofline table (programs with
     recorded cost; rates present once a wall time was analyzed)."""
@@ -296,6 +287,13 @@ class AotProgram:
         self._failed: set = set()
 
     def __call__(self, *args):
+        # signature + enqueue of the compiled program, under the
+        # wrapper's own stable name (the device trace calls the same
+        # program ``jit__<function>``: PERF.md 7.2)
+        with RecordEvent("program:" + self.name):
+            return self._call(*args)
+
+    def _call(self, *args):
         try:
             sig = _aot_signature(args)
         except Exception:
@@ -313,10 +311,7 @@ class AotProgram:
                 exe = None
         if exe is not None:
             try:
-                t0 = time.perf_counter()
-                out = exe(*args)
-                observe_wall(self.name, time.perf_counter() - t0)
-                return out
+                return exe(*args)
             except Exception:
                 self._exes.pop(sig, None)
                 self._failed.add(sig)

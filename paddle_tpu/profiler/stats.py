@@ -98,7 +98,12 @@ Conventions for the built-in instrumentation (all optional reading):
   decode_chunk,spec_verify,migration,host_overhead,total}_ms``
   histograms on the injectable serving clock — the phase sums equal
   the step wall time (host_overhead is the residual), so "where did
-  the step go" is answerable from telemetry alone
+  the step go" is answerable from telemetry alone; the work phase
+  once more as ``serve.step.{plan,run,emit}_ms`` (``run`` is the
+  program call until its token fetch returned)
+- ``jit.train_step.*_ms``      host phases of one ``TrainStep``
+  call (jit/train_step.py): ``args``, ``dispatch``, ``rebind``, from
+  the stamps of the ``pt.train.*`` spans
 - ``telemetry.*``              the continuous time-series sampler's
   own accounting (profiler/timeseries.py):
   ``telemetry.ticks`` sampler passes and ``telemetry.tick_us`` the

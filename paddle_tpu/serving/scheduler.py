@@ -81,6 +81,7 @@ import numpy as np
 from ..core.flags import flag as _flag
 from ..incubate.nn.fused_transformer import PagedKV
 from ..inference.engine import ContinuousBatchingEngine, FusedCausalLM
+from ..profiler import RecordEvent
 from ..profiler import roofline as _roofline
 from ..profiler import stats as _stats
 from . import faults as _faults
@@ -231,6 +232,8 @@ class ServingEngine(ContinuousBatchingEngine):
         if _flag("usage_ledger"):
             self.usage = UsageLedger()
         self._usage = self.usage  # engine/speculative token hooks
+        self._now = _faults.now   # plan / run / emit stamps: the seam
+        self._n_steps = 0         # ``step=`` of the pt.serve.step span
         self.last_crash_dump: Optional[str] = None
         self.prefix_cache: Optional[PrefixCache] = None
         if slo.prefix_cache:
@@ -531,23 +534,33 @@ class ServingEngine(ContinuousBatchingEngine):
 
         Each completed step's wall time is ATTRIBUTED into phase
         histograms via the clock seam (``serve.step.{admit,
-        prefill_chunk,decode_chunk,spec_verify}_ms`` plus the
-        ``host_overhead_ms`` residual — see ``_observe_step``);
+        prefill_chunk,decode_chunk,spec_verify}_ms``, the work phase
+        once more as ``{plan,run,emit}_ms``, plus the
+        ``host_overhead_ms`` residual — see ``_observe_step``), and
+        every phase is a ``pt.serve.*`` span in the profiler's trace;
         recovery early-returns skip attribution so the phase sums
         stay an exact partition of the observed ``total_ms``."""
+        self._n_steps += 1
+        with RecordEvent("serve.step", step=self._n_steps) as span:
+            return self._step(span)
+
+    def _step(self, span):
         ts0 = _faults.now()
-        self._drain_inbox()
-        self._expire_deadlines()
-        try:
-            self._admit()
-        except Exception as e:
-            self._recover_admit(e)
-        self.slo_monitor.update_gauges(
-            len(self.waiting) + len(self._inbox), self.num_active,
-            len(self._prefilling), self.max_batch)
-        self._watchdog_tick()
+        self._run_ts = None
+        with RecordEvent("serve.admit"):
+            self._drain_inbox()
+            self._expire_deadlines()
+            try:
+                self._admit()
+            except Exception as e:
+                self._recover_admit(e)
+            self.slo_monitor.update_gauges(
+                len(self.waiting) + len(self._inbox), self.num_active,
+                len(self._prefilling), self.max_batch)
+            self._watchdog_tick()
         ts_admit = _faults.now()
         action = self._pick_action()
+        span.annotate(action=action)
         if action == "prefill":
             self.action_log.append("prefill")
             try:
@@ -621,10 +634,18 @@ class ServingEngine(ContinuousBatchingEngine):
         drives decode; migration is timed by the router around slot
         export/import), and host_overhead — the RESIDUAL between the
         work phase's end and step exit (token bookkeeping, tpot
-        observes, finish hooks). admit + phase + host_overhead ==
-        total EXACTLY per step, so the histograms answer "where did
-        the step go" with no unaccounted remainder. All stamps come
-        from the clock seam — ManualClock tests see exact values."""
+        observes, finish hooks). The work phase splits once more, at
+        the two stamps its program call left in ``_run_ts``: plan
+        (action picked .. operands built), run (the program call until
+        its token fetch returned: the only interval in which the
+        device has this step's work) and emit (tokens -> requests,
+        callbacks, finish hooks, page release); a work phase that
+        never reached its program (a stalled chunk) is all plan.
+        admit + plan + run + emit + host_overhead == total EXACTLY
+        per step, and plan + run + emit is the work phase, so the
+        histograms answer "where did the step go" with no unaccounted
+        remainder. All stamps come from the clock seam — ManualClock
+        tests see exact values."""
         if not _stats.is_enabled():
             return
         ts_end = _faults.now()
@@ -632,6 +653,12 @@ class ServingEngine(ContinuousBatchingEngine):
         if phase is not None:
             _stats.observe("serve.step.%s_ms" % phase,
                            (ts_work - ts_admit) * 1e3)
+            t_run0, t_run1 = self._run_ts or (ts_work, ts_work)
+            _stats.observe("serve.step.plan_ms",
+                           (t_run0 - ts_admit) * 1e3)
+            _stats.observe("serve.step.run_ms", (t_run1 - t_run0) * 1e3)
+            _stats.observe("serve.step.emit_ms",
+                           (ts_work - t_run1) * 1e3)
         _stats.observe("serve.step.host_overhead_ms",
                        (ts_end - ts_work) * 1e3)
         _stats.observe("serve.step.total_ms", (ts_end - ts0) * 1e3)
@@ -1417,6 +1444,32 @@ class ServingEngine(ContinuousBatchingEngine):
         on prompt completion the request joins the decode batch with
         its first token emitted. Returns requests finished this step
         (a one-token request can finish straight out of prefill)."""
+        with RecordEvent("serve.plan"):
+            plan = self._plan_prefill()
+        if plan is None:
+            return []
+        i, stt, c, n, program, lead, tail = plan
+        t_run0 = self._now()
+        with RecordEvent("serve.run", program=program.name,
+                         rid=stt.req.id):
+            t0 = time.perf_counter()
+            logits, self._ck, self._cv = program(
+                *lead, self._ck, self._cv, *tail)
+            tok = int(np.asarray(
+                self._gen._argmax(jnp.asarray(logits)))[0])
+        self._run_ts = (t_run0, self._now())
+        # the argmax fetch synced the chunk — honest phase roofline
+        _roofline.analyze(program.name, time.perf_counter() - t0)
+        with RecordEvent("serve.emit"):
+            return self._emit_prefill(i, stt, c, n, tok)
+
+    def _plan_prefill(self):
+        """The host's work before a prefill chunk's program call: pick
+        the slot, size the chunk, grow its pages (evicting, shrinking
+        the chunk or requeueing others under pool pressure), build the
+        operands. Returns (slot, its state, chunk size, real tokens,
+        the program, operands before the pool, operands after it), or
+        None where the chunk is deferred to a later step."""
         i = self._pick_prefilling()
         stt = self._prefilling[i]
         req = stt.req
@@ -1459,7 +1512,7 @@ class ServingEngine(ContinuousBatchingEngine):
                 if jr is not None:
                     jr.record("stall", req.id, i,
                               {"need": need - have})
-                return []
+                return None
             # no decoders to wait for: requeue LESS-urgent prefilling
             # requests (never this one — ``i`` is the most urgent, and
             # sacrificing it would livelock: it re-admits first and
@@ -1501,16 +1554,22 @@ class ServingEngine(ContinuousBatchingEngine):
                      self.adapters.operands(tp=self._gen._tp))
             _stats.inc("lora.grouped_launches",
                        4 * self.model.stack.num_layers)
-        t0 = time.perf_counter()
-        logits, self._ck, self._cv = self._get_chunk_prefill(
-            c, adaptered)(
-            self._gen._weights(), self._gen._embed(),
-            self._gen._head_t, lnf_s, lnf_b, jnp.asarray(ids),
-            jnp.asarray([stt.pos], jnp.int32),
-            jnp.asarray([n], jnp.int32), self._ck, self._cv, tables,
-            *extra)
-        tok = int(np.asarray(
-            self._gen._argmax(jnp.asarray(logits)))[0])
+        lead = (self._gen._weights(), self._gen._embed(),
+                self._gen._head_t, lnf_s, lnf_b, jnp.asarray(ids),
+                jnp.asarray([stt.pos], jnp.int32),
+                jnp.asarray([n], jnp.int32))
+        return (i, stt, c, n, self._get_chunk_prefill(c, adaptered), lead,
+                (tables, *extra))
+
+    def _emit_prefill(self, i, stt, c, n, tok):
+        """The chunk's token -> its request: corruption check, journal,
+        and on prompt completion the handoff to the decode batch with
+        the first token emitted (``on_token``, finish hook, page
+        release for a one-token request). Returns the requests that
+        finished."""
+        req, toks, L = stt.req, stt.tokens, len(stt.tokens)
+        key = ("prefill", i)
+        fi = self.faults
         if fi is not None:
             tok = fi.corrupt("prefill.dispatch", tok)
         if not 0 <= tok < self.model.vocab_size:
@@ -1521,9 +1580,6 @@ class ServingEngine(ContinuousBatchingEngine):
             raise TokenCorruption(
                 f"prefill chunk for request {req.id} produced token "
                 f"{tok} outside [0, {self.model.vocab_size})")
-        # the argmax fetch synced the chunk — honest phase roofline
-        _roofline.analyze(self._chunk_rung(c, adaptered),
-                          time.perf_counter() - t0)
         _stats.inc("serve.prefill_chunks")
         _stats.inc("serve.prefill_tokens", n)
         u = self.usage

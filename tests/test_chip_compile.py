@@ -22,6 +22,7 @@ A compile that passes is not a chip run: nothing here says the kernels
 are right or fast.
 """
 import os
+import re
 
 import pytest
 
@@ -166,7 +167,19 @@ def test_kernel_compiles_for_v5e(name, one_chip, as_on_chip):
     args = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                        sharding=one_chip), args)
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text(), (
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, (
         f"{name}: compiled without a Pallas kernel — a reference path "
         f"was taken")
+    # the compiled instruction's name is what the device trace shows:
+    # each launch carries its name from the inventory's table, none is
+    # left as ``closed_call`` / ``custom-call``
+    from paddle_tpu.analysis.sites import KERNEL_SITES
+
+    site = {s.name: s for s in KERNEL_SITES}.get(name)
+    shown = {m.group(1) for m in re.finditer(
+        r"%([\w-]+?)(?:\.\d+)* = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text)}
+    assert all(n.startswith("pt_") for n in shown), shown
+    if site is not None:
+        assert shown == set(site.kernels)
