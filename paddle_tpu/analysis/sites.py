@@ -427,6 +427,38 @@ def _expected_flash_varlen_paged():
             + _B((2, npp, n_kv, ps, d), "bfloat16") * 2)  # k+v page DMA
 
 
+def _build_paged_kv_write(c=64):
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional.paged_attention import (
+        write_prefill_kv_inplace)
+
+    b, n_kv, d, ps = (_POOL[k] for k in ("b", "n_kv", "d", "ps"))
+    pp, P = 16, 256
+
+    def fn(kc, vc, k, v, tables, start, lens):
+        return write_prefill_kv_inplace(kc, vc, k, v, tables, start,
+                                        lens)
+
+    return fn, (_sds((P, n_kv, ps, d), jnp.bfloat16),
+                _sds((P, n_kv, ps, d), jnp.bfloat16),
+                _sds((b, c, n_kv, d), jnp.bfloat16),
+                _sds((b, c, n_kv, d), jnp.bfloat16),
+                _sds((b, pp), jnp.int32),
+                _sds((b,), jnp.int32),
+                _sds((b,), jnp.int32))
+
+
+def _expected_paged_kv_write():
+    # a 64-row chunk at any offset touches 64/16 + 1 = 5 pages a row;
+    # the pool itself stays in HBM (aliased, memory_space=ANY)
+    n_kv, d, ps = (_POOL[k] for k in ("n_kv", "d", "ps"))
+    npg = 5
+    return (2 * _B((npg, n_kv, ps, d), "bfloat16") * 2   # shifted k + v
+            + 2 * _B((npg, 1, ps, 1), "float32")         # row mask
+            + _B((npg, n_kv, ps, d), "bfloat16") * 2)    # k + v page RMW
+
+
 # ragged grouped-GEMM MoE kernel (ISSUE 15): a serving-shaped FFN1
 # bank — 8 experts, d=2048 -> dff=8192, 1024 expert-sorted rows, bf16
 # weights. bn = 2048 (8 MiB bf16 stream target / K=2048), bm = 128;
@@ -569,6 +601,10 @@ KERNEL_SITES: List[KernelSite] = [
                         "pt_flash_varlen_packed_dkv")),
     KernelSite("flash_varlen.paged", "nn/functional/flash_varlen.py",
                _build_flash_varlen_paged, _expected_flash_varlen_paged),
+    # the chunked-prefill write into the pool, in place (ISSUE 29):
+    # the layer loop's only other touch of the pool besides the attend
+    KernelSite("paged_kv.write", "nn/functional/paged_attention.py",
+               _build_paged_kv_write, _expected_paged_kv_write),
     # ragged grouped-GEMM MoE (ISSUE 15): fwd, and the grad trace's
     # fwd + pre-activation recompute + dx walk + dw segment kernel
     KernelSite("grouped_gemm.fwd", "nn/functional/grouped_gemm.py",

@@ -559,10 +559,24 @@ class FusedMultiTransformer(Layer):
         program that blocks the decode batch.
 
         ``start``/``chunk_lens``: [b] int32 traced arrays — position
-        offset and VALID row count (rows ``>= chunk_lens[b]`` are
-        right-padding; their KV writes go to the scratch page and their
-        hidden rows are garbage the caller discards). Returns
-        (hidden [b, c, d], cache').
+        offset (ANY value: the scheduler's chunks start on page
+        boundaries, the speculative verify pass at ``seq_len``) and
+        VALID row count (rows ``>= chunk_lens[b]`` are right-padding:
+        their K/V never reach a live page and their hidden rows are
+        garbage the caller discards). Returns (hidden [b, c, d],
+        cache').
+
+        Who writes the pool: inside the layer loop a bf16/f32 pool is
+        touched ONLY by Pallas calls — ``write_prefill_kv_inplace``
+        (``pt_paged_kv_write``, both sides aliased) writes the chunk,
+        ``paged_prefill_attention`` reads the pages in place — so the
+        loop-carried pool keeps the default layout from entry to exit
+        and is never copied. An XLA scatter in this loop would pin it
+        to another layout and cost two whole-pool copies a layer (the
+        note on ``paged_decode_attention_inplace``; 416 of a chunk's
+        424 ms on the 1.3B cell before ISSUE 29). The int8-quantized
+        pool keeps the scatter (``write_prefill_kv_pages``): its attend
+        is the dequantizing XLA gather, no Pallas call sits beside it.
         """
         if a8w8 and self._weights_dtype(weights) != jnp.int8:
             raise ValueError("a8w8 prefill needs an int8 weight stack "
@@ -576,7 +590,8 @@ class FusedMultiTransformer(Layer):
         from ...core.flags import flag
         from ...nn.functional.flash_varlen import paged_prefill_attention
         from ...nn.functional.paged_attention import (
-            gather_kv_pages, write_prefill_kv_pages)
+            gather_kv_pages, write_prefill_kv_inplace,
+            write_prefill_kv_pages)
 
         b, c, _d = x.shape
         start = start.astype(jnp.int32)
@@ -591,8 +606,9 @@ class FusedMultiTransformer(Layer):
         # int8-quantized pools keep the dequantizing gather path; bf16/
         # f32 pools route through the varlen kernel, which reads the
         # pages IN PLACE (no per-chunk dense gather copy)
+        quant_pool = isinstance(cache.k, tuple)
         use_varlen = (flag("prefill_attention_backend") != "gather"
-                      and not isinstance(cache.k, tuple))
+                      and not quant_pool)
 
         ad_base = None
         if adapters is not None:
@@ -616,9 +632,12 @@ class FusedMultiTransformer(Layer):
             tbl = block_tables + l * npages
 
             def kv_write(k, v):
-                return write_prefill_kv_pages(
-                    ck, cv, k, v, tbl, start=start,
-                    valid_lens=chunk_lens)
+                if quant_pool:
+                    return write_prefill_kv_pages(
+                        ck, cv, k, v, tbl, start=start,
+                        valid_lens=chunk_lens)
+                return write_prefill_kv_inplace(
+                    ck, cv, k, v, tbl, start, chunk_lens)
 
             def attend(q, k, v, nck, ncv):
                 # the sequence's whole cached span (the chunk's own KV

@@ -1120,10 +1120,13 @@ def paged_prefill_attention(q, key_cache, value_cache, block_tables,
     q: ``[b, c, n_q_heads, d]`` chunk queries at positions
     ``start[b] .. start[b]+c-1``; ``block_tables`` ``[b, pp]`` hold
     ABSOLUTE (layer-offset) page ids; the chunk's own K/V must already
-    be written to the pool (the prefill write happens first). Queries
-    attend causally: key position <= query position — the cached prefix
-    plus the in-chunk triangle, exactly the dense-gather path's mask.
-    Returns ``[b, c, n_q_heads, d]`` in q's dtype.
+    be written to the pool — by ``write_prefill_kv_inplace`` where this
+    call sits in a layer loop on the chip: an XLA scatter on the
+    loop-carried pool beside this Pallas call costs two whole-pool
+    copies a layer (the note on ``paged_decode_attention_inplace``).
+    Queries attend causally: key position <= query position — the
+    cached prefix plus the in-chunk triangle, exactly the dense-gather
+    path's mask. Returns ``[b, c, n_q_heads, d]`` in q's dtype.
     """
     b, c, n_q, d = q.shape
     _, _, ps, _ = key_cache.shape

@@ -44,7 +44,8 @@ import jax.numpy as jnp
 from ...device import chip as _chip
 from ...device.vmem import KERNEL_VMEM_LIMIT_BYTES
 
-__all__ = ["paged_attention", "write_kv_pages", "write_prefill_kv_pages"]
+__all__ = ["paged_attention", "write_kv_pages", "write_prefill_kv_pages",
+           "write_prefill_kv_inplace"]
 
 
 def _enable_x64(flag: bool):
@@ -835,7 +836,16 @@ def write_kv_pages(key_cache, value_cache, new_k, new_v, positions,
 
 def write_prefill_kv_pages(key_cache, value_cache, k, v, block_tables,
                            start=None, valid_lens=None):
-    """Write a prompt chunk's K/V ([batch, seq, n_kv, d]) into pages.
+    """Write a prompt chunk's K/V ([batch, seq, n_kv, d]) into pages
+    with an XLA scatter.
+
+    Used where no Pallas call touches the pool in the same loop: the
+    monolithic ``prefill_raw`` (dense attention over the operands), the
+    int8-quantized pool (its attend is an XLA gather), and off the chip
+    as the fallback of ``write_prefill_kv_inplace``. On the chip the
+    chunked-prefill loop must NOT use it for a bf16/f32 pool: beside a
+    Pallas call the scatter's preferred layout costs two whole-pool
+    copies a layer (the note on ``paged_decode_attention_inplace``).
 
     ``start`` (optional [batch] int32): per-sequence position offset —
     the chunked-prefill path writes chunk c's tokens at positions
@@ -887,6 +897,159 @@ def write_prefill_kv_pages(key_cache, value_cache, k, v, block_tables,
     value_cache = value_cache.at[page_ids, :, slots].set(
         v.astype(value_cache.dtype))
     return key_cache, value_cache
+
+
+def write_prefill_kv_inplace(key_cache, value_cache, k, v, block_tables,
+                             start, valid_lens=None, backend="auto"):
+    """``write_prefill_kv_pages`` for a bf16/f32 pool as ONE Pallas call
+    that aliases both pool sides (``pt_paged_kv_write``): the write the
+    chunked-prefill layer loop uses, because an XLA scatter on a
+    loop-carried pool beside a Pallas call costs two whole-pool copies a
+    layer (the layout note on ``paged_decode_attention_inplace``).
+
+    k/v ``[b, c, n_kv, d]`` land at positions ``start[b] ..
+    start[b]+c-1`` of the pages ``block_tables`` ``[b, pp]`` names
+    (ABSOLUTE ids), for ANY traced ``start``. Rows ``>= valid_lens[b]``
+    and rows at positions past the table are DROPPED (the scatter sends
+    the former to scratch page 0 and clamps the latter into the table's
+    last page); every other byte of the pool matches the scatter's.
+
+    Mechanics: XLA shifts the small chunk by ``start % page_size`` into
+    whole page-shaped blocks and builds a per-row f32 mask; the kernel
+    (one grid step a sequence) reads each touched page, selects the
+    chunk's rows into it and writes the whole page back — whole-page
+    DMAs only, as in the decode kernel (single-slot DMA slices break
+    Mosaic's sublane tiling). Pages no valid row touches are skipped,
+    so a page index clamped into the table never writes stale bytes.
+
+    ``backend``: auto (the kernel on TPU, the scatter elsewhere) |
+    interpret (the kernel through the interpreter — tests).
+    """
+    if backend not in ("auto", "interpret"):
+        raise ValueError(f"write_prefill_kv_inplace backend={backend!r}: "
+                         "expected 'auto' or 'interpret'")
+    if backend == "auto" and not _chip.on_tpu():
+        return write_prefill_kv_pages(key_cache, value_cache, k, v,
+                                      block_tables, start=start,
+                                      valid_lens=valid_lens)
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, c, n_kv, d = k.shape
+    _, _, ps, _ = key_cache.shape
+    pp = block_tables.shape[1]
+    # pages a chunk of c rows can touch at any offset inside a page
+    npg = (c + ps - 2) // ps + 1
+    span = npg * ps
+    start = start.astype(jnp.int32)
+    off = start % ps
+    first = start // ps                                    # [b] page idx
+    vlen = jnp.full((b,), c, jnp.int32) if valid_lens is None \
+        else jnp.minimum(valid_lens.astype(jnp.int32), c)
+
+    def shifted(x, dtype):
+        # row r of the page blocks = chunk row r - off: a window of the
+        # chunk padded by one page in front (kilobytes, not the pool)
+        x = jnp.pad(x.astype(dtype),
+                    ((0, 0), (ps, span - c), (0, 0), (0, 0)))
+        x = jax.vmap(lambda xi, s: jax.lax.dynamic_slice_in_dim(
+            xi, s, span, axis=0))(x, ps - off)
+        return jnp.swapaxes(x.reshape(b, npg, ps, n_kv, d), 2, 3) \
+            .reshape(b * npg, n_kv, ps, d)
+
+    r = jnp.arange(span, dtype=jnp.int32)[None, :]
+    live = ((r >= off[:, None]) & (r < (off + vlen)[:, None])
+            & (first[:, None] * ps + r < pp * ps))         # [b, span]
+    live = live.reshape(b * npg, ps)
+    counts = live.sum(-1).astype(jnp.int32)                # [b*npg]
+    # f32 and pre-shaped 4-D: Mosaic broadcasts only 32-bit values
+    # along the sub-minor dim and cannot insert dims on i1/bf16
+    rowmask = live.astype(jnp.float32)[:, None, :, None]
+    pidx = jnp.minimum(
+        first[:, None] + jnp.arange(npg, dtype=jnp.int32)[None, :],
+        pp - 1)
+    pids = jnp.take_along_axis(block_tables.astype(jnp.int32), pidx,
+                               axis=1).reshape(-1)         # [b*npg]
+
+    def kernel(pid_ref, cnt_ref, nk_ref, nv_ref, m_ref, k_in, v_in,
+               k_hbm, v_hbm, pgk, pgv, in_sem, out_sem):
+        del k_in, v_in                      # aliased: k_hbm/v_hbm
+        i = pl.program_id(0)
+
+        def page_in(j):
+            pid = pid_ref[i * npg + j]
+            return (pltpu.make_async_copy(k_hbm.at[pid], pgk.at[j],
+                                          in_sem.at[j, 0]),
+                    pltpu.make_async_copy(v_hbm.at[pid], pgv.at[j],
+                                          in_sem.at[j, 1]))
+
+        def page_out(j):
+            pid = pid_ref[i * npg + j]
+            return (pltpu.make_async_copy(pgk.at[j], k_hbm.at[pid],
+                                          out_sem.at[j, 0]),
+                    pltpu.make_async_copy(pgv.at[j], v_hbm.at[pid],
+                                          out_sem.at[j, 1]))
+
+        def each_touched(copies, act):
+            for j in range(npg):
+                @pl.when(cnt_ref[i * npg + j] > 0)
+                def _():
+                    for cpy in copies(j):
+                        act(cpy)
+
+        each_touched(page_in, lambda cpy: cpy.start())
+        each_touched(page_in, lambda cpy: cpy.wait())
+        # select in f32 (exact both ways for bf16; v5e has no bf16 VPU);
+        # a select, not a blend: garbage beside a 0 weight stays out
+        sel = jnp.broadcast_to(m_ref[...], pgk.shape) > jnp.float32(0.5)
+        pgk[...] = jnp.where(sel, nk_ref[...].astype(jnp.float32),
+                             pgk[...].astype(jnp.float32)) \
+            .astype(pgk.dtype)
+        pgv[...] = jnp.where(sel, nv_ref[...].astype(jnp.float32),
+                             pgv[...].astype(jnp.float32)) \
+            .astype(pgv.dtype)
+        each_touched(page_out, lambda cpy: cpy.start())
+        each_touched(page_out, lambda cpy: cpy.wait())
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((npg, n_kv, ps, d), lambda i, *_: (i, 0, 0, 0)),
+            pl.BlockSpec((npg, n_kv, ps, d), lambda i, *_: (i, 0, 0, 0)),
+            pl.BlockSpec((npg, 1, ps, 1), lambda i, *_: (i, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+        ],
+        out_specs=[
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((npg, n_kv, ps, d), key_cache.dtype),
+            pltpu.VMEM((npg, n_kv, ps, d), value_cache.dtype),
+            pltpu.SemaphoreType.DMA((npg, 2)),
+            pltpu.SemaphoreType.DMA((npg, 2)),
+        ])
+    with _enable_x64(False), jax.named_scope("pt_paged_kv_write"):
+        ck, cv = pl.pallas_call(
+            kernel,
+            name="pt_paged_kv_write",
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct(key_cache.shape, key_cache.dtype),
+                jax.ShapeDtypeStruct(value_cache.shape,
+                                     value_cache.dtype),
+            ],
+            # inputs are numbered with the scalar-prefetch operands
+            # first: key_cache is arg 5, value_cache arg 6
+            input_output_aliases={5: 0, 6: 1},
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
+            interpret=not _chip.on_tpu(),
+        )(pids, counts, shifted(k, key_cache.dtype),
+          shifted(v, value_cache.dtype), rowmask, key_cache, value_cache)
+    return ck, cv
 
 
 def gather_kv_pages(cache_side, block_tables, out_dtype=None):

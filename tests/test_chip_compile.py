@@ -21,6 +21,7 @@ Rules this file keeps (see the on-chip-measurement guide):
 A compile that passes is not a chip run: nothing here says the kernels
 are right or fast.
 """
+import math
 import os
 import re
 
@@ -47,6 +48,8 @@ KERNELS = [
     "flash_varlen.paged",
     "flash_varlen.paged.c256",
     "flash_varlen.paged.c512",
+    "paged_kv.write",
+    "paged_kv.write.c256",
     "grouped_gemm.fwd",
     "grouped_gemm.bwd",
     "lora.delta",
@@ -117,6 +120,10 @@ def _build(name):
                            next_qkv=name.endswith(".next_qkv"))
     if name.startswith("flash_varlen.paged.c"):
         return _paged_prefill(int(name.rsplit("c", 1)[1]))
+    if name.startswith("paged_kv.write.c"):
+        from paddle_tpu.analysis.sites import _build_paged_kv_write
+
+        return _build_paged_kv_write(int(name.rsplit("c", 1)[1]))
     from paddle_tpu.analysis.sites import KERNEL_SITES
 
     # the lint's own inventory (analysis/sites.py) at its 1.3B widths
@@ -159,15 +166,26 @@ def as_on_chip(monkeypatch):
     monkeypatch.setattr(chip, "on_tpu", lambda: True)
 
 
+def _placed(args, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=sharding), args)
+
+
+def _kernel_names(hlo_text):
+    """Names of the compiled Pallas launches (what the device trace
+    shows), numeric suffixes dropped."""
+    return {m.group(1) for m in re.finditer(
+        r"%([\w-]+?)(?:\.\d+)* = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        hlo_text)}
+
+
 @pytest.mark.parametrize("name", KERNELS)
 def test_kernel_compiles_for_v5e(name, one_chip, as_on_chip):
     assert jax.config.jax_enable_x64            # the package's config
     assert jax.config.jax_default_matmul_precision == "high"
     fn, args = _build(name)
-    args = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                       sharding=one_chip), args)
-    text = jax.jit(fn).lower(*args).compile().as_text()
+    text = jax.jit(fn).lower(*_placed(args, one_chip)).compile().as_text()
     assert "tpu_custom_call" in text, (
         f"{name}: compiled without a Pallas kernel — a reference path "
         f"was taken")
@@ -176,10 +194,73 @@ def test_kernel_compiles_for_v5e(name, one_chip, as_on_chip):
     # left as ``closed_call`` / ``custom-call``
     from paddle_tpu.analysis.sites import KERNEL_SITES
 
-    site = {s.name: s for s in KERNEL_SITES}.get(name)
-    shown = {m.group(1) for m in re.finditer(
-        r"%([\w-]+?)(?:\.\d+)* = [^\n]*custom_call_target=\"tpu_custom_call\"",
-        text)}
+    site = {s.name: s for s in KERNEL_SITES}.get(
+        re.sub(r"\.c\d+$", "", name))
+    shown = _kernel_names(text)
     assert all(n.startswith("pt_") for n in shown), shown
     if site is not None:
         assert shown == set(site.kernels)
+
+
+def _prefill_chunk_program(chunk: int, pages: int):
+    """``prefill_chunk_raw`` as the serving engine jits it: the cell's
+    widths and depth, bf16 weight stacks, one row of ``chunk`` tokens, a
+    pool of ``pages`` pages a layer, both pool sides donated. The stack
+    is a parameterless view (as ``_tp_view`` builds one): the raw
+    methods read config attributes and the weights they are handed."""
+    from paddle_tpu.incubate.nn.fused_transformer import (
+        FusedMultiTransformer, PagedKV)
+
+    st = object.__new__(FusedMultiTransformer)
+    for n, v in dict(embed_dim=D, head_dim=HEAD_DIM, dim_feedforward=DFF,
+                     num_layers=L, num_heads=HEADS, num_kv_heads=HEADS,
+                     activation="gelu", epsilon=1e-5, rope_theta=1e4,
+                     max_position=4096, moe_num_experts=None,
+                     moe_top_k=2).items():
+        object.__setattr__(st, n, v)
+    bf = jnp.bfloat16
+    w = {"ln1_scale": (L, D), "ln1_bias": (L, D),
+         "qkv_weight": (L, D, NQ), "qkv_bias": (L, NQ),
+         "out_weight": (L, D, D), "out_bias": (L, D),
+         "ln2_scale": (L, D), "ln2_bias": (L, D),
+         "ffn1_weight": (L, D, DFF), "ffn1_bias": (L, DFF),
+         "ffn2_weight": (L, DFF, D), "ffn2_bias": (L, D)}
+    w = {n: _sds(s, bf) for n, s in w.items()}
+
+    def fn(w, x, ck, cv, tables, start, lens, cos, sin):
+        h, cache = st.prefill_chunk_raw(w, x, PagedKV(ck, cv), tables,
+                                        start, lens, cos, sin)
+        return h, cache.k, cache.v
+
+    pool = _sds((L * pages, HEADS, PAGE, HEAD_DIM), bf)
+    rope = _sds((4096, HEAD_DIM // 2), jnp.float32)
+    args = (w, _sds((1, chunk, D), bf), pool, pool,
+            _sds((1, 160), jnp.int32), _sds((1,), jnp.int32),
+            _sds((1,), jnp.int32), rope, rope)
+    return jax.jit(fn, donate_argnums=(2, 3)), args, pool
+
+
+@pytest.mark.parametrize("chunk", [64, 256])
+def test_prefill_chunk_program_never_copies_the_pool(chunk, one_chip,
+                                                     as_on_chip):
+    """The layer loop carries the pool through two Pallas calls a layer
+    (``pt_paged_kv_write`` aliases it, ``pt_flash_varlen_paged`` reads
+    it) and nothing else, so layout assignment leaves it in the default
+    layout from entry to exit: no ``copy`` of the pool's shape in the
+    optimised HLO, and the program's temp stays far under one pool
+    side. With the XLA scatter in the loop this read 6 pool-shaped
+    copies (2 in the loop body, 4 at entry and exit) and a temp of two
+    pool sides (PR 29's parent, same compile)."""
+    jitted, args, pool = _prefill_chunk_program(chunk, pages=256)
+    compiled = jitted.lower(*_placed(args, one_chip)).compile()
+    text = compiled.as_text()
+    shape = "bf16[%s]" % ",".join(map(str, pool.shape))
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"= " + re.escape(shape) + r"\S* copy\(", line)]
+    assert not copies, copies
+    assert _kernel_names(text) == {"pt_paged_kv_write",
+                                   "pt_flash_varlen_paged"}
+    side = 2 * math.prod(pool.shape)                # bf16 bytes
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < side // 4, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes == 2 * side      # both sides donated
