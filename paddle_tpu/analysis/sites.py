@@ -564,6 +564,96 @@ def _expected_lora_delta():
             + 2 * _B((bm, bn), "float32"))     # delta tile stream
 
 
+# state-space and routed-expert serving kernels (ISSUE 31) at
+# granite-4.0-h-small's published widths: 128 Mamba heads of 64, state
+# 128, one SSD chunk of 256 rows; 64 decode slots over 9 Mamba layers;
+# 36 held experts of width 768 over hidden 4096, 10 layers.
+_SSM = dict(H=128, P=64, N=128, T=256, L=9, S=64)
+_MOE = dict(M=64, d=4096, f=768, E=36, L=10, tf=256)
+
+
+def _build_ssd_chunk_scan():
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional.ssm import ssd_chunk_scan
+
+    H, P, N, T = (_SSM[k] for k in ("H", "P", "N", "T"))
+
+    def fn(x, dt, A, B, C, D, s0):
+        return ssd_chunk_scan(x, dt, A, B, C, D, s0, chunk_size=T)
+
+    return fn, (_sds((T, H, P), jnp.bfloat16), _sds((T, H), jnp.float32),
+                _sds((H,), jnp.float32), _sds((T, N), jnp.bfloat16),
+                _sds((T, N), jnp.bfloat16), _sds((H,), jnp.float32),
+                _sds((N, H * P), jnp.float32))
+
+
+def _expected_ssd_chunk_scan():
+    # grid (heads, chunks) = (128, 1): what is indexed by the head
+    # streams, what is indexed by the chunk alone stays put
+    H, P, N, Q = (_SSM[k] for k in ("H", "P", "N", "T"))
+    return (2 * _B((1, Q, P), "bfloat16")       # x, one head
+            + _B((H, Q), "float32")              # decay sums, row form
+            + 2 * _B((Q, H), "float32")          # ... column form, dt
+            + _B((N, Q), "bfloat16")             # B^T
+            + _B((Q, N), "bfloat16")             # C
+            + 2 * _B((1, N, P), "float32")       # the head's initial state
+            + 2 * _B((1, Q, P), "float32")       # y
+            + 2 * _B((1, N, P), "float32")       # final state
+            + _B((N, P), "float32"))             # carried state (scratch)
+
+
+def _build_ssm_decode_update():
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional.ssm import ssm_decode_update
+
+    H, P, N, L, S = (_SSM[k] for k in ("H", "P", "N", "L", "S"))
+
+    def fn(state, decay, dtx, B, C):
+        return ssm_decode_update(state, 4, decay, dtx, B, C)
+
+    return fn, (_sds((L, S, N, H * P), jnp.float32),
+                _sds((S, H * P), jnp.float32),
+                _sds((S, H * P), jnp.float32),
+                _sds((S, N), jnp.bfloat16), _sds((S, N), jnp.bfloat16))
+
+
+def _expected_ssm_decode_update():
+    # [N, 4096] float32 blocks of one slot's state, in and (aliased) out
+    N, w = _SSM["N"], 4096
+    return (2 * 2 * _B((1, 1, w), "float32")     # decay and dt*u rows
+            + 2 * _B((1, N, 2), "float32")       # B | C columns
+            + 2 * _B((1, 1, N, w), "float32")    # state block in
+            + 2 * _B((1, 1, N, w), "float32")    # state block out
+            + 2 * _B((1, 1, w), "float32"))      # y row
+
+
+def _build_moe_stream_experts():
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional.moe_gated import moe_gated_stream
+
+    M, d, f, E, L = (_MOE[k] for k in ("M", "d", "f", "E", "L"))
+
+    def fn(x, gates, idx, w1, w2):
+        return moe_gated_stream(x, gates, idx, w1, w2, 3, (0, E))
+
+    return fn, (_sds((M, d), jnp.bfloat16), _sds((M, 10), jnp.float32),
+                _sds((M, 10), jnp.int32),
+                _sds((L, E, d, 2 * f), jnp.bfloat16),
+                _sds((L, E, f, d), jnp.bfloat16))
+
+
+def _expected_moe_stream_experts():
+    M, d, tf = (_MOE[k] for k in ("M", "d", "tf"))
+    return (_B((M, d), "bfloat16")               # rows (resident)
+            + 2 * _B((1, M, 1), "float32")       # the expert's gate column
+            + 2 * 2 * _B((1, 1, d, tf), "bfloat16")  # a and b column tiles
+            + 2 * _B((1, 1, tf, d), "bfloat16")  # W2 row tile
+            + _B((M, d), "float32"))             # the accumulated output
+
+
 KERNEL_SITES: List[KernelSite] = [
     KernelSite("stream_linear.bf16", "nn/functional/stream_linear.py",
                _build_stream_linear, _expected_stream_linear),
@@ -618,6 +708,15 @@ KERNEL_SITES: List[KernelSite] = [
     # every adapter's x·A·B for an adapter-sorted chunk
     KernelSite("lora.delta", "nn/functional/lora.py",
                _build_lora_delta, _expected_lora_delta),
+    # state-space layers and routed gated experts of a pattern-built
+    # stack (ISSUE 31): the chunked SSD scan of a prefill chunk, the
+    # in-place one-token state update, the decode rows' expert stream
+    KernelSite("ssd.chunk_scan", "nn/functional/ssm.py",
+               _build_ssd_chunk_scan, _expected_ssd_chunk_scan),
+    KernelSite("ssm.decode_update", "nn/functional/ssm.py",
+               _build_ssm_decode_update, _expected_ssm_decode_update),
+    KernelSite("moe.stream_experts", "nn/functional/moe_gated.py",
+               _build_moe_stream_experts, _expected_moe_stream_experts),
 ]
 
 

@@ -168,6 +168,25 @@ class FusedMultiTransformer(Layer):
 
         return Parameter(arr)
 
+    @property
+    def pattern(self):
+        """This stack as a ``LayerPattern``: the one-kind pattern (every
+        layer rotary GQA attention with pages, a LayerNorm'd biased GELU
+        FFN or the 8-expert synthetic MoE) — what the serving engines
+        size their cache groups from. ``HybridStack`` is BUILT from a
+        pattern; this class only reports one."""
+        from .layer_pattern import LayerPattern, MoESpec
+
+        moe = None
+        if self.moe_num_experts:
+            moe = MoESpec(int(self.moe_num_experts), self.moe_top_k,
+                          self.dim_feedforward)
+        return LayerPattern.uniform_attention(
+            self.embed_dim, self.num_layers, self.num_heads,
+            self.num_kv_heads, self.head_dim, self.dim_feedforward,
+            rope_theta=self.rope_theta, epsilon=self.epsilon,
+            activation=self.activation, moe=moe)
+
     # ---------- functional core (raw arrays; jit-able) ----------
 
     def _stack(self):

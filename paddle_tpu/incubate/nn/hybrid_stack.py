@@ -1,0 +1,378 @@
+"""A served stack BUILT from a ``LayerPattern``: a period of layer kinds
+(Mamba-2 mixers, NoPE / rotary GQA attention), each followed by an FFN
+of routed gated experts plus a shared gated MLP, RMSNorm, no biases,
+residual / attention multipliers from the description.
+
+    h = h + r * Mixer(RMSNorm(h))
+    h = h + r * (MoE(x) + SharedMLP(x)),   x = RMSNorm(h)
+
+A weight stack a kind (``m_*`` the mamba layers, ``qkv_weight`` /
+``out_weight`` / ``a_norm`` the attention layers, ``f_*`` / ``e_*`` /
+``s_*`` the FFN of every layer), a cache group a kind: the attention
+layers share the serving engine's paged pool (layer-folded over
+``n_attention`` layers, touched only by the three paged Pallas kernels
+``FusedMultiTransformer`` uses), the mamba layers a slot-indexed
+``RecurrentState`` that the decode program updates in place.
+
+The two raw phases mirror ``FusedMultiTransformer``'s:
+
+``prefill_chunk_raw``  ONE sequence's chunk ``[1, c, d]`` at positions
+    ``start ..``; the recurrent state enters as that slot's arrays
+    (zeros on a fresh admission) and leaves as the state after the
+    chunk's VALID rows — padded rows of a bucketed chunk have ``dt =
+    0`` and stay out of the conv tail.
+``decode_raw``         one token for every slot ``[slots, d]``; rows not
+    ``active`` (idle slots, slots still prefilling) leave pool and state
+    as they were.
+
+Both return pick counts of the expert layers (all picks, picks on held
+experts, held experts hit, held experts offered) so the engine can
+publish them with the tokens it fetches anyway.
+"""
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from ...nn.layer_base import Layer
+from .fused_transformer import PagedKV, _apply_rope
+from .layer_pattern import MAMBA, LayerPattern
+
+__all__ = ["HybridStack", "RecurrentState"]
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "dtype", "std"))
+def _draw(key, shape, dtype, std):
+    """Normal values drawn straight into ``dtype``: under jit no float32
+    array of the stack's size is ever alive (an expert bank is
+    gigabytes)."""
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
+class RecurrentState(NamedTuple):
+    """Slot-indexed state of the recurrent layers, beside the paged pool.
+
+    ``ssm [layers, slots, d_state, d_inner]`` float32 (lanes are
+    ``(head, p)``: ``nn/functional/ssm.py``), ``conv [layers, slots,
+    d_conv - 1, conv_dim]`` the last rows the causal conv still needs."""
+    ssm: jax.Array
+    conv: jax.Array
+
+
+class HybridStack(Layer):
+    def __init__(self, pattern: LayerPattern, dtype=jnp.float32):
+        super().__init__()
+        if pattern.moe is None or not pattern.gated \
+                or pattern.norm != "rmsnorm" or pattern.bias:
+            raise NotImplementedError(
+                "HybridStack serves RMSNorm, bias-free, SiLU-gated "
+                "routed-expert blocks; the LayerNorm / biased GELU "
+                "one-kind pattern is FusedMultiTransformer's")
+        self.pattern = pattern
+        self.embed_dim = pattern.d_model
+        self.num_layers = pattern.num_layers
+        self.epsilon = pattern.epsilon
+        att = pattern.attention
+
+        from ...core.generator import default_generator
+        from ...core.tensor import Parameter
+
+        dtype = jnp.dtype(dtype)
+
+        def normal(*s, std=0.02, dt=dtype):
+            return _draw(default_generator().next_key(), s, dt, std)
+
+        def mk(name, arr):
+            setattr(self, name, Parameter(arr))
+
+        d, L = pattern.d_model, pattern.num_layers
+        ones = lambda *s: jnp.ones(s, jnp.float32)   # noqa: E731
+        self._names = []
+
+        def add(name, arr):
+            mk(name, arr)
+            self._names.append(name)
+
+        Lm, La = pattern.n_mamba, pattern.n_attention
+        if Lm:
+            m = pattern.mamba
+            add("m_norm", ones(Lm, d))
+            add("m_in", normal(Lm, d, m.in_proj_dim))
+            add("m_conv_w", normal(Lm, m.d_conv, m.conv_dim, std=0.2,
+                                   dt=jnp.dtype(jnp.float32)))
+            add("m_conv_b", jnp.zeros((Lm, m.conv_dim), jnp.float32))
+            add("m_dt_bias", jnp.full((Lm, m.num_heads), -4.0,
+                                      jnp.float32))
+            add("m_A_log", jnp.zeros((Lm, m.num_heads), jnp.float32))
+            add("m_D", ones(Lm, m.num_heads))
+            add("m_gnorm", ones(Lm, m.d_inner))
+            add("m_out", normal(Lm, m.d_inner, d))
+        if La:
+            qkv = (att.num_heads + 2 * att.num_kv_heads) * att.head_dim
+            add("a_norm", ones(La, d))
+            add("qkv_weight", normal(La, d, qkv))
+            add("out_weight", normal(La, att.num_heads * att.head_dim, d))
+        moe = pattern.moe
+        held = moe.held[1]
+        add("f_norm", ones(L, d))
+        add("f_router", normal(L, d, moe.num_experts,
+                               dt=jnp.dtype(jnp.float32)))
+        add("e_w1", normal(L, held, d, 2 * moe.expert_dim))
+        add("e_w2", normal(L, held, moe.expert_dim, d))
+        if moe.shared_dim:
+            add("s_w1", normal(L, d, 2 * moe.shared_dim))
+            add("s_w2", normal(L, moe.shared_dim, d))
+
+    # ------------------------------------------------ functional core
+
+    def _stack(self):
+        return {n: getattr(self, n)._data for n in self._names}
+
+    @staticmethod
+    def _rms(x, scale, eps):
+        xf = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(xf), -1, keepdims=True)
+        return xf * jax.lax.rsqrt(var + eps) * scale
+
+    def _residual(self, h, update):
+        """``h + residual_multiplier * update`` in float32, back in h's
+        dtype."""
+        return (h.astype(jnp.float32)
+                + self.pattern.residual_multiplier * update).astype(h.dtype)
+
+    def _rope(self, x, positions, cos_t, sin_t):
+        if self.pattern.attention.rope_theta is None:
+            return x
+        cos = cos_t[positions][..., None, :]
+        sin = sin_t[positions][..., None, :]
+        return _apply_rope(x, cos, sin)
+
+    def _proj(self, x, w, l, rows_are_decode):
+        """x @ w[l] for a layer-stacked matrix: decode rows stream the
+        stack in place (``pt_stream_linear_bf16``), prefill rows take the
+        XLA dot over the static slice."""
+        if rows_are_decode:
+            from ...nn.functional.stream_linear import stream_linear
+
+            return stream_linear(x, w, layer=l, out_dtype=jnp.float32)
+        return jax.lax.dot_general(
+            x, w[l], (((x.ndim - 1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    def _ffn(self, w, h, l, rows, decode, counts):
+        """``h + r * (MoE(x) + SharedMLP(x))`` over flat rows ``[T, d]``
+        and the layer's pick counts added to ``counts``."""
+        from ...nn.functional.moe_gated import (
+            gated_mlp, moe_gated_grouped, moe_gated_stream, pick_counts,
+            route_topk_softmax)
+
+        p = self.pattern
+        moe = p.moe
+        x = self._rms(h, w["f_norm"][l], p.epsilon).astype(h.dtype)
+        gates, idx = route_topk_softmax(x, w["f_router"][l], moe.top_k)
+        if decode:
+            y = moe_gated_stream(x, gates, idx, w["e_w1"], w["e_w2"], l,
+                                 moe.held)
+        else:
+            y = moe_gated_grouped(x, gates, idx, w["e_w1"], w["e_w2"], l,
+                                  moe.held)
+        if moe.shared_dim:
+            y = y + gated_mlp(x, w["s_w1"][l], w["s_w2"][l])
+        c = pick_counts(idx, rows, moe.held)
+        counts = counts + jnp.concatenate(
+            [c, jnp.full((1,), moe.held[1], jnp.int32)])
+        return self._residual(h, y), counts
+
+    def _mamba_split(self, zxbcdt):
+        m = self.pattern.mamba
+        di, cd = m.d_inner, m.conv_dim
+        return (zxbcdt[..., :di], zxbcdt[..., di: di + cd],
+                zxbcdt[..., di + cd:])
+
+    def _mamba_finish(self, w, lm, h, y, z, rows_are_decode):
+        """Gated RMSNorm over all of d_inner, the out projection and the
+        residual. y float32 ``[T, d_inner]``."""
+        p = self.pattern
+        g = y * jax.nn.silu(z.astype(jnp.float32))
+        g = self._rms(g, w["m_gnorm"][lm], p.epsilon).astype(h.dtype)
+        return self._residual(
+            h, self._proj(g, w["m_out"], lm, rows_are_decode))
+
+    # ------------------------------------------------------- prefill
+
+    def prefill_chunk_raw(self, weights, x, cache, state, block_tables,
+                          start, chunk_lens, cos_t=None, sin_t=None):
+        """x ``[1, c, d]``; ``cache`` the paged pool (PagedKV) of the
+        attention layers; ``state`` this sequence's recurrent arrays
+        (``ssm [Lm, N, d_inner]`` float32, ``conv [Lm, k-1, conv_dim]``)
+        as they stood before the chunk. Returns ``(hidden [1, c, d],
+        cache', state', counts int32 [4])``."""
+        from ...nn.functional.flash_varlen import paged_prefill_attention
+        from ...nn.functional.paged_attention import (
+            write_prefill_kv_inplace)
+        from ...nn.functional.ssm import (causal_conv1d_chunk,
+                                          ssd_chunk_scan)
+
+        p = self.pattern
+        b, c, d = x.shape
+        if b != 1:
+            raise ValueError("HybridStack.prefill_chunk_raw takes one "
+                             "sequence's chunk at a time")
+        w = weights
+        start = start.astype(jnp.int32)
+        chunk_lens = chunk_lens.astype(jnp.int32)
+        valid = jnp.arange(c, dtype=jnp.int32) < chunk_lens[0]   # [c]
+        positions = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None]
+        ck, cv = (cache.k, cache.v) if cache is not None else (None, None)
+        ssm, conv = state if state is not None else (None, None)
+        npages = ck.shape[0] // max(p.n_attention, 1) \
+            if ck is not None else 0
+        counts = jnp.zeros((4,), jnp.int32)
+        h = x[0]
+        for l, kind in enumerate(p.kinds()):
+            li = p.kind_index(l)
+            if kind == MAMBA:
+                m = p.mamba
+                hn = self._rms(h, w["m_norm"][li], p.epsilon) \
+                    .astype(h.dtype)
+                z, xbc, dt = self._mamba_split(
+                    self._proj(hn, w["m_in"], li, False))
+                xbc, tail = causal_conv1d_chunk(
+                    xbc.astype(h.dtype)[None], conv[li][None],
+                    w["m_conv_w"][li], w["m_conv_b"][li], chunk_lens)
+                xbc = xbc[0]
+                u = xbc[:, :m.d_inner].reshape(c, m.num_heads, m.head_dim)
+                Bm = xbc[:, m.d_inner: m.d_inner + m.d_state]
+                Cm = xbc[:, m.d_inner + m.d_state:]
+                dt = jax.nn.softplus(dt + w["m_dt_bias"][li][None, :])
+                dt = jnp.where(valid[:, None], dt, 0.0)
+                y, s_new = ssd_chunk_scan(
+                    u, dt, -jnp.exp(w["m_A_log"][li]), Bm, Cm,
+                    w["m_D"][li], ssm[li], chunk_size=m.chunk_size)
+                ssm = ssm.at[li].set(s_new)
+                conv = conv.at[li].set(tail[0])
+                h = self._mamba_finish(w, li, h, y, z, False)
+            else:
+                att = p.attention
+                hn = self._rms(h, w["a_norm"][li], p.epsilon) \
+                    .astype(h.dtype)
+                proj = self._proj(hn, w["qkv_weight"], li, False) \
+                    .astype(h.dtype)
+                nq, nkv, hd = att.num_heads, att.num_kv_heads, att.head_dim
+                q, k, v = jnp.split(
+                    proj.reshape(1, c, nq + 2 * nkv, hd), [nq, nq + nkv],
+                    axis=2)
+                q = self._rope(q, positions, cos_t, sin_t)
+                k = self._rope(k, positions, cos_t, sin_t)
+                tbl = block_tables + li * npages
+                ck, cv = write_prefill_kv_inplace(ck, cv, k, v, tbl,
+                                                  start, chunk_lens)
+                o = paged_prefill_attention(
+                    q, ck, cv, tbl, start, n_kv=nkv,
+                    scale=att.softmax_scale, backend="auto")
+                o = o.reshape(c, nq * hd).astype(h.dtype)
+                h = self._residual(
+                    h, self._proj(o, w["out_weight"], li, False))
+            h, counts = self._ffn(w, h, l, valid, False, counts)
+        # held experts hit / offered are a reading of the DECODE steps
+        # (is the expert stream's time free of the data?): a prefill
+        # chunk reports its picks alone
+        counts = counts * jnp.asarray([1, 1, 0, 0], jnp.int32)
+        cache2 = PagedKV(ck, cv) if ck is not None else None
+        state2 = (ssm, conv) if ssm is not None else None
+        return h[None], cache2, state2, counts
+
+    # -------------------------------------------------------- decode
+
+    def decode_raw(self, weights, x, cache, state, block_tables,
+                   seq_lens, active, cos_t=None, sin_t=None):
+        """One decode step for every slot: x ``[slots, d]``, ``seq_lens``
+        the tokens already cached, ``active [slots]`` bool. ``state`` is
+        the WHOLE ``RecurrentState`` (its ssm array is updated in place
+        by ``pt_ssm_decode_update``). Returns ``(hidden, cache',
+        state', counts int32 [4])``."""
+        from ...device import chip as _chip
+        from ...nn.functional.paged_attention import (
+            build_pool_ownership, paged_attention,
+            paged_decode_attention_inplace, write_kv_pages)
+        from ...nn.functional.ssm import (causal_conv1d_step,
+                                          expand_heads, ssm_decode_update)
+
+        p = self.pattern
+        w = weights
+        S = x.shape[0]
+        stream = _chip.on_tpu() and S % 8 == 0
+        seq_lens = seq_lens.astype(jnp.int32)
+        ck, cv = (cache.k, cache.v) if cache is not None else (None, None)
+        ssm, conv = state if state is not None else (None, None)
+        npages = ck.shape[0] // max(p.n_attention, 1) \
+            if ck is not None else 0
+        ownership = None
+        fused = False
+        if ck is not None:
+            fused = _chip.on_tpu() and p.attention.head_dim % 128 == 0
+            ownership = build_pool_ownership(
+                block_tables, seq_lens if fused else seq_lens + 1,
+                npages, ck.shape[2])
+        counts = jnp.zeros((4,), jnp.int32)
+        h = x
+        for l, kind in enumerate(p.kinds()):
+            li = p.kind_index(l)
+            if kind == MAMBA:
+                m = p.mamba
+                hn = self._rms(h, w["m_norm"][li], p.epsilon) \
+                    .astype(h.dtype)
+                z, xbc, dt = self._mamba_split(
+                    self._proj(hn, w["m_in"], li, stream))
+                xbc, tail = causal_conv1d_step(
+                    xbc.astype(h.dtype), conv[li], w["m_conv_w"][li],
+                    w["m_conv_b"][li], active)
+                conv = conv.at[li].set(tail)
+                u = xbc[:, :m.d_inner].astype(jnp.float32)
+                Bm = xbc[:, m.d_inner: m.d_inner + m.d_state]
+                Cm = xbc[:, m.d_inner + m.d_state:]
+                dt = jax.nn.softplus(dt + w["m_dt_bias"][li][None, :])
+                dt = jnp.where(active[:, None], dt, 0.0)        # [S, H]
+                A = -jnp.exp(w["m_A_log"][li])
+                decay = expand_heads(jnp.exp(dt * A[None, :]), m.head_dim)
+                dtx = expand_heads(dt, m.head_dim) * u
+                ssm, y = ssm_decode_update(ssm, li, decay, dtx, Bm, Cm)
+                y = y + expand_heads(w["m_D"][li], m.head_dim)[None] * u
+                h = self._mamba_finish(w, li, h, y, z, stream)
+            else:
+                att = p.attention
+                nq, nkv, hd = att.num_heads, att.num_kv_heads, att.head_dim
+                hn = self._rms(h, w["a_norm"][li], p.epsilon) \
+                    .astype(h.dtype)
+                proj = self._proj(hn, w["qkv_weight"], li, stream)
+                q, k, v = jnp.split(proj.reshape(S, nq + 2 * nkv, hd),
+                                    [nq, nq + nkv], axis=1)
+                # the paged decode kernels scale by 1/sqrt(head_dim):
+                # fold the description's own scale into q before the cast
+                q = q * (att.softmax_scale * hd ** 0.5)
+                q = self._rope(q.astype(h.dtype), seq_lens, cos_t, sin_t)
+                k = self._rope(k.astype(h.dtype), seq_lens, cos_t, sin_t)
+                v = v.astype(h.dtype)
+                base = li * npages
+                if fused:
+                    o, ck, cv = paged_decode_attention_inplace(
+                        q, k, v, ck, cv, seq_lens, block_tables,
+                        pool_base=base, pool_pages=npages,
+                        ownership=ownership)
+                else:
+                    ck, cv = write_kv_pages(ck, cv, k, v, seq_lens,
+                                            block_tables + base)
+                    o = paged_attention(q, ck, cv, seq_lens + 1,
+                                        block_tables, pool_base=base,
+                                        pool_pages=npages,
+                                        ownership=ownership)
+                o = o.reshape(S, nq * hd).astype(h.dtype)
+                h = self._residual(
+                    h, self._proj(o, w["out_weight"], li, stream))
+            h, counts = self._ffn(w, h, l, active, True, counts)
+        cache2 = PagedKV(ck, cv) if ck is not None else None
+        state2 = RecurrentState(ssm, conv) if ssm is not None else None
+        return h, cache2, state2, counts

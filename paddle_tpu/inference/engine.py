@@ -29,7 +29,22 @@ from .kv_cache import BlockKVCacheManager, gather_rows, restore_scatter_jit
 
 __all__ = ["FusedCausalLM", "GenerationEngine",
            "ContinuousBatchingEngine", "GenRequest",
-           "DEFAULT_DECODE_CHUNK"]
+           "DEFAULT_DECODE_CHUNK", "RecurrentStateUnsupported"]
+
+
+class RecurrentStateUnsupported(NotImplementedError):
+    """A mechanism that snapshots, shares or replays PAGES was asked of a
+    model whose recurrent layers keep slot-indexed state beside the pool
+    (prefix reuse, speculative verify, slot migration, host-tier spill):
+    carrying pages alone would be silently wrong, so the engine refuses.
+    Counted in ``serving.recurrent.refusals``."""
+
+
+def _refuse_recurrent(what: str):
+    _stats.inc("serving.recurrent.refusals")
+    raise RecurrentStateUnsupported(
+        f"{what} moves K/V pages only; this model's recurrent layers "
+        "keep slot-indexed state that it would leave behind")
 
 #: auto-picked decode scan-chunk: 128 measured best on the 1.3B bench
 #: geometry (chunk 64 -> 128: +7% tok/s, bench_profile.json r5 — one
@@ -674,7 +689,8 @@ class ContinuousBatchingEngine:
         # pins the queue until it fits (bounded unfairness)
         self.admit_window = max(int(admit_window), 1)
         self.starvation_bound = max(int(starvation_bound), 1)
-        self._gen = GenerationEngine.__new__(GenerationEngine)  # share
+        gen_cls = getattr(model, "_gen_cls", GenerationEngine)
+        self._gen = gen_cls.__new__(gen_cls)  # share
         self._gen.model = model
         self._gen.max_length = self.max_length
         self._gen.page_size = self.page_size
@@ -683,22 +699,41 @@ class ContinuousBatchingEngine:
                                       mp_degree=mp_degree,
                                       ep_degree=ep_degree)
         st = model.stack
+        # the stack's description: a cache group a layer kind — pages
+        # for the attention layers only, slot-indexed state for the
+        # recurrent ones (none in the one-kind pattern)
+        pattern = st.pattern
+        recurrent = pattern.recurrent
+        att = pattern.attention
+        if att is None:
+            raise NotImplementedError(
+                "a pattern without attention layers has no paged pool: "
+                "the engines page every sequence")
         self._pages_per_seq = -(-self.max_length // self.page_size)
         requested = (num_pages or self.max_batch * self._pages_per_seq) + 1
         tp = self._gen._tp
         self._mgr = BlockKVCacheManager(
-            st.num_layers, st.num_kv_heads, st.head_dim, self.page_size,
+            pattern.n_attention, att.num_kv_heads, att.head_dim,
+            self.page_size,
             num_pages=_round_pool_pages(requested, self.page_size),
             dtype=self._gen._kv_dtype, reserve_scratch=True,
             mp_degree=tp.mp if tp else 1,
-            mesh=tp.mesh if tp else None)
+            mesh=tp.mesh if tp else None,
+            recurrent=recurrent, slots=self.max_batch)
         _stats.set_gauge("serving.pool_pages_requested", requested)
         _stats.set_gauge("serving.pool_pages", self._mgr.num_pages)
         cache = self._mgr.fresh_cache()
         self._ck, self._cv = cache.k, cache.v
-        self._cos, self._sin = rope_table(st.max_position, st.head_dim,
-                                          st.rope_theta)
-        self._gen._cos, self._gen._sin = self._cos, self._sin
+        # None on a model without recurrent layers: the programs then
+        # take and return the pool's two sides alone
+        self._rs = self._mgr.fresh_recurrent_state(self._gen._cdtype)
+        if recurrent is None:
+            self._cos, self._sin = rope_table(
+                st.max_position, st.head_dim, st.rope_theta)
+            self._gen._cos, self._gen._sin = self._cos, self._sin
+        else:
+            _stats.set_gauge("serving.recurrent.state_bytes",
+                             recurrent.bytes_per_slot() * self.max_batch)
         self._gen._mgr = self._mgr
 
         self.waiting: list = []
@@ -736,6 +771,9 @@ class ContinuousBatchingEngine:
         # heads), a Drafter instance, or a small FusedCausalLM draft
         # model; ``spec_k`` defaults to FLAGS_spec_k.
         self._spec = None
+        if speculative and recurrent is not None:
+            _refuse_recurrent("speculative verify (rejected drafts roll "
+                              "the page table back)")
         if speculative:
             from .speculative import build_speculative_decoder
 
@@ -774,14 +812,38 @@ class ContinuousBatchingEngine:
         t_run0 = self._now()
         with RecordEvent("serve.run", program=program.name):
             t0 = time.perf_counter()
-            toks, self._ck, self._cv = program(
-                *lead, self._ck, self._cv, *tail)
-            toks_np = np.asarray(toks)
+            toks_np = self._fetch(self._run_program(program, lead, tail))
         self._run_ts = (t_run0, self._now())
         # synced by the fetch above — an honest per-chunk roofline
         _roofline.analyze(program.name, time.perf_counter() - t0)
         with RecordEvent("serve.emit"):
             return self._emit_decode(toks_np, active, k)
+
+    def _run_program(self, program, lead, tail):
+        """``program(*lead, <cache>, *tail)``: the cache (the pool's two
+        sides and, on a model with recurrent layers, the slot-indexed
+        state) is donated and rebound; returns the program's first
+        result."""
+        if self._rs is None:
+            out, self._ck, self._cv = program(
+                *lead, self._ck, self._cv, *tail)
+        else:
+            out, self._ck, self._cv, self._rs = program(
+                *lead, self._ck, self._cv, self._rs, *tail)
+        return out
+
+    def _fetch(self, out):
+        """A program's first result on the host. A pattern-built model
+        returns its expert layers' pick counts beside it: both come in
+        the one fetch and the counts go to the ``serving.moe.*``
+        counters."""
+        if self._rs is None:
+            return np.asarray(out)
+        first, counts = jax.device_get(out)
+        for name, n in zip(("picks", "picks_here", "experts_hit",
+                            "experts_held"), counts):
+            _stats.inc("serving.moe." + name, int(n))
+        return first
 
     def _plan_decode(self, k: int):
         """The host's work before a decode chunk's program call: page
@@ -841,6 +903,10 @@ class ContinuousBatchingEngine:
                 self._gen._head_t, lnf_s, lnf_b,
                 jnp.asarray(self._last_tok, jnp.int32),
                 jnp.asarray(cur, jnp.int32))
+        if self._rs is not None:
+            # rows that decode; the others (idle slots, slots whose
+            # prompt is still prefilling) keep pool and state
+            extra = (jnp.asarray([r is not None for r in self._slots]),)
         return (active, self._gen._get_decode_k(k, adaptered=adaptered),
                 lead, (tables, *extra))
 
@@ -968,7 +1034,7 @@ class ContinuousBatchingEngine:
         planes and TP pools shard by kv-head — both fall back to the
         preemption-by-recompute path on a fleet drain."""
         return not isinstance(self._ck, tuple) \
-            and self._mgr._mesh is None
+            and self._mgr._mesh is None and self._rs is None
 
     def export_slot(self, i: int) -> dict:
         """Export decode slot ``i``'s live state for page-granular
@@ -978,6 +1044,8 @@ class ContinuousBatchingEngine:
         ``BlockKVCacheManager.phys_rows``). Pages are NOT freed here;
         the caller releases the slot only after the import lands, so
         a failed migration leaves this engine untouched."""
+        if self._rs is not None:
+            _refuse_recurrent("slot export")
         if not self.can_migrate():
             raise NotImplementedError(
                 "KV-page migration needs a plain pool (no int8 "
@@ -1004,6 +1072,8 @@ class ContinuousBatchingEngine:
         replicated weights) are byte-identical. False when the slot is
         occupied or the pool can't cover the pages (the caller falls
         back to recompute)."""
+        if self._rs is not None:
+            _refuse_recurrent("slot import")
         if not self.can_migrate():
             raise NotImplementedError(
                 "KV-page migration needs a plain pool (no int8 "
@@ -1146,7 +1216,7 @@ class ContinuousBatchingEngine:
         """Host-DRAM spill/restore supports plain AND int8 pools; only
         TP kv-head-sharded pools fall back (a one-shard blob could not
         restore into a differently-sharded peer pool)."""
-        return self._mgr._mesh is None
+        return self._mgr._mesh is None and self._rs is None
 
     def _scale_cols(self, rows_np: np.ndarray) -> np.ndarray:
         """Scale-plane columns of the given pool rows: row r position t
@@ -1161,6 +1231,8 @@ class ContinuousBatchingEngine:
         layer-major page-inner layout per ``phys_rows``, so the blob
         scatters back via ``import_kv_pages`` on any engine with the
         same geometry. int8 pools add the per-token scale columns."""
+        if self._rs is not None:
+            _refuse_recurrent("host-tier page spill")
         if not self.can_spill():
             raise NotImplementedError(
                 "host-tier KV spill needs an unsharded pool — TP "
@@ -1227,6 +1299,7 @@ class ContinuousBatchingEngine:
 
     def _release(self, i: int):
         self._mgr.free(("slot", i))
+        self._mgr.recurrent_free(i)
         self._slots[i] = None
         self._lens[i] = 0
         self._last_tok[i] = 0
@@ -1323,6 +1396,11 @@ class ContinuousBatchingEngine:
         ``i``. (The serving frontend overrides this with chunked
         prefill: the prompt fills in fixed-size chunks interleaved with
         decode steps instead of one monolithic program.)"""
+        if self._rs is not None:
+            raise NotImplementedError(
+                "a model with recurrent layers prefills in chunks that "
+                "carry its state: serve it through "
+                "paddle_tpu.serving.ServingEngine")
         self._slots[i] = req
         _stats.inc("serving.admitted")
         self._gen._count_a8w8(1)

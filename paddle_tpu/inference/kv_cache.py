@@ -68,8 +68,18 @@ class BlockKVCacheManager:
     def __init__(self, num_layers: int, num_kv_heads: int, head_dim: int,
                  page_size: int = 16, num_pages: int = 512,
                  dtype=jnp.float32, reserve_scratch: bool = False,
-                 mp_degree: int = 1, mesh=None, mp_axis: str = "mp"):
+                 mp_degree: int = 1, mesh=None, mp_axis: str = "mp",
+                 recurrent=None, slots: int = 0):
         self.num_layers = num_layers
+        # recurrent layers (a ``LayerPattern.recurrent`` spec): their
+        # state is indexed by decode SLOT, not by page — allocated with
+        # the slot, zeroed at admission, carried from prefill chunk to
+        # prefill chunk and through every decode step, released with the
+        # slot. ``_fresh`` holds the slots admitted since their last
+        # prefill chunk landed: that chunk starts from zeros.
+        self.recurrent = recurrent
+        self.slots = int(slots)
+        self._fresh: set = set()
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
         self.page_size = page_size
@@ -159,6 +169,42 @@ class BlockKVCacheManager:
             return PagedKV(zero(), zero())
         return PagedKV(jnp.zeros(shape, self.dtype),
                        jnp.zeros(shape, self.dtype))
+
+    def fresh_recurrent_state(self, conv_dtype=None):
+        """The slot-indexed state of the recurrent layers, zeros, or None
+        for a pattern without any (``RecurrentState``: ssm float32
+        ``[layers, slots, d_state, d_inner]``, conv tail in the compute
+        dtype)."""
+        r = self.recurrent
+        if r is None:
+            return None
+        from ..incubate.nn.hybrid_stack import RecurrentState
+
+        return RecurrentState(
+            jnp.zeros((r.layers, self.slots, r.d_state, r.d_inner),
+                      jnp.float32),
+            jnp.zeros((r.layers, self.slots, r.conv_rows, r.conv_dim),
+                      conv_dtype or self.dtype))
+
+    def recurrent_admit(self, slot: int) -> None:
+        """A sequence takes ``slot``: whatever state the slot holds is
+        dead, the sequence's first prefill chunk starts from zeros (a
+        preempted request re-admitted for recompute comes through here
+        too). No device work: the reset rides in that chunk's program."""
+        if self.recurrent is None:
+            return
+        self._fresh.add(slot)
+
+    def recurrent_is_fresh(self, slot: int) -> bool:
+        return slot in self._fresh
+
+    def recurrent_landed(self, slot: int) -> None:
+        """A prefill chunk of ``slot`` landed: the state it wrote is the
+        sequence's own from here on."""
+        self._fresh.discard(slot)
+
+    def recurrent_free(self, slot: int) -> None:
+        self._fresh.discard(slot)
 
     def pages_needed(self, length: int) -> int:
         return -(-length // self.page_size)

@@ -378,8 +378,10 @@ def _pad_rows(x, t_pad):
     return jnp.pad(x, ((0, t_pad - t), (0, 0)))
 
 
-def _raw_grouped(x, w, b, offsets, activation, backend):
-    """One ragged grouped GEMM, f32 output [T, N] (no autodiff)."""
+def _raw_grouped(x, w, b, offsets, activation, backend, first_group=0):
+    """One ragged grouped GEMM, f32 output [T, N] (no autodiff). The
+    ``len(offsets) - 1`` groups are rows ``first_group ..`` of the bank
+    ``w`` (0 and the whole bank, except through ``grouped_gemm_banked``)."""
     T, K = x.shape
     E, _, N = w.shape
     geo = _geometry(K, N, w.dtype.itemsize)
@@ -393,6 +395,8 @@ def _raw_grouped(x, w, b, offsets, activation, backend):
     x_pad = _pad_rows(x, t_pad)
     b3 = b.reshape(E, 1, N).astype(jnp.float32)
     gids, tids, lo, hi = grouped_work_map(offsets, t_pad, bm)
+    if first_group:
+        gids = gids + jnp.int32(first_group)
     if backend == "xla":
         out = _grouped_fwd_xla(x_pad, w, b3, gids, tids, lo, hi,
                                bm, bn, activation)
@@ -496,6 +500,26 @@ def grouped_gemm(x, w, offsets, *, bias=None, activation=None,
     out_dtype = out_dtype or x.dtype
     return _grouped_core(x, w, b, jnp.asarray(offsets, jnp.int32),
                          activation, backend, out_dtype)
+
+
+def grouped_gemm_banked(x, bank, offsets, first_group: int, *,
+                        backend="auto"):
+    """The serving form over a LAYER-STACKED bank read in place:
+    ``bank [G, K, N]`` holds every layer's experts back to back
+    (``stack.reshape(L * E, K, N)``: no copy) and this call's ``E =
+    len(offsets) - 1`` groups are its rows ``first_group ..
+    first_group + E - 1`` — the kernel's weight block index is shifted,
+    so no per-layer slice of the bank is ever materialised (a slice
+    handed to a Pallas call is copied: 432 MB a layer at granite's
+    widths). No bias, no activation, no autodiff; float32 ``[T, N]``."""
+    G, _, N = bank.shape
+    if offsets.shape[0] - 1 + int(first_group) > G:
+        raise ValueError(
+            f"grouped_gemm_banked: groups {first_group}.."
+            f"{first_group + offsets.shape[0] - 2} exceed the bank's {G}")
+    return _raw_grouped(x, bank, jnp.zeros((G, N), jnp.float32),
+                        jnp.asarray(offsets, jnp.int32), None, backend,
+                        first_group=int(first_group))
 
 
 # ---------------------------------------------------------------------

@@ -90,6 +90,7 @@ from .faults import (DeadlineExceeded, PoolSizingError, ServerOverloaded,
                      TokenCorruption, WatchdogTimeout)
 from .journal import FlightRecorder
 from .prefix_cache import PrefixCache
+from ..inference.engine import _refuse_recurrent
 from .request import Request
 from .slo import SLOMonitor
 
@@ -106,7 +107,10 @@ class SLOConfig:
     bound's unit; one compiled program serves every chunk of this size).
     ``admit_window`` / ``starvation_bound``: admission skip-ahead reach
     and its fairness bound (inference/engine.py ``_pick_waiting``).
-    ``prefix_cache``: enable prefix/KV reuse; ``prefix_cache_pages``
+    ``prefix_cache``: enable prefix/KV reuse (None, the default: on
+    wherever the model can reuse pages, i.e. off on a model with
+    recurrent layers, whose state the pages do not hold; an explicit
+    True there is refused at engine construction); ``prefix_cache_pages``
     caps the registered pages (None = pool-pressure eviction only).
     ``ttft_target_ms`` / ``tpot_target_ms``: per-request SLO targets
     the monitor (serving/slo.py) judges verdicts against (None
@@ -126,7 +130,7 @@ class SLOConfig:
     def __init__(self, ttft_weight: float = 1.0,
                  tpot_weight: float = 1.0, prefill_chunk: int = 256,
                  admit_window: int = 8, starvation_bound: int = 16,
-                 prefix_cache: bool = True,
+                 prefix_cache: Optional[bool] = None,
                  prefix_cache_pages: Optional[int] = None,
                  ttft_target_ms: Optional[float] = 1000.0,
                  tpot_target_ms: Optional[float] = 100.0,
@@ -141,7 +145,8 @@ class SLOConfig:
         self.prefill_chunk = max(int(prefill_chunk), 1)
         self.admit_window = max(int(admit_window), 1)
         self.starvation_bound = max(int(starvation_bound), 1)
-        self.prefix_cache = bool(prefix_cache)
+        self.prefix_cache = None if prefix_cache is None \
+            else bool(prefix_cache)
         self.prefix_cache_pages = prefix_cache_pages
         self.ttft_target_ms = None if ttft_target_ms is None \
             else float(ttft_target_ms)
@@ -236,7 +241,13 @@ class ServingEngine(ContinuousBatchingEngine):
         self._n_steps = 0         # ``step=`` of the pt.serve.step span
         self.last_crash_dump: Optional[str] = None
         self.prefix_cache: Optional[PrefixCache] = None
-        if slo.prefix_cache:
+        want_prefix = slo.prefix_cache
+        if self._rs is not None:
+            if want_prefix:
+                _refuse_recurrent("SLOConfig(prefix_cache=True): prefix "
+                                  "reuse")
+            want_prefix = False
+        if want_prefix or want_prefix is None:
             self.prefix_cache = PrefixCache(
                 self._mgr, self.page_size, slo.prefix_cache_pages,
                 journal=self.journal)
@@ -736,6 +747,7 @@ class ServingEngine(ContinuousBatchingEngine):
         """Vacate prefill slot ``i`` and free its pages (no requeue —
         the caller decides the request's fate)."""
         stt = self._prefilling.pop(i, None)
+        self._mgr.recurrent_free(i)
         if ("prefill", i) in self._mgr._owned:
             self._mgr.free(("prefill", i))
         u = self.usage
@@ -1282,6 +1294,12 @@ class ServingEngine(ContinuousBatchingEngine):
                 # credit (the prefix-cache's own refs charge nobody)
                 u.credit_prefix(req, len(shared))
                 u.set_pages(req, len(shared), now=now)
+        if self._rs is not None:
+            # the slot's recurrent state is dead from here: this
+            # sequence's first chunk starts from zeros, also when it is
+            # a preempted request coming back for recompute
+            self._mgr.recurrent_admit(i)
+            _stats.inc("serving.recurrent.resets")
         self._prefilling[i] = _Prefill(
             req, pos=len(shared) * self.page_size, tokens=toks)
         self._admitting = None
@@ -1406,13 +1424,18 @@ class ServingEngine(ContinuousBatchingEngine):
         add programs (at most 2 per chunk size)."""
         key = (c, adaptered)
         if key not in self._chunk_jit:
-            import functools
+            rung = self._chunk_rung(c, adaptered)
+            if self._rs is not None:
+                # a pattern-built model brings its own chunk program
+                # (the recurrent state rides with the pool)
+                prog = self._gen._get_chunk_prefill(rung)
+            else:
+                import jax
 
-            import jax
-
-            self._chunk_jit[key] = _roofline.AotProgram(
-                self._chunk_rung(c, adaptered),
-                jax.jit(self._chunk_prefill_fn, donate_argnums=(8, 9)))
+                prog = _roofline.AotProgram(
+                    rung, jax.jit(self._chunk_prefill_fn,
+                                  donate_argnums=(8, 9)))
+            self._chunk_jit[key] = prog
         return self._chunk_jit[key]
 
     def _chunk_prefill_fn(self, weights, embed, head_t, lnf_s, lnf_b,
@@ -1453,10 +1476,14 @@ class ServingEngine(ContinuousBatchingEngine):
         with RecordEvent("serve.run", program=program.name,
                          rid=stt.req.id):
             t0 = time.perf_counter()
-            logits, self._ck, self._cv = program(
-                *lead, self._ck, self._cv, *tail)
-            tok = int(np.asarray(
-                self._gen._argmax(jnp.asarray(logits)))[0])
+            out = self._run_program(program, lead, tail)
+            if self._rs is None:
+                tok = int(np.asarray(
+                    self._gen._argmax(jnp.asarray(out)))[0])
+            else:
+                # a pattern-built model's chunk program picks the token
+                # itself and returns it with its pick counts
+                tok = int(self._fetch(out)[0])
         self._run_ts = (t_run0, self._now())
         # the argmax fetch synced the chunk — honest phase roofline
         _roofline.analyze(program.name, time.perf_counter() - t0)
@@ -1554,6 +1581,13 @@ class ServingEngine(ContinuousBatchingEngine):
                      self.adapters.operands(tp=self._gen._tp))
             _stats.inc("lora.grouped_launches",
                        4 * self.model.stack.num_layers)
+        if self._rs is not None:
+            # which slot's state the chunk continues, and whether it
+            # starts from zeros (the first chunk after an admission)
+            fresh = self._mgr.recurrent_is_fresh(i)
+            if not fresh:
+                _stats.inc("serving.recurrent.resumed_chunks")
+            extra = (jnp.asarray([i], jnp.int32), jnp.asarray([fresh]))
         lead = (self._gen._weights(), self._gen._embed(),
                 self._gen._head_t, lnf_s, lnf_b, jnp.asarray(ids),
                 jnp.asarray([stt.pos], jnp.int32),
@@ -1582,6 +1616,7 @@ class ServingEngine(ContinuousBatchingEngine):
                 f"{tok} outside [0, {self.model.vocab_size})")
         _stats.inc("serve.prefill_chunks")
         _stats.inc("serve.prefill_tokens", n)
+        self._mgr.recurrent_landed(i)
         u = self.usage
         if u is not None:
             u.add_tokens(req, prefill=n)
@@ -1636,6 +1671,7 @@ class ServingEngine(ContinuousBatchingEngine):
         by the surviving prefilling slots, which can now grow."""
         stt = self._prefilling.pop(i)
         self._mgr.free(("prefill", i))
+        self._mgr.recurrent_free(i)
         _stats.inc("serving.prefill_requeues")
         req = stt.req
         req.n_requeues = getattr(req, "n_requeues", 0) + 1

@@ -54,6 +54,9 @@ KERNELS = [
     "grouped_gemm.bwd",
     "lora.delta",
     "attention.flash",
+    "ssd.chunk_scan",
+    "ssm.decode_update",
+    "moe.stream_experts",
 ]
 
 L, D, DFF, NQ, HEADS, HEAD_DIM, PAGE = 24, 2048, 8192, 3 * 2048, 16, 128, 16
