@@ -249,18 +249,19 @@ def _build_decode_inplace():
 
     def fn(q, nk, nv, kc, vc, lens, tables):
         return paged_decode_attention_inplace(
-            q, nk, nv, kc, vc, lens, tables, pool_base=0, pool_pages=128)
+            q, nk, nv, kc, vc, lens, tables, pool_base=0)
 
     return fn, (q, nk, nk, kc, vc, lens, tables)
 
 
 def _expected_decode_inplace():
+    # the walk: b * pp = 64 entries = one chunk of 64 pages (1024 tokens)
     b, n_kv, d, ps = (_POOL[k] for k in ("b", "n_kv", "d", "ps"))
     bf = "bfloat16"
     return (_B((n_kv, b, d), bf)                   # qt
-            + 2 * _B((1, b, 1024), "int32")        # ownership mask chunk
+            + _B((1, 1024), "int32")               # the walk's token owners
             + 2 * _B((n_kv, b, d), bf)             # nk_t + nv_t operands
-            + 2 * _B((b, n_kv, ps, d), bf)         # nk_w + nv_w page patch
+            + 2 * _B((b, n_kv, 1, d), "float32")   # nk_w + nv_w page patch
             + _B((b, 1, ps, 1), "float32")         # slot selector
             + _B((n_kv, b, d), "float32")          # out
             + 2 * 2 * _B((64, n_kv, ps, d), bf)    # kb + vb chunk scratch

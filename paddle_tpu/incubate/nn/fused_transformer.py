@@ -772,16 +772,17 @@ class FusedMultiTransformer(Layer):
                                  overlap=overlap)
         npages = self._pages_per_layer(cache)
         lens1 = (seq_lens + 1).astype(jnp.int32)
-        # token-level pool ownership (the stream kernels' mask) is
-        # layer-independent: compute ONCE per decode step, share across
-        # the 24-layer loop
+        # what the attention kernels mask with is layer-independent:
+        # compute it ONCE per decode step, share across the 24-layer loop
         from ...core.flags import flag
         from ...device import chip as _chip
         from ...nn.functional.paged_attention import (
-            build_pool_ownership, paged_decode_attention_inplace_q)
+            build_page_walk, build_pool_ownership,
+            paged_decode_attention_inplace_q)
 
         quantized_kv = isinstance(cache.k, tuple)
         fused_stream = False
+        ownership = walk = None
         if quantized_kv:
             # int8 cache-KV mode: always the fused quantized kernel
             # (interpret off-TPU); the pools never touch a non-Pallas op
@@ -794,11 +795,11 @@ class FusedMultiTransformer(Layer):
                             and _chip.on_tpu()
                             and self.head_dim % 128 == 0)
             if fused_stream:
-                # fused append+attend kernel masks with seq_lens
-                # (current token joins from the operands)
-                ownership = build_pool_ownership(
-                    block_tables, seq_lens.astype(jnp.int32), npages,
-                    cache.k.shape[2])
+                # fused append+attend kernel: the list of the pages the
+                # tables name at seq_lens (the current token joins from
+                # the operands)
+                walk = build_page_walk(block_tables, seq_lens,
+                                       cache.k.shape[2])
             else:
                 ownership = build_pool_ownership(
                     block_tables, lens1, npages, cache.k.shape[2])
@@ -818,8 +819,7 @@ class FusedMultiTransformer(Layer):
             if fused_stream:
                 return paged_decode_attention_inplace(
                     q, k, v, ck, cv, seq_lens, tbl,
-                    pool_base=base, pool_pages=npages,
-                    ownership=ownership)
+                    pool_base=base, walk=walk)
             ck, cv = write_kv_pages(ck, cv, k, v, seq_lens, tbl + base)
             att = paged_attention(q, ck, cv, lens1, tbl,
                                   pool_base=base, pool_pages=npages,

@@ -296,7 +296,7 @@ class HybridStack(Layer):
         state', counts int32 [4])``."""
         from ...device import chip as _chip
         from ...nn.functional.paged_attention import (
-            build_pool_ownership, paged_attention,
+            build_page_walk, build_pool_ownership, paged_attention,
             paged_decode_attention_inplace, write_kv_pages)
         from ...nn.functional.ssm import (causal_conv1d_step,
                                           expand_heads, ssm_decode_update)
@@ -310,13 +310,17 @@ class HybridStack(Layer):
         ssm, conv = state if state is not None else (None, None)
         npages = ck.shape[0] // max(p.n_attention, 1) \
             if ck is not None else 0
-        ownership = None
+        ownership = walk = None
         fused = False
         if ck is not None:
+            # layer-independent: built once a step, shared by the layers
             fused = _chip.on_tpu() and p.attention.head_dim % 128 == 0
-            ownership = build_pool_ownership(
-                block_tables, seq_lens if fused else seq_lens + 1,
-                npages, ck.shape[2])
+            if fused:
+                walk = build_page_walk(block_tables, seq_lens,
+                                       ck.shape[2])
+            else:
+                ownership = build_pool_ownership(
+                    block_tables, seq_lens + 1, npages, ck.shape[2])
         counts = jnp.zeros((4,), jnp.int32)
         h = x
         for l, kind in enumerate(p.kinds()):
@@ -360,8 +364,7 @@ class HybridStack(Layer):
                 if fused:
                     o, ck, cv = paged_decode_attention_inplace(
                         q, k, v, ck, cv, seq_lens, block_tables,
-                        pool_base=base, pool_pages=npages,
-                        ownership=ownership)
+                        pool_base=base, walk=walk)
                 else:
                     ck, cv = write_kv_pages(ck, cv, k, v, seq_lens,
                                             block_tables + base)

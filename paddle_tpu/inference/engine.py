@@ -890,6 +890,20 @@ class ContinuousBatchingEngine:
 
         cur = np.where([r is not None for r in self._slots],
                        self._lens - 1, 0).astype(np.int64)
+        if not isinstance(self._ck, tuple):
+            # what the decode kernel walks in this chunk, a step and
+            # attention layer: the pages the tables name at the step's
+            # lengths (every row advances a token a step), beside the
+            # pages of the layer's region (the int8 pool's kernel still
+            # walks its whole region and counts nothing)
+            named = np.minimum(
+                -(-(cur[:, None] + np.arange(k)[None, :])
+                  // self.page_size), self._pages_per_seq)
+            layers = self._mgr.num_layers
+            _stats.inc("serving.kv.pages_walked",
+                       int(named.sum()) * layers)
+            _stats.inc("serving.kv.pages_region",
+                       self._mgr.num_pages * layers * k)
         lnf_s, lnf_b = self._gen._lnf()
         a_slots, a_banks = self._adapter_operands(active)
         adaptered = a_banks is not None

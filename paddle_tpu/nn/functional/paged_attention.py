@@ -37,6 +37,8 @@ FLAGS_paged_attention_backend=pallas via an explicit transpose.
 from __future__ import annotations
 
 import contextlib
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -295,8 +297,11 @@ STREAM_CHUNK_TOKENS = 1024
 
 
 def stream_chunk_pages(page_size: int) -> int:
-    """Full-target pages-per-chunk for a page size (the pool-size
-    rounding quantum)."""
+    """Full-target pages-per-chunk for a page size: the pool-size
+    rounding quantum, and the entries the in-place decode kernel gathers
+    and scores at a time (on the chip 64 pages of 16 beat 16, 32 and 128
+    at gpt3-1.3b's widths; 128 is 3-5% better at granite's: PERF.md,
+    PR 32)."""
     return max(1, STREAM_CHUNK_TOKENS // max(page_size, 1))
 
 
@@ -461,11 +466,77 @@ def _stream_paged(q, key_cache, value_cache, seq_lens, block_tables,
     return out.reshape(b, n_q, d).astype(q.dtype)
 
 
+class PageWalk(NamedTuple):
+    """The pages one decode step has to read, compacted: entry ``j`` of
+    row ``r`` is live while ``j * page_size < seq_lens[r]``; live entries
+    stand first, row by row, in table order. ``E`` is ``batch *
+    pages_per_seq`` rounded up to whole chunks of ``stream_chunk_pages``.
+
+    index  [E] int32  where the entry stands in the block tables, ``r *
+                      pages_per_seq + j`` (the kernel reads the page id
+                      there; past ``length``: entry 0's index again, so
+                      a short last chunk gathers bytes that are live
+                      and masked)
+    length [1] int32  number of live entries
+    owner  [E] int32  the entry's row, -1 past ``length``
+    pos    [E] int32  sequence position of the page's first slot
+    tok_owner [E // cp, cp * page_size] int32: per token slot of the
+                      list the row that may attend to it (its position
+                      is under the row's length), else -1 -- the mask
+                      the kernel compares against its row ids
+    """
+    index: jax.Array
+    length: jax.Array
+    owner: jax.Array
+    pos: jax.Array
+    tok_owner: jax.Array
+
+
+def build_page_walk(block_tables, seq_lens, page_size) -> PageWalk:
+    """``PageWalk`` of ``block_tables [b, pp]`` at ``seq_lens [b]``
+    (tokens in the pool, the current one not among them). A row takes
+    its pages from ITS table, so a physical page that two tables name
+    (a shared prefix) appears once a row. Layer-independent: build it
+    once a decode step and hand it to every layer's
+    ``paged_decode_attention_inplace``. A row's live entries are a
+    prefix of its table, so the list follows from the rows' page counts
+    alone: compares and row sums over ``[E, b]``, no gather and no
+    scatter (either costs 5 ns an entry on the chip, 0.1-0.3 ms a step:
+    my chip run, PR 32); the table itself is read by the kernel."""
+    b, pp = block_tables.shape
+    ps = int(page_size)
+    cp = stream_chunk_pages(ps)
+    E = -(-(b * pp) // cp) * cp
+    lens = seq_lens.astype(jnp.int32)
+    count = jnp.minimum((lens + (ps - 1)) // ps, pp)       # [b]
+    ends = jnp.cumsum(count, dtype=jnp.int32)              # [b]
+    length = ends[-1]
+    e = jnp.arange(E, dtype=jnp.int32)
+    # rows that end at or before entry e: their number is e's row, the
+    # largest of their ends is where that row starts
+    before = e[:, None] >= ends[None, :]                   # [E, b]
+    row = jnp.minimum(jnp.sum(before, axis=1, dtype=jnp.int32), b - 1)
+    start = jnp.max(jnp.where(before, ends[None, :], 0), axis=1)
+    row_len = jnp.sum(
+        jnp.where(row[:, None] == jnp.arange(b, dtype=jnp.int32)[None, :],
+                  lens[None, :], 0), axis=1, dtype=jnp.int32)
+    live = e < length
+    j = jnp.clip(e - start, 0, pp - 1)
+    index = row * pp + j
+    index = jnp.where(live, index, index[0])
+    owner = jnp.where(live, row, -1)
+    pos = jnp.where(live, j * ps, 0)
+    tok_pos = pos[:, None] + jnp.arange(ps, dtype=jnp.int32)[None, :]
+    tok_owner = jnp.where(tok_pos < row_len[:, None], owner[:, None], -1)
+    return PageWalk(index, length.reshape(1), owner, pos,
+                    tok_owner.reshape(E // cp, cp * ps))
+
+
 def paged_decode_attention_inplace(q, new_k, new_v, key_cache,
                                    value_cache, seq_lens, block_tables,
-                                   pool_base=None, pool_pages=None,
-                                   ownership=None):
-    """Fused KV-append + pool-streaming decode attention, IN PLACE.
+                                   pool_base=None, walk=None):
+    """Fused KV-append + decode attention over the pages the block
+    tables name, IN PLACE.
 
     One Pallas kernel per layer does what the reference's
     masked_multihead_attention_kernel.cu does on GPU: append the current
@@ -482,16 +553,29 @@ def paged_decode_attention_inplace(q, new_k, new_v, key_cache,
     touched only by this kernel, so it stays in the default layout and
     is never copied.
 
-    Mechanics: the sequential grid walks the layer's page region in
-    multi-page chunks (manual double-buffered chunk DMA); every chunk
-    is one batched-per-head MXU matmul for ALL sequences with an
-    ownership mask, online-softmax accumulated in VMEM. The current
-    token's K/V arrive as OPERANDS: they join the softmax as a virtual
-    chunk (diagonal mask), while 2b small DMAs write them into their
-    page slots concurrently — the streamed reads of those rows are
-    masked out (where-before-max also kills any NaN garbage), so the
-    write/read race is benign and the writes land before the kernel
-    returns (waited on the last chunk).
+    What it walks: the ``PageWalk`` of (block_tables, seq_lens) — the
+    compacted list of the pages the rows' tables name for their current
+    lengths — in chunks of ``stream_chunk_pages`` entries. A loop with a
+    dynamic trip count (the list's length over the chunk, rounded up)
+    gathers chunk c+1 page by page into one half of a double buffer
+    (one DMA a page and side: a page is a contiguous [n_kv, ps, d]
+    block) while chunk c is scored: one batched-per-head MXU matmul for
+    ALL rows against the chunk's token slots, masked to each slot's own
+    row, online softmax in VMEM. Pages no table names are never read,
+    whatever the pool holds; the kernel's time follows the tokens in
+    the batch, not the size of the layer's region (until PR 32 it
+    streamed and scored the whole region, 1,664 pages a call whether 30
+    or 1,200 were live). ``pool_base`` is the first physical page of
+    the layer's region (tables and walk hold LAYER-LOCAL ids; it may be
+    a traced loop index). ``walk``: the step's ``build_page_walk``
+    result, shared by all layers (built here when absent).
+
+    The current token's K/V arrive as OPERANDS: they join the softmax
+    as a virtual chunk (diagonal mask), while 2b whole-page DMAs patch
+    them into their page slots concurrently — the gathered reads of
+    those slots are masked out (where-before-max also kills any NaN
+    garbage), so the write/read race is benign and the writes land
+    before the kernel returns.
 
     seq_lens = tokens already cached EXCLUDING the current token (the
     current token's write position, and its softmax entry comes from
@@ -513,25 +597,17 @@ def paged_decode_attention_inplace(q, new_k, new_v, key_cache,
 
     b, n_q, d = q.shape
     _, n_kv, ps, _ = key_cache.shape
-    P = int(pool_pages) if pool_pages is not None else key_cache.shape[0]
     g = n_q // n_kv
     bg = b * g
     scale = d ** -0.5
     NEG = -1e30
 
-    cp = _pick_chunk_pages(P, ps)
+    if walk is None:
+        walk = build_page_walk(block_tables, seq_lens, ps)
+    cp = stream_chunk_pages(ps)
     C = cp * ps
-    nchunks = P // cp
-
-    if ownership is None:
-        ownership = build_pool_ownership(block_tables, seq_lens, P, ps)
-    owner_tok, pos_tok = ownership
-    rows = jnp.arange(b, dtype=jnp.int32)[:, None]
-    valid_full = ((owner_tok[None, :] == rows)
-                  & (pos_tok[None, :]
-                     < seq_lens.astype(jnp.int32)[:, None]))
-    mask3 = jnp.transpose(
-        valid_full.astype(jnp.int32).reshape(b, nchunks, C), (1, 0, 2))
+    nch_max = walk.tok_owner.shape[0]
+    unroll = math.gcd(cp, 8)
 
     qt = jnp.transpose(q.reshape(b, n_kv, g, d), (1, 0, 2, 3)) \
         .reshape(n_kv, bg, d).astype(key_cache.dtype)
@@ -539,22 +615,23 @@ def paged_decode_attention_inplace(q, new_k, new_v, key_cache,
     # [b, n_kv, d] for the page patch (broadcast over slots)
     nk_t = jnp.swapaxes(new_k, 0, 1).astype(key_cache.dtype)
     nv_t = jnp.swapaxes(new_v, 0, 1).astype(value_cache.dtype)
-    # page-shaped broadcast for the patch select (Mosaic can't insert a
-    # sub-minor dim on 16-bit values in-kernel)
-    nk_w = jnp.broadcast_to(new_k.astype(key_cache.dtype)[:, :, None, :],
-                            (b, n_kv, ps, d))
-    nv_w = jnp.broadcast_to(
-        new_v.astype(value_cache.dtype)[:, :, None, :], (b, n_kv, ps, d))
+    # the page patch's view, rounded to the pool's dtype: float32 with
+    # a unit slot dim, which the kernel broadcasts over the page's slots
+    # (Mosaic broadcasts only 32-bit values along the sub-minor dim and
+    # cannot insert one on 16-bit values)
+    nk_w = new_k.astype(key_cache.dtype).astype(jnp.float32)[:, :, None, :]
+    nv_w = new_v.astype(value_cache.dtype).astype(jnp.float32)[:, :, None, :]
 
     base = jnp.asarray(0 if pool_base is None else pool_base, jnp.int32)
     lens_i = seq_lens.astype(jnp.int32)
+    tables = block_tables.astype(jnp.int32)
     # seq_lens < pages_per_seq*page_size guard (see docstring): overfull
     # rows clamp their write-page index in range and zero their slot
     # selector, turning the page RMW into a no-op write-back
     pp = block_tables.shape[1]
     overfull = lens_i >= jnp.int32(pp * ps)                # [b]
     wpages = (jnp.take_along_axis(
-        block_tables.astype(jnp.int32),
+        tables,
         jnp.minimum(lens_i // ps, pp - 1)[:, None],
         axis=1)[:, 0] + base)                              # [b] abs page
     # slot selector as a 4-D f32 operand (single-slot DMA slices violate
@@ -566,27 +643,40 @@ def paged_decode_attention_inplace(q, new_k, new_v, key_cache,
                  == (lens_i % ps)[:, None])
                 & ~overfull[:, None]) \
         .astype(jnp.float32)[:, None, :, None]           # [b,1,ps,1]
-    scalars = jnp.concatenate(
-        [jnp.reshape(base // jnp.int32(cp), (1,)), wpages])
+    # scalars: [region base, chunks to walk, each row's write page]
+    nch = (walk.length + jnp.int32(cp - 1)) // jnp.int32(cp)
+    scalars = jnp.concatenate([jnp.reshape(base, (1,)), nch, wpages])
 
-    def kernel(s_ref, q_ref, mask_ref, nk_ref, nv_ref, nkw_ref, nvw_ref,
-               sm_ref, k_in, v_in, o_ref, k_hbm, v_hbm,
+    def kernel(s_ref, walk_ref, tbl_ref, q_ref, own_ref, nk_ref, nv_ref,
+               nkw_ref, nvw_ref, sm_ref, k_in, v_in, o_ref, k_hbm, v_hbm,
                kb, vb, pgk, pgv, m_ref, l_ref, acc_ref, rsem, pin_sem,
                pout_sem):
-        c = pl.program_id(0)
-        base_c = s_ref[0]
+        del k_in, v_in                      # aliased: k_hbm / v_hbm
+        base_p = s_ref[0]
+        nchunks = s_ref[1]
 
-        def chunk_copy(idx, slot):
+        def page_copies(idx, slot, j):
+            """Entry ``idx * cp + j`` of the walk into page j of half
+            ``slot``: (K copy, V copy)."""
+            pid = base_p + tbl_ref[walk_ref[idx * cp + j]]
             return (
-                pltpu.make_async_copy(
-                    k_hbm.at[pl.ds((base_c + idx) * cp, cp)],
-                    kb.at[slot], rsem.at[slot, 0]),
-                pltpu.make_async_copy(
-                    v_hbm.at[pl.ds((base_c + idx) * cp, cp)],
-                    vb.at[slot], rsem.at[slot, 1]))
+                pltpu.make_async_copy(k_hbm.at[pid], kb.at[slot, j],
+                                      rsem.at[slot, 0]),
+                pltpu.make_async_copy(v_hbm.at[pid], vb.at[slot, j],
+                                      rsem.at[slot, 1]))
+
+        def gather(idx, slot, act):
+            # eight pages a loop step (Mosaic unrolls a loop whole or
+            # not at all; cp copies in a row would be cp x 2 call sites)
+            def eight(j8, carry):
+                for j in range(unroll):
+                    for cpy in page_copies(idx, slot, j8 * unroll + j):
+                        act(cpy)
+                return carry
+            jax.lax.fori_loop(0, cp // unroll, eight, 0)
 
         def page_in(i):
-            pid = s_ref[1 + i]
+            pid = s_ref[2 + i]
             return (
                 pltpu.make_async_copy(k_hbm.at[pid], pgk.at[i],
                                       pin_sem.at[i, 0]),
@@ -594,129 +684,128 @@ def paged_decode_attention_inplace(q, new_k, new_v, key_cache,
                                       pin_sem.at[i, 1]))
 
         def page_out(i):
-            pid = s_ref[1 + i]
+            pid = s_ref[2 + i]
             return (
                 pltpu.make_async_copy(pgk.at[i], k_hbm.at[pid],
                                       pout_sem.at[i, 0]),
                 pltpu.make_async_copy(pgv.at[i], v_hbm.at[pid],
                                       pout_sem.at[i, 1]))
 
-        @pl.when(c == 0)
-        def _():
-            m_ref[...] = jnp.full((n_kv, bg), NEG, jnp.float32)
-            l_ref[...] = jnp.zeros((n_kv, bg), jnp.float32)
-            acc_ref[...] = jnp.zeros((n_kv, bg, d), jnp.float32)
-            for cpy in chunk_copy(jnp.int32(0), jnp.int32(0)):
-                cpy.start()
-            # current token's K/V: read-modify-write each row's page
-            # (whole-page DMAs; the slot row is patched by vector
-            # select). Page-outs overlap the stream — raced reads see
-            # identical bytes except the masked current row — and are
-            # waited on the last chunk.
-            for i in range(b):
-                for cpy in page_in(i):
-                    cpy.start()
-            for i in range(b):
-                for cpy in page_in(i):
-                    cpy.wait()
-            sel = sm_ref[...]                            # [b,1,ps,1] f32
-            inv = jnp.float32(1.0) - sel
-            pgk[...] = (pgk[...].astype(jnp.float32) * inv
-                        + nkw_ref[...].astype(jnp.float32) * sel) \
-                .astype(pgk.dtype)
-            pgv[...] = (pgv[...].astype(jnp.float32) * inv
-                        + nvw_ref[...].astype(jnp.float32) * sel) \
-                .astype(pgv.dtype)
-            for i in range(b):
-                for cpy in page_out(i):
-                    cpy.start()
+        m_ref[...] = jnp.full((n_kv, bg), NEG, jnp.float32)
+        l_ref[...] = jnp.zeros((n_kv, bg), jnp.float32)
+        acc_ref[...] = jnp.zeros((n_kv, bg, d), jnp.float32)
 
-        @pl.when(c + 1 < nchunks)
+        @pl.when(nchunks > 0)
         def _():
-            for cpy in chunk_copy(c + 1, jax.lax.rem(c + 1,
-                                                     jnp.int32(2))):
+            gather(jnp.int32(0), jnp.int32(0), lambda cpy: cpy.start())
+
+        # current token's K/V: read-modify-write each row's page
+        # (whole-page DMAs; the slot row is patched by vector select).
+        # Page-outs overlap the walk — raced reads see identical bytes
+        # except the masked current row — and are waited at the end.
+        for i in range(b):
+            for cpy in page_in(i):
+                cpy.start()
+        for i in range(b):
+            for cpy in page_in(i):
+                cpy.wait()
+        sel = sm_ref[...]                                # [b,1,ps,1] f32
+        inv = jnp.float32(1.0) - sel
+        pgk[...] = (pgk[...].astype(jnp.float32) * inv
+                    + nkw_ref[...] * sel).astype(pgk.dtype)
+        pgv[...] = (pgv[...].astype(jnp.float32) * inv
+                    + nvw_ref[...] * sel).astype(pgv.dtype)
+        for i in range(b):
+            for cpy in page_out(i):
                 cpy.start()
 
-        slot = jax.lax.rem(c, jnp.int32(2))
-        for cpy in chunk_copy(c, slot):
-            cpy.wait()
+        row_id = jax.lax.broadcasted_iota(jnp.int32, (bg, 1), 0) // g
 
-        valid = mask_ref[0] != 0                         # [b, C]
-        if g > 1:
-            valid = jnp.repeat(valid, g, axis=0)         # [bg, C]
+        def chunk(c, carry):
+            slot = jax.lax.rem(c, jnp.int32(2))
 
-        # current-token virtual chunk: row i attends to operand column i
-        diag = (jax.lax.broadcasted_iota(jnp.int32, (bg, b), 0) // g
-                == jax.lax.broadcasted_iota(jnp.int32, (bg, b), 1))
-        last = c == nchunks - 1
+            @pl.when(c + 1 < nchunks)
+            def _():
+                gather(c + 1, jnp.int32(1) - slot,
+                       lambda cpy: cpy.start())
 
-        for h in range(n_kv):
-            k_h = kb[slot, :, h].reshape(C, d)
-            v_h = vb[slot, :, h].reshape(C, d)
-            logits = jax.lax.dot_general(
-                q_ref[h], k_h, (((1,), (1,)), ((), ())),
-                precision=jax.lax.Precision.DEFAULT,
-                preferred_element_type=jnp.float32) * jnp.float32(scale)
-            logits = jnp.where(valid, logits, jnp.float32(NEG))
-            m = m_ref[h]
-            pm = jnp.maximum(m, logits.max(-1))          # [bg]
-            alpha = jnp.exp(m - pm)
-            w = jnp.exp(logits - pm[:, None])            # [bg, C]
-            w = jnp.where(valid, w, jnp.float32(0.0))
-            l_h = l_ref[h] * alpha + w.sum(-1)
-            pv = jax.lax.dot_general(
-                w.astype(v_h.dtype), v_h, (((1,), (0,)), ((), ())),
-                precision=jax.lax.Precision.DEFAULT,
-                preferred_element_type=jnp.float32)      # [bg, d]
-            acc_h = acc_ref[h] * alpha[:, None] + pv
-            m_ref[h] = pm
-            l_ref[h] = l_h
-            acc_ref[h] = acc_h
+            gather(c, slot, lambda cpy: cpy.wait())
+            valid = own_ref[pl.ds(c, 1), :] == row_id    # [bg, C]
 
-        @pl.when(c == nchunks - 1)
-        def _():
-            # fold in the current token from the operands, normalize
+            # head loop (python-unrolled): with heads OUTER in the page
+            # layout, each slice is a run of contiguous [ps, d] blocks
             for h in range(n_kv):
-                lc = jax.lax.dot_general(
-                    q_ref[h], nk_ref[h], (((1,), (1,)), ((), ())),
+                k_h = kb[slot, :, h].reshape(C, d)
+                v_h = vb[slot, :, h].reshape(C, d)
+                logits = jax.lax.dot_general(
+                    q_ref[h], k_h, (((1,), (1,)), ((), ())),
                     precision=jax.lax.Precision.DEFAULT,
                     preferred_element_type=jnp.float32) \
-                    * jnp.float32(scale)                 # [bg, b]
-                lc = jnp.where(diag, lc, jnp.float32(NEG))
+                    * jnp.float32(scale)
+                logits = jnp.where(valid, logits, jnp.float32(NEG))
                 m = m_ref[h]
-                pm = jnp.maximum(m, lc.max(-1))
+                pm = jnp.maximum(m, logits.max(-1))      # [bg]
                 alpha = jnp.exp(m - pm)
-                wc = jnp.exp(lc - pm[:, None])
-                wc = jnp.where(diag, wc, jnp.float32(0.0))
-                l_h = l_ref[h] * alpha + wc.sum(-1)
+                w = jnp.exp(logits - pm[:, None])        # [bg, C]
+                w = jnp.where(valid, w, jnp.float32(0.0))
+                l_h = l_ref[h] * alpha + w.sum(-1)
                 pv = jax.lax.dot_general(
-                    wc.astype(nv_ref.dtype), nv_ref[h],
-                    (((1,), (0,)), ((), ())),
+                    w.astype(v_h.dtype), v_h, (((1,), (0,)), ((), ())),
                     precision=jax.lax.Precision.DEFAULT,
-                    preferred_element_type=jnp.float32)
+                    preferred_element_type=jnp.float32)  # [bg, d]
                 acc_h = acc_ref[h] * alpha[:, None] + pv
-                o_ref[h] = acc_h / jnp.maximum(
-                    l_h, jnp.float32(1e-30))[:, None]
-            for i in range(b):
-                for cpy in page_out(i):
-                    cpy.wait()
+                m_ref[h] = pm
+                l_ref[h] = l_h
+                acc_ref[h] = acc_h
+            return carry
+
+        jax.lax.fori_loop(jnp.int32(0), nchunks, chunk, 0)
+
+        # fold in the current token from the operands (row i attends to
+        # operand column i), normalize
+        diag = (jax.lax.broadcasted_iota(jnp.int32, (bg, b), 0) // g
+                == jax.lax.broadcasted_iota(jnp.int32, (bg, b), 1))
+        for h in range(n_kv):
+            lc = jax.lax.dot_general(
+                q_ref[h], nk_ref[h], (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.DEFAULT,
+                preferred_element_type=jnp.float32) \
+                * jnp.float32(scale)                     # [bg, b]
+            lc = jnp.where(diag, lc, jnp.float32(NEG))
+            m = m_ref[h]
+            pm = jnp.maximum(m, lc.max(-1))
+            alpha = jnp.exp(m - pm)
+            wc = jnp.exp(lc - pm[:, None])
+            wc = jnp.where(diag, wc, jnp.float32(0.0))
+            l_h = l_ref[h] * alpha + wc.sum(-1)
+            pv = jax.lax.dot_general(
+                wc.astype(nv_ref.dtype), nv_ref[h],
+                (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.DEFAULT,
+                preferred_element_type=jnp.float32)
+            acc_h = acc_ref[h] * alpha[:, None] + pv
+            o_ref[h] = acc_h / jnp.maximum(
+                l_h, jnp.float32(1e-30))[:, None]
+        for i in range(b):
+            for cpy in page_out(i):
+                cpy.wait()
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nchunks,),
+        num_scalar_prefetch=3,
+        grid=(1,),
         in_specs=[
-            pl.BlockSpec((n_kv, bg, d), lambda c, s: (0, 0, 0)),
-            pl.BlockSpec((1, b, C), lambda c, s: (c, 0, 0)),
-            pl.BlockSpec((n_kv, b, d), lambda c, s: (0, 0, 0)),
-            pl.BlockSpec((n_kv, b, d), lambda c, s: (0, 0, 0)),
-            pl.BlockSpec((b, n_kv, ps, d), lambda c, s: (0, 0, 0, 0)),
-            pl.BlockSpec((b, n_kv, ps, d), lambda c, s: (0, 0, 0, 0)),
-            pl.BlockSpec((b, 1, ps, 1), lambda c, s: (0, 0, 0, 0)),
+            pl.BlockSpec((n_kv, bg, d), lambda i, *_: (0, 0, 0)),
+            pl.BlockSpec((nch_max, C), lambda i, *_: (0, 0)),
+            pl.BlockSpec((n_kv, b, d), lambda i, *_: (0, 0, 0)),
+            pl.BlockSpec((n_kv, b, d), lambda i, *_: (0, 0, 0)),
+            pl.BlockSpec((b, n_kv, 1, d), lambda i, *_: (0, 0, 0, 0)),
+            pl.BlockSpec((b, n_kv, 1, d), lambda i, *_: (0, 0, 0, 0)),
+            pl.BlockSpec((b, 1, ps, 1), lambda i, *_: (0, 0, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
             pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((n_kv, bg, d), lambda c, s: (0, 0, 0)),
+            pl.BlockSpec((n_kv, bg, d), lambda i, *_: (0, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
             pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         ],
@@ -744,14 +833,14 @@ def paged_decode_attention_inplace(q, new_k, new_v, key_cache,
                 jax.ShapeDtypeStruct(value_cache.shape,
                                      value_cache.dtype),
             ],
-            # inputs are numbered with the scalar-prefetch operand as 0:
-            # key_cache is arg 8, value_cache arg 9 -> outputs 1, 2
-            input_output_aliases={8: 1, 9: 2},
+            # inputs are numbered with the three scalar-prefetch operands
+            # first: key_cache is arg 10, value_cache arg 11
+            input_output_aliases={10: 1, 11: 2},
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
             interpret=not _chip.on_tpu(),
-        )(scalars, qt, mask3, nk_t, nv_t, nk_w, nv_w, slotmask,
-          key_cache, value_cache)
+        )(scalars, walk.index, tables.reshape(-1), qt, walk.tok_owner, nk_t,
+          nv_t, nk_w, nv_w, slotmask, key_cache, value_cache)
     out = jnp.transpose(out.reshape(n_kv, b, g, d), (1, 0, 2, 3))
     return out.reshape(b, n_q, d).astype(q.dtype), ck, cv
 

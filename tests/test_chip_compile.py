@@ -205,14 +205,13 @@ def test_kernel_compiles_for_v5e(name, one_chip, as_on_chip):
         assert shown == set(site.kernels)
 
 
-def _prefill_chunk_program(chunk: int, pages: int):
-    """``prefill_chunk_raw`` as the serving engine jits it: the cell's
-    widths and depth, bf16 weight stacks, one row of ``chunk`` tokens, a
-    pool of ``pages`` pages a layer, both pool sides donated. The stack
-    is a parameterless view (as ``_tp_view`` builds one): the raw
-    methods read config attributes and the weights they are handed."""
+def _uniform_stack():
+    """A parameterless ``FusedMultiTransformer`` at the gpt3-1.3b cells'
+    widths and depth (as ``_tp_view`` builds one: the raw methods read
+    config attributes and the weights they are handed) and the shapes
+    of its bf16 weight stacks."""
     from paddle_tpu.incubate.nn.fused_transformer import (
-        FusedMultiTransformer, PagedKV)
+        FusedMultiTransformer)
 
     st = object.__new__(FusedMultiTransformer)
     for n, v in dict(embed_dim=D, head_dim=HEAD_DIM, dim_feedforward=DFF,
@@ -221,14 +220,23 @@ def _prefill_chunk_program(chunk: int, pages: int):
                      max_position=4096, moe_num_experts=None,
                      moe_top_k=2).items():
         object.__setattr__(st, n, v)
-    bf = jnp.bfloat16
     w = {"ln1_scale": (L, D), "ln1_bias": (L, D),
          "qkv_weight": (L, D, NQ), "qkv_bias": (L, NQ),
          "out_weight": (L, D, D), "out_bias": (L, D),
          "ln2_scale": (L, D), "ln2_bias": (L, D),
          "ffn1_weight": (L, D, DFF), "ffn1_bias": (L, DFF),
          "ffn2_weight": (L, DFF, D), "ffn2_bias": (L, D)}
-    w = {n: _sds(s, bf) for n, s in w.items()}
+    return st, {n: _sds(s, jnp.bfloat16) for n, s in w.items()}
+
+
+def _prefill_chunk_program(chunk: int, pages: int):
+    """``prefill_chunk_raw`` as the serving engine jits it: the cell's
+    widths and depth, bf16 weight stacks, one row of ``chunk`` tokens, a
+    pool of ``pages`` pages a layer, both pool sides donated."""
+    from paddle_tpu.incubate.nn.fused_transformer import PagedKV
+
+    st, w = _uniform_stack()
+    bf = jnp.bfloat16
 
     def fn(w, x, ck, cv, tables, start, lens, cos, sin):
         h, cache = st.prefill_chunk_raw(w, x, PagedKV(ck, cv), tables,
@@ -241,6 +249,91 @@ def _prefill_chunk_program(chunk: int, pages: int):
             _sds((1, 160), jnp.int32), _sds((1,), jnp.int32),
             _sds((1,), jnp.int32), rope, rope)
     return jax.jit(fn, donate_argnums=(2, 3)), args, pool
+
+
+def _uniform_decode_program():
+    """``FusedMultiTransformer.decode_raw`` at gpt3-1.3b's serving cells:
+    32 rows, tables of 160 pages, a pool of 1,664 pages a layer
+    (``[39936, 16, 16, 128]``), both sides donated."""
+    from paddle_tpu.incubate.nn.fused_transformer import PagedKV
+
+    st, w = _uniform_stack()
+    bf = jnp.bfloat16
+
+    def fn(w, x, ck, cv, tables, lens, cos, sin):
+        h, cache = st.decode_raw(w, x, PagedKV(ck, cv), tables, lens,
+                                 cos, sin)
+        return h, cache.k, cache.v
+
+    pool = _sds((L * 1664, HEADS, PAGE, HEAD_DIM), bf)
+    rope = _sds((4096, HEAD_DIM // 2), jnp.float32)
+    args = (w, _sds((32, D), bf), pool, pool, _sds((32, 160), jnp.int32),
+            _sds((32,), jnp.int32), rope, rope)
+    return jax.jit(fn, donate_argnums=(2, 3)), args, pool
+
+
+def _hybrid_decode_program():
+    """``HybridStack.decode_raw`` at granite-4.0-h-small's cell: 64 rows,
+    tables of 321 pages, the one attention layer's pool of 20,544 pages
+    (``[20544, 8, 16, 128]``) and the recurrent state, all donated. The
+    pattern keeps the published widths of mixer, attention and experts
+    and is cut to ONE mamba layer before the attention layer and 4 held
+    experts of the 72: the cell's ten layers take 144 s to compile, and
+    neither the other mixers nor the other experts touch the pool."""
+    from paddle_tpu.incubate.nn.hybrid_stack import (HybridStack,
+                                                     RecurrentState)
+    from paddle_tpu.incubate.nn.fused_transformer import PagedKV
+    from paddle_tpu.incubate.nn.layer_pattern import (
+        ATTENTION, MAMBA, AttentionSpec, LayerPattern, MambaSpec, MoESpec)
+
+    d = 4096
+    p = LayerPattern(
+        d_model=d, period=(MAMBA, ATTENTION), n_periods=1,
+        attention=AttentionSpec(32, 8, HEAD_DIM, scale=0.0078125,
+                                rope_theta=None),
+        mamba=MambaSpec(num_heads=128, head_dim=64, d_state=128),
+        moe=MoESpec(72, 10, 768, shared_dim=1536, experts_held=(0, 4)),
+        norm="rmsnorm", gated=True, bias=False, activation="silu",
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        logits_scaling=16.0)
+    st = object.__new__(HybridStack)
+    object.__setattr__(st, "pattern", p)
+    m, moe, bf, f32 = p.mamba, p.moe, jnp.bfloat16, jnp.float32
+    w = {"m_norm": ((1, d), f32), "m_in": ((1, d, m.in_proj_dim), bf),
+         "m_conv_w": ((1, m.d_conv, m.conv_dim), f32),
+         "m_conv_b": ((1, m.conv_dim), f32),
+         "m_dt_bias": ((1, m.num_heads), f32),
+         "m_A_log": ((1, m.num_heads), f32), "m_D": ((1, m.num_heads), f32),
+         "m_gnorm": ((1, m.d_inner), f32), "m_out": ((1, m.d_inner, d), bf),
+         "a_norm": ((1, d), f32), "qkv_weight": ((1, d, 48 * HEAD_DIM), bf),
+         "out_weight": ((1, 32 * HEAD_DIM, d), bf),
+         "f_norm": ((2, d), f32), "f_router": ((2, d, 72), f32),
+         "e_w1": ((2, 4, d, 2 * moe.expert_dim), bf),
+         "e_w2": ((2, 4, moe.expert_dim, d), bf),
+         "s_w1": ((2, d, 2 * moe.shared_dim), bf),
+         "s_w2": ((2, moe.shared_dim, d), bf)}
+    w = {n: _sds(*sd) for n, sd in w.items()}
+
+    def fn(w, x, ck, cv, ssm, conv, tables, lens, active):
+        h, cache, state, counts = st.decode_raw(
+            w, x, PagedKV(ck, cv), RecurrentState(ssm, conv), tables,
+            lens, active)
+        return h, cache.k, cache.v, state.ssm, state.conv, counts
+
+    pool = _sds((20544, 8, PAGE, HEAD_DIM), bf)
+    args = (w, _sds((64, d), bf), pool, pool,
+            _sds((1, 64, m.d_state, m.d_inner), f32),
+            _sds((1, 64, m.d_conv - 1, m.conv_dim), bf),
+            _sds((64, 321), jnp.int32), _sds((64,), jnp.int32),
+            _sds((64,), jnp.bool_))
+    return jax.jit(fn, donate_argnums=(2, 3, 4, 5)), args, pool
+
+
+def _pool_copies(text, pool):
+    """The optimised HLO's ``copy`` instructions of the pool's shape."""
+    shape = "bf16[%s]" % ",".join(map(str, pool.shape))
+    return [line.strip()[:160] for line in text.splitlines()
+            if re.search(r"= " + re.escape(shape) + r"\S* copy\(", line)]
 
 
 @pytest.mark.parametrize("chunk", [64, 256])
@@ -257,13 +350,29 @@ def test_prefill_chunk_program_never_copies_the_pool(chunk, one_chip,
     jitted, args, pool = _prefill_chunk_program(chunk, pages=256)
     compiled = jitted.lower(*_placed(args, one_chip)).compile()
     text = compiled.as_text()
-    shape = "bf16[%s]" % ",".join(map(str, pool.shape))
-    copies = [line.strip()[:160] for line in text.splitlines()
-              if re.search(r"= " + re.escape(shape) + r"\S* copy\(", line)]
-    assert not copies, copies
+    assert not _pool_copies(text, pool)
     assert _kernel_names(text) == {"pt_paged_kv_write",
                                    "pt_flash_varlen_paged"}
     side = 2 * math.prod(pool.shape)                # bf16 bytes
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < side // 4, mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes == 2 * side      # both sides donated
+
+
+@pytest.mark.parametrize("stack", ["uniform", "hybrid"])
+def test_decode_program_never_copies_the_pool(stack, one_chip, as_on_chip):
+    """The decode step of both stacks at their cells' shapes: the pool
+    passes through ``pt_paged_attention_decode_inplace`` (aliased) and
+    no other instruction, the step's page walk is a few small int32
+    operands beside it, and nothing of the pool's shape is copied; both
+    sides stay donated and the program's temp far under one side."""
+    jitted, args, pool = (_uniform_decode_program() if stack == "uniform"
+                          else _hybrid_decode_program())
+    compiled = jitted.lower(*_placed(args, one_chip)).compile()
+    text = compiled.as_text()
+    assert not _pool_copies(text, pool)
+    assert "pt_paged_attention_decode_inplace" in _kernel_names(text)
+    side = 2 * math.prod(pool.shape)                # bf16 bytes
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < side // 4, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= 2 * side      # both sides donated

@@ -265,3 +265,32 @@ def test_the_base_engine_points_at_chunked_prefill():
     eng.submit([1, 2, 3], max_new_tokens=2)
     with pytest.raises(NotImplementedError, match="ServingEngine"):
         eng.step()
+
+
+@pytest.mark.parametrize("kind", ["uniform", "hybrid"])
+def test_page_walk_counters_count_what_the_tables_name(kind):
+    """``serving.kv.pages_walked`` / ``.pages_region``: a decode step and
+    attention layer, the pages the block tables name at the step's
+    lengths beside the pages of the layer's region. One request of 10
+    prompt tokens and 9 new ones on a page of 4, beside an idle slot:
+    two decode chunks of 4 steps at 10..13 and 14..17 cached tokens name
+    3+3+3+4 and 4+4+4+5 pages; the idle row's length runs 0..3 in each
+    chunk and names its table's first entry (the scratch page) from 1."""
+    if kind == "uniform":
+        paddle.seed(0)
+        m, layers = FusedCausalLM(64, 32, 4, 64, 2), 2
+    else:
+        m, layers = model(), 1          # one attention layer of the four
+    eng = ServingEngine(m, max_batch=2, page_size=4, max_length=64,
+                        decode_chunk=4, prompt_bucket=8,
+                        slo=SLOConfig(prefill_chunk=32, prefix_cache=False))
+    stats.reset()
+    rid = eng.submit(list(prompts(10, seed=1)[0]), max_new_tokens=9)
+    done = {r.id: r for r in eng.run()}
+    assert done[rid].state == "ok" and len(done[rid].generated) == 9
+    snap = stats.snapshot("serving")["counters"]
+    assert snap["serving.decode_steps"] == 8
+    assert snap["serving.kv.pages_walked"] == (13 + 3 + 17 + 3) * layers
+    assert snap["serving.kv.pages_region"] == \
+        eng._mgr.num_pages * layers * 8
+    assert eng._mgr.num_layers == layers

@@ -163,42 +163,102 @@ def test_stream_kernel_parity(g):
         np.testing.assert_allclose(out, ref, atol=3e-2)
 
 
-@pytest.mark.parametrize("g", [1, 2])
-def test_fused_inplace_kernel_parity(g):
+def _alloc_tables(rng, lens_np, pp, ps, P):
+    """Each row gets the pages its length + 1 needs, drawn without
+    replacement from a permutation of 1..P-1 (scattered over the
+    region); the rest of its table is padded with page 0."""
+    tables = np.zeros((len(lens_np), pp), np.int32)
+    perm = rng.permutation(np.arange(1, P))
+    i = 0
+    for r, n_tok in enumerate(lens_np):
+        n = min(-(-int(n_tok + 1) // ps), pp)
+        tables[r, :n] = perm[i:i + n]
+        i += n
+    return tables
+
+
+def _inplace_case(name, rng):
+    """(ps, pp, P, lens, tables, fill free pages with NaN) of one case of
+    the in-place parity test. Page 16 gives the walk chunks of 64
+    entries; page 4 (the original geometry) one chunk of 256."""
+    nan_free = False
+    if name == "ragged":            # incl. an idle slot; one short chunk
+        ps, pp, P = 4, 6, 16
+        lens = np.array([5, 0, 13, 9], np.int32)
+        tables = _alloc_tables(rng, lens, pp, ps, P)
+    elif name in ("scattered", "free_pages_nan"):
+        # 78 live entries of a 300-page region: two chunks, the second
+        # short; every page no table names holds NaN in the second case
+        ps, pp, P = 16, 48, 300
+        lens = np.array([700, 17, 0, 500], np.int32)
+        tables = _alloc_tables(rng, lens, pp, ps, P)
+        nan_free = name == "free_pages_nan"
+    elif name == "shared_page":
+        # rows 0 and 2 name the SAME two physical pages as their first
+        # two (a shared prefix); each must see them as its own
+        ps, pp, P = 16, 8, 40
+        lens = np.array([45, 20, 38, 33], np.int32)
+        tables = _alloc_tables(rng, lens, pp, ps, P)
+        tables[2, :2] = tables[0, :2]
+    elif name == "all_empty":       # nothing to walk: every row length 0
+        ps, pp, P = 16, 4, 24
+        lens = np.zeros((4,), np.int32)
+        tables = _alloc_tables(rng, lens, pp, ps, P)
+    elif name == "full_region":     # every page of the region is live
+        ps, pp, P = 16, 16, 65
+        lens = np.full((4,), pp * ps - 1, np.int32)
+        tables = _alloc_tables(rng, lens, pp, ps, P)
+        assert sorted(tables.ravel()) == list(range(1, P))
+    elif name == "partial_chunk":   # 65 entries: one whole chunk + one page
+        ps, pp, P = 16, 24, 80
+        lens = np.array([16 * 20, 16 * 20, 16 * 20, 16 * 4 + 1], np.int32)
+        tables = _alloc_tables(rng, lens, pp, ps, P)
+    else:
+        raise AssertionError(name)
+    return ps, pp, P, lens, tables, nan_free
+
+
+@pytest.mark.parametrize("case,g", [
+    ("ragged", 1), ("ragged", 2), ("ragged", 4), ("scattered", 2),
+    ("free_pages_nan", 1), ("shared_page", 2), ("all_empty", 1),
+    ("full_region", 1), ("partial_chunk", 4)])
+def test_fused_inplace_kernel_parity(case, g):
     """paged_decode_attention_inplace (the default TPU serving path):
     append + attend in one kernel must equal scatter-write followed by
     the XLA gather attention with lens+1, AND must have patched exactly
     the written rows of the layer's pool region in place (other layers'
-    regions untouched). Interpret mode off-TPU, compiled on the chip."""
+    regions untouched), for both regions of a two-layer pool. The
+    kernel walks the pages the tables name and no others: a region
+    whose free pages hold NaN gives the same finite result. Interpret
+    mode off-TPU, compiled on the chip."""
     from paddle_tpu.nn.functional.paged_attention import (
         _xla_paged, paged_decode_attention_inplace, write_kv_pages)
 
     rng = np.random.RandomState(5)
-    b, n_kv, d, ps = 4, 2, 128, 4
+    ps, pp, P, lens_np, tables_np, nan_free = _inplace_case(case, rng)
+    b, n_kv, d, L = len(lens_np), 2, 128, 2
     n_q = n_kv * g
-    pp, P, L = 6, 16, 2
     q = jnp.asarray(rng.randn(b, n_q, d).astype(np.float32))
     nk = jnp.asarray(rng.randn(b, n_kv, d).astype(np.float32))
     nv = jnp.asarray(rng.randn(b, n_kv, d).astype(np.float32))
-    kpool = jnp.asarray(rng.randn(L * P, n_kv, ps, d).astype(np.float32))
-    vpool = jnp.asarray(rng.randn(L * P, n_kv, ps, d).astype(np.float32))
-    lens_np = np.array([5, 0, 13, 9], np.int32)  # incl. idle slot
-    tables_np = np.zeros((b, pp), np.int32)
-    perm = rng.permutation(np.arange(1, P))
-    i = 0
-    for r in range(b):
-        n = -(-int(lens_np[r] + 1) // ps)
-        tables_np[r, :n] = perm[i:i + n]
-        i += n
+    kpool = rng.randn(L * P, n_kv, ps, d).astype(np.float32)
+    vpool = rng.randn(L * P, n_kv, ps, d).astype(np.float32)
+    if nan_free:
+        free = np.setdiff1d(np.arange(1, P), tables_np.ravel())
+        assert len(free) > P // 2
+        for base in (0, P):
+            kpool[base + free] = np.nan
+            vpool[base + free] = np.nan
+    kpool, vpool = jnp.asarray(kpool), jnp.asarray(vpool)
     lens, tables = jnp.asarray(lens_np), jnp.asarray(tables_np)
     for base in (0, P):
         out, ck, cv = paged_decode_attention_inplace(
-            q, nk, nv, kpool, vpool, lens, tables,
-            pool_base=base, pool_pages=P)
+            q, nk, nv, kpool, vpool, lens, tables, pool_base=base)
         ck_ref, cv_ref = write_kv_pages(
             kpool[base:base + P], vpool[base:base + P], nk, nv, lens,
             tables)
         ref = _xla_paged(q, ck_ref, cv_ref, lens + 1, tables)
+        assert np.isfinite(np.asarray(out)).all()
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=3e-2)
         # in-place page writes: layer region equals the scatter result,
@@ -210,6 +270,53 @@ def test_fused_inplace_kernel_parity(g):
         other = slice(P, 2 * P) if base == 0 else slice(0, P)
         np.testing.assert_array_equal(np.asarray(ck[other]),
                                       np.asarray(kpool[other]))
+        np.testing.assert_array_equal(np.asarray(cv[other]),
+                                      np.asarray(vpool[other]))
+
+
+def test_page_walk_builder_matches_a_numpy_loop():
+    """build_page_walk alone: entries, length, owners, positions and the
+    per-slot mask against the definition written as loops (entry j of
+    row r is live while j * ps < seq_lens[r]); incl. an empty row, an
+    overfull row (all its pp entries, no more) and a shared page."""
+    from paddle_tpu.nn.functional.paged_attention import (
+        build_page_walk, stream_chunk_pages)
+
+    rng = np.random.RandomState(3)
+    ps, b, pp = 16, 5, 30
+    cp = stream_chunk_pages(ps)
+    lens = np.array([0, 33, pp * ps + 7, 16, 250], np.int32)
+    tables = rng.randint(1, 500, (b, pp)).astype(np.int32)
+    tables[4, :2] = tables[1, :2]
+    walk = build_page_walk(jnp.asarray(tables), jnp.asarray(lens), ps)
+
+    pages, owner, pos = [], [], []
+    for r in range(b):
+        for j in range(pp):
+            if j * ps < lens[r]:
+                pages.append(tables[r, j])
+                owner.append(r)
+                pos.append(j * ps)
+    n = len(pages)
+    E = -(-(b * pp) // cp) * cp
+    assert n == 0 + 3 + pp + 1 + 16 and n % cp
+    tok = np.full((E, ps), -1, np.int32)
+    for e in range(n):
+        for t in range(ps):
+            if pos[e] + t < lens[owner[e]]:
+                tok[e, t] = owner[e]
+    assert int(walk.length[0]) == n
+    index = np.asarray(walk.index)
+    assert index.shape == (E,)
+    got_pages = tables.ravel()[index]
+    np.testing.assert_array_equal(got_pages[:n], pages)
+    # past the end: a live page again, never one no table names
+    np.testing.assert_array_equal(got_pages[n:], pages[0])
+    np.testing.assert_array_equal(np.asarray(walk.owner),
+                                  owner + [-1] * (E - n))
+    np.testing.assert_array_equal(np.asarray(walk.pos)[:n], pos)
+    np.testing.assert_array_equal(
+        np.asarray(walk.tok_owner), tok.reshape(E // cp, cp * ps))
 
 
 def test_inplace_overfull_row_masked_noop_write():
@@ -236,8 +343,7 @@ def test_inplace_overfull_row_masked_noop_write():
     lens, tables = jnp.asarray(lens_np), jnp.asarray(tables_np)
 
     out, ck, cv = paged_decode_attention_inplace(
-        q, nk, nv, kpool, vpool, lens, tables, pool_base=0,
-        pool_pages=P)
+        q, nk, nv, kpool, vpool, lens, tables, pool_base=0)
     # expected: ONLY row 1's token written (page tables[1, 5//4]=8,
     # slot 1); row 0's pages — last one included — bit-identical
     exp_k = kpool.at[8, :, 1].set(nk[1])
