@@ -239,7 +239,7 @@ def run_decode_bench(batch=32, prompt=128, new_tokens=129,
                      mp_degree=None):
     # Flagship-comparable serving rung: the decode model matches the
     # gpt3-1.3b training rung (d2048 L24). Round-4 redesign (each step
-    # diagnosed in tools/decode_profile.py + HLO inspection):
+    # diagnosed by ablation + HLO inspection before PR 1):
     # - layer-FOLDED paged pool updated IN PLACE via fori_loop carry
     #   (the r3 scan xs->ys shuttle copied the whole pool every token:
     #   10.8ms/step of pure copy)
@@ -728,19 +728,14 @@ def _run_secondary(kind):
                           "decode_a8w8_roofline": cost_rl,
                           "decode_a8w8_telemetry": _telemetry()}))
     elif kind == "--decode-bf16-grouped":
-        # GROUPED bf16 weight-stream decode (FLAGS_decode_grouped on +
-        # cross-layer prefetch): the fused O+LN2+FFN tail kernel plus
-        # in-tail next-layer QKV — ONE streamed call per layer.
+        # GROUPED bf16 weight-stream decode (the loop every dense
+        # stack runs): the fused O+LN2+FFN tail kernel plus in-tail
+        # next-layer QKV — ONE streamed call per layer.
         # TPU targets for the next chip run (ISSUE r6 / VERDICT r5 #1):
         #   - >= 50% of the weight-bandwidth roofline
-        #   - weights_only_grouped ablation within 2x of the
-        #     weight-read floor
         # (the earlier-round chip records these bars were set against
         # were removed in PR 24; PERF.md holds what was read since)
         # gated by tools/bench_gate.py (direction "down").
-        import paddle_tpu as _p
-
-        _p.set_flags({"decode_grouped": "on", "decode_prefetch": True})
         tps, pct, cost_rl = run_decode_bench()
         print(json.dumps(
             {"decode_bf16_grouped_tokens_per_sec": round(tps, 1),

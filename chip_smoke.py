@@ -13,7 +13,7 @@ user calls, in ONE process (a chip belongs to one process):
           end ``ok`` with 64 tokens; the prefill and decode programs that
           ran must contain ``tpu_custom_call``; one two-chunk prefill and
           one decode step are then repeated through the XLA reference
-          backends on the same weights and inputs and the logits compared.
+          paths on the same weights and inputs and the logits compared.
   train   ``bench.build_train_step(*LADDER[0])`` (b4 x s1024, the _FAST
           optimizer recipe) through ``TrainStep``: 3 steps on one fixed
           seeded batch, loss finite every step and lower at step 3.
@@ -60,13 +60,11 @@ TOY = dict(vocab=512, d_model=128, n_heads=4, n_layers=2,
            n_requests=8, new_tokens=8, len_quantum=16,
            train_seq=64, train_batch=2, ref_tokens=64, prefill_chunk=32)
 
-#: the XLA reference backends the kernel path is checked against
-REFERENCE_FLAGS = {
-    "FLAGS_prefill_attention_backend": "gather",
-    "FLAGS_decode_grouped": "off",
-    "FLAGS_decode_linear": "xla",
-    "FLAGS_paged_attention_backend": "xla",
-}
+#: the XLA reference the kernel path is checked against: every kernel
+#: entry takes its plain-XLA form while ``device.chip.on_tpu`` answers
+#: False (``reference_check`` patches it for the length of the trace);
+#: the chunked prefill's attend has a dense-gather form besides
+REFERENCE_FLAGS = {"FLAGS_prefill_attention_backend": "gather"}
 #: max |logit difference| allowed between the kernel path and the XLA
 #: reference (and between TP=4 and one chip). Random-weight logits here
 #: have a standard deviation of about 0.9; a wrong kernel moves them by
@@ -278,12 +276,14 @@ def compare_logits(what, got, ref) -> dict:
 
 def reference_check(engine, cfg, seed) -> dict:
     """One two-chunk prefill and one decode step: the default (kernel)
-    programs against the same raw functions re-traced under
-    REFERENCE_FLAGS, same weights, same tokens, same page tables."""
+    programs against the same raw functions re-traced with the platform
+    probe answering False (and REFERENCE_FLAGS), same weights, same
+    tokens, same page tables."""
     import jax
     import jax.numpy as jnp
 
     import paddle_tpu as paddle
+    from paddle_tpu.device import chip
 
     rng = np.random.RandomState(seed + 1)
     tokens = rng.randint(0, cfg["vocab"], cfg["ref_tokens"]).tolist()
@@ -306,14 +306,17 @@ def reference_check(engine, cfg, seed) -> dict:
                 jnp.asarray(lens), engine._ck, engine._cv, tables)
     dk = np.asarray(jax.jit(dec)(*dec_args), np.float32)
 
-    # reference path: flags are read at trace time, so the same raw
-    # functions traced again under REFERENCE_FLAGS are the XLA programs.
+    # reference path: the probe and the flags are read at trace time, so
+    # the same raw functions traced again while ``chip.on_tpu`` answers
+    # False are the XLA programs (the assignment that
+    # analysis/sites.py::_force_tpu_routing makes the other way).
     # Each gets a FRESH function object: jit caches traces by function,
     # and a cache hit would hand back the kernel program (the first chip
     # run of this script caught exactly that). Both are compiled ahead of
     # time so their text can be checked: a reference holds no kernel.
     old = paddle.get_flags(list(REFERENCE_FLAGS))
     paddle.set_flags(REFERENCE_FLAGS)
+    probe, chip.on_tpu = chip.on_tpu, lambda: False
     try:
         ref_exes = {}
 
@@ -335,6 +338,7 @@ def reference_check(engine, cfg, seed) -> dict:
             .lower(*dec_args).compile()
         dr = np.asarray(ref_exes["decode"](*dec_args), np.float32)
     finally:
+        chip.on_tpu = probe
         paddle.set_flags(old)
     engine._mgr.free(key)
     kernels_in_ref = [str(n) for n, exe in ref_exes.items()

@@ -197,47 +197,6 @@ def _paged_args(P, pp, dtype_name="bfloat16"):
             _sds((b, pp), jnp.int32))
 
 
-def _build_fused_paged():
-    from paddle_tpu.nn.functional.paged_attention import _fused_paged
-
-    q, kc, vc, lens, tables = _paged_args(P=64, pp=8)
-
-    def fn(q, kc, vc, lens, tables):
-        return _fused_paged(q, kc, vc, lens, tables)
-
-    return fn, (q, kc, vc, lens, tables)
-
-
-def _expected_fused_paged():
-    b, n_kv, d, ps = (_POOL[k] for k in ("b", "n_kv", "d", "ps"))
-    return (2 * _B((1, n_kv, d), "bfloat16")       # q block per sequence
-            + 2 * _B((1, n_kv, d), "float32")      # out block
-            + 2 * _B((2, n_kv, ps, d), "bfloat16"))  # k_buf + v_buf scratch
-
-
-def _build_stream_paged():
-    from paddle_tpu.nn.functional.paged_attention import _stream_paged
-
-    q, kc, vc, lens, tables = _paged_args(P=128, pp=8)
-
-    def fn(q, kc, vc, lens, tables):
-        return _stream_paged(q, kc, vc, lens, tables, pool_base=0,
-                             pool_pages=128)
-
-    return fn, (q, kc, vc, lens, tables)
-
-
-def _expected_stream_paged():
-    # cp = 64 pages -> C = 1024 tokens/chunk, nchunks = 2, bg = 8
-    b, n_kv, d, ps = (_POOL[k] for k in ("b", "n_kv", "d", "ps"))
-    return (_B((n_kv, b, d), "bfloat16")           # qt, resident
-            + 2 * _B((1, b, 1024), "int32")        # ownership mask chunk
-            + 2 * 2 * _B((64, n_kv, ps, d), "bfloat16")  # k+v chunk streams
-            + _B((n_kv, b, d), "float32")          # out
-            + 2 * _B((n_kv, b), "float32")         # m + l scratch
-            + _B((n_kv, b, d), "float32"))         # acc scratch
-
-
 def _build_decode_inplace():
     import jax.numpy as jnp
 
@@ -663,11 +622,6 @@ KERNEL_SITES: List[KernelSite] = [
     KernelSite("stream_linear.layer_tail",
                "nn/functional/stream_linear.py",
                _build_stream_layer_tail, _expected_stream_layer_tail),
-    KernelSite("paged_attention.fused", "nn/functional/paged_attention.py",
-               _build_fused_paged, _expected_fused_paged),
-    KernelSite("paged_attention.stream",
-               "nn/functional/paged_attention.py",
-               _build_stream_paged, _expected_stream_paged),
     KernelSite("paged_attention.decode_inplace",
                "nn/functional/paged_attention.py",
                _build_decode_inplace, _expected_decode_inplace),

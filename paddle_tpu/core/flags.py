@@ -84,12 +84,6 @@ define_flag("check_nan_inf_level", 0, "0: error on nan/inf; >0: report stats onl
 define_flag("record_double_grad", True,
             "record primal recipes on the tape for paddle.grad(create_graph=True); disable to save memory in first-order-only runs")
 define_flag("benchmark", False, "synchronize after each op for timing")
-define_flag("paged_attention_backend", "auto",
-            "decode paged-attention backend: auto (pool-streaming "
-            "Pallas kernel on TPU, XLA gather elsewhere — see "
-            "nn/functional/paged_attention.py) | stream | xla | fused "
-            "(r4 per-sequence page-DMA Pallas kernel, opt-in) | pallas "
-            "(stock jax kernel via a layout transpose)")
 define_flag("attn_varlen_backend", "auto",
             "flash_attn_unpadded varlen flash-attention backend "
             "(nn/functional/flash_varlen.py): auto (segment-aware "
@@ -106,31 +100,12 @@ define_flag("prefill_attention_backend", "auto",
             "walk elsewhere) | varlen (force the tiled walk family) | "
             "gather (legacy dense gather_kv_pages copy per chunk — "
             "also the forced path for int8-quantized pools)")
-define_flag("decode_linear", "auto",
-            "UNGROUPED decode matmul path (used when decode_grouped "
-            "is off): auto (stream for int8 weights, XLA dots over "
-            "loop-sliced stacked weights for bf16 — the r5 "
-            "measurement) | xla | stream (force the Pallas "
-            "weight-streaming kernel, nn/functional/stream_linear.py)")
-define_flag("decode_grouped", "auto",
-            "grouped decode weight streaming (fused O+LN2+FFN layer "
-            "tail + QKV, <=2 streamed matmul calls per layer — "
-            "nn/functional/stream_linear.py stream_layer_tail): auto "
-            "(grouped for bf16/f32/weight-only-int8 stacks; A8W8 "
-            "keeps the ungrouped int8 x int8 act-quant kernel) | on | "
-            "off")
 define_flag("moe_grouped_backend", "auto",
             "no-drop MoE ragged grouped-GEMM backend "
             "(nn/functional/grouped_gemm.py): auto (Pallas kernel on "
             "TPU, the math-identical tiled XLA walk elsewhere) | "
             "pallas | interpret (the kernel through the Pallas "
             "interpreter — debug/parity) | xla")
-define_flag("decode_prefetch", True,
-            "cross-layer prefetch inside the grouped decode tail: "
-            "layer l+1's LN1+QKV projection runs as the tail kernel's "
-            "final grid phase, overlapping its weight DMA with layer "
-            "l's FFN compute; off = a separate streamed QKV call per "
-            "layer (2 streamed calls/layer instead of 1)")
 define_flag("check_donation", False,
             "use-after-donate poison mode (paddle_tpu.analysis.donation): "
             "buffers donated by the compiled-forward fast path are "

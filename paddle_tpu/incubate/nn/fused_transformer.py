@@ -25,8 +25,7 @@ import jax.numpy as jnp
 from ...core.tensor import Tensor
 from ...nn.layer_base import Layer
 from ...nn.functional.paged_attention import (
-    paged_attention, paged_decode_attention_inplace, write_kv_pages,
-    write_prefill_kv_pages)
+    decode_attend, plan_decode_attention, write_prefill_kv_pages)
 
 __all__ = ["qkv_split_rope_fused", "rope_table", "FusedMultiTransformer"]
 
@@ -86,12 +85,12 @@ class PagedKV(NamedTuple):
     decode step updates the pool **in place** (XLA aliases loop-carry
     buffers; the scatter writes only the new token's rows). The round-3
     layout ([L, n_kv, pages, ...] shuttled through scan xs→ys) copied
-    the whole pool every token: measured 10.8ms/step of pure copy on
-    the 1.3B config vs 0.7ms for this carry design (tools/decode_profile
-    cache_copy vs carry_cache). Page-major ([P, n_kv, ps, d], heads
+    the whole pool every token: 10.8ms/step of pure copy on the 1.3B
+    config vs 0.7ms for this carry design (r4 chip record, before PR 1;
+    not measured on today's code). Page-major ([P, n_kv, ps, d], heads
     outer within the page — r5) makes each page one contiguous block
     whose per-head slices are contiguous too: the scatter's indexed page
-    dim leads and the stream decode kernel consumes whole [C, d] head
+    dim leads and the decode kernel consumes whole [C, d] head
     runs with zero relayout.
     """
     k: jax.Array   # [num_layers * num_pages, n_kv, page_size, head_dim]
@@ -393,13 +392,6 @@ class FusedMultiTransformer(Layer):
         return h, ck, cv
 
     @staticmethod
-    def _weights_dtype(weights):
-        """Matmul-stack dtype for either weight form (stacked dict or
-        list of per-layer dicts)."""
-        w = weights[0] if isinstance(weights, (list, tuple)) else weights
-        return w["qkv_weight"].dtype
-
-    @staticmethod
     def _pool_data(side):
         """Raw page array of a cache side (quantized sides are
         (int8_rows, f32_scale_plane) tuples)."""
@@ -407,9 +399,6 @@ class FusedMultiTransformer(Layer):
 
     def _pages_per_layer(self, cache: PagedKV) -> int:
         return self._pool_data(cache.k).shape[0] // self.num_layers
-
-    def _pool_page_size(self, cache: PagedKV) -> int:
-        return self._pool_data(cache.k).shape[2]
 
     # ---------- tensor parallelism (mp mesh axis) ----------
 
@@ -449,10 +438,6 @@ class FusedMultiTransformer(Layer):
             raise ValueError(
                 "tensor-parallel prefill needs a paged cache (the "
                 "dense training/eval path is single-chip)")
-        if isinstance(weights, (list, tuple)):
-            raise ValueError(
-                "tensor-parallel decode takes the stacked weight dict "
-                "(per-layer lists do not carry shard specs)")
         if isinstance(cache.k, tuple):
             raise NotImplementedError(
                 "int8 cache-KV is not supported under tensor "
@@ -518,7 +503,7 @@ class FusedMultiTransformer(Layer):
         kv-head-sharded). ``psum_axis`` is the internal per-shard form
         (set by the shard_map wrapper, not callers).
         """
-        if a8w8 and self._weights_dtype(weights) != jnp.int8:
+        if a8w8 and weights["qkv_weight"].dtype != jnp.int8:
             raise ValueError("a8w8 prefill needs an int8 weight stack "
                              "(quantize_weight_only_int8 first)")
         if tp is not None:
@@ -597,7 +582,7 @@ class FusedMultiTransformer(Layer):
         pool keeps the scatter (``write_prefill_kv_pages``): its attend
         is the dequantizing XLA gather, no Pallas call sits beside it.
         """
-        if a8w8 and self._weights_dtype(weights) != jnp.int8:
+        if a8w8 and weights["qkv_weight"].dtype != jnp.int8:
             raise ValueError("a8w8 prefill needs an int8 weight stack "
                              "(quantize_weight_only_int8 first)")
         if tp is not None:
@@ -708,17 +693,19 @@ class FusedMultiTransformer(Layer):
             0, self.num_layers, body, (x, cache.k, cache.v))
         return h, PagedKV(nk, nv)
 
-    def unstack_weights(self, weights=None):
-        """Per-layer weight dicts for the UNROLLED decode path
-        (experimental). Measured on the 1.3B b32 decode (r4): the
-        unrolled program was SLOWER end-to-end than the stacked
-        fori_loop (1859 vs 2583 tok/s) — XLA already schedules the
-        loop-indexed weight slices efficiently, and the 24-layer
-        unrolled body lost the while-loop's buffer reuse. Kept for
-        per-config experimentation via decode_raw's list form."""
-        weights = weights or self._stack()
-        return [{n: a[l] for n, a in weights.items()}
-                for l in range(self.num_layers)]
+    def decode_loop(self, a8w8=False, adapters=None) -> str:
+        """Which layer loop ``decode_raw`` runs, decided from the stack
+        and the call and nothing else: ``"adaptered"`` with LoRA banks;
+        ``"layerwise"`` for MoE stacks (the FFN is the routed expert
+        bank, not the fused dense tail) and for A8W8 (the grouped tail
+        streams weight-only math and would forgo the int8 x int8 MXU
+        dots); ``"grouped"`` for every other dense stack — bf16, f32,
+        weight-only int8, one chip or a tensor-parallel shard."""
+        if adapters is not None:
+            return "adaptered"
+        if self.moe_num_experts or a8w8:
+            return "layerwise"
+        return "grouped"
 
     def decode_raw(self, weights, x, cache: PagedKV, block_tables,
                    seq_lens, cos_t, sin_t, a8w8=False, tp=None,
@@ -728,22 +715,14 @@ class FusedMultiTransformer(Layer):
         tokens already cached (the new token's position). Returns
         (hidden [b, d], cache').
 
-        ``weights`` may be the stacked dict (fori_loop layer loop —
-        the DEFAULT and measured-fastest serving path) or a LIST of
-        per-layer dicts from ``unstack_weights`` (Python-unrolled —
-        experimental, measured slower end-to-end; see that method's
-        docstring). Either way the pool is carried through the loop and
-        only scatter-written/gather-read — never copied.
-
-        GROUPED streaming (``FLAGS_decode_grouped``, default auto):
-        the four per-layer matmuls issue as at most TWO streamed calls
-        — one QKV stream and one fused O+LN2+FFN tail
-        (``stream_layer_tail``) — and with ``FLAGS_decode_prefetch``
-        the tail's last grid phase computes layer l+1's LN1+QKV so its
-        weight DMA overlaps layer l's FFN compute: ONE fused streamed
-        call per layer in steady state. ``auto`` groups bf16/f32/
-        weight-only-int8 stacks; A8W8 keeps the ungrouped int8 x int8
-        act-quant kernel (grouped would forgo its int8 MXU math).
+        ``weights`` is the stacked dict: the layer loop is a
+        ``fori_loop`` whose streamed matmuls read layer l's block of the
+        UNSLICED ``[L, K, N]`` stacks through a prefetched index (a
+        dynamic-slice operand to a kernel's custom call would copy
+        ~100 MB a layer), and the pool is carried through the loop and
+        touched only by ``decode_attend`` — never copied. Which loop
+        runs is ``decode_loop``'s answer; the attention kernel is
+        ``plan_decode_attention``'s, made once here for all layers.
 
         ``a8w8``: activations dynamically quantized per token into the
         int8 x int8 streamed matmuls (stream_linear act_quant path) —
@@ -755,14 +734,16 @@ class FusedMultiTransformer(Layer):
         column→row projection pair meeting in exactly one ``psum``
         (two per layer: after the row-parallel O-proj and FFN2, the
         reference's fused_multi_transformer_op.cu:220,529 ring_id
-        allreduce points). The per-shard matmuls go through
-        ``stream_linear`` so every chip streams only its [K, N/mp] /
-        [K/mp, N] weight slice — TP decode keeps the per-chip
-        weight-bandwidth roofline; the fused grouped tail is split at
-        the psum boundaries (a collective cannot live inside one
-        Pallas grid). ``psum_axis`` is the internal per-shard form.
+        allreduce points). Every chip streams only its [K, N/mp] /
+        [K/mp, N] weight slice; the fused grouped tail is split at the
+        psum boundaries (a collective cannot live inside one Pallas
+        grid). ``psum_axis`` is the internal per-shard form.
         """
-        if a8w8 and self._weights_dtype(weights) != jnp.int8:
+        if not isinstance(weights, dict):
+            raise TypeError(
+                "decode_raw takes the layer-STACKED weight dict "
+                f"(_stack()), not {type(weights).__name__}")
+        if a8w8 and weights["qkv_weight"].dtype != jnp.int8:
             raise ValueError("a8w8 decode needs an int8 weight stack "
                              "(quantize_weight_only_int8 first)")
         if tp is not None:
@@ -770,428 +751,197 @@ class FusedMultiTransformer(Layer):
                                  block_tables, (seq_lens,), cos_t,
                                  sin_t, a8w8, adapters=adapters,
                                  overlap=overlap)
-        npages = self._pages_per_layer(cache)
-        lens1 = (seq_lens + 1).astype(jnp.int32)
-        # what the attention kernels mask with is layer-independent:
-        # compute it ONCE per decode step, share across the 24-layer loop
-        from ...core.flags import flag
-        from ...device import chip as _chip
-        from ...nn.functional.paged_attention import (
-            build_page_walk, build_pool_ownership,
-            paged_decode_attention_inplace_q)
+        # layer-independent: built ONCE a step, shared by the layer loop
+        plan = plan_decode_attention(cache.k, block_tables, seq_lens,
+                                     self._pages_per_layer(cache))
+        loop = self.decode_loop(a8w8, adapters)
+        if loop == "adaptered":
+            return self._loop_adaptered(
+                weights, x, cache, plan, cos_t, sin_t, adapters,
+                a8w8=a8w8, psum_axis=psum_axis, overlap=overlap)
+        if loop == "layerwise":
+            return self._loop_layerwise(
+                weights, x, cache, plan, cos_t, sin_t, a8w8=a8w8,
+                psum_axis=psum_axis, overlap=overlap, ep_axis=ep_axis,
+                ep_size=ep_size)
+        return self._loop_grouped(weights, x, cache, plan, cos_t, sin_t,
+                                  psum_axis=psum_axis, overlap=overlap)
 
-        quantized_kv = isinstance(cache.k, tuple)
-        fused_stream = False
-        ownership = walk = None
-        if quantized_kv:
-            # int8 cache-KV mode: always the fused quantized kernel
-            # (interpret off-TPU); the pools never touch a non-Pallas op
-            ownership = build_pool_ownership(
-                block_tables, seq_lens.astype(jnp.int32), npages,
-                self._pool_page_size(cache))
-        else:
-            backend = flag("paged_attention_backend")
-            fused_stream = (backend in ("auto", "stream")
-                            and _chip.on_tpu()
-                            and self.head_dim % 128 == 0)
-            if fused_stream:
-                # fused append+attend kernel: the list of the pages the
-                # tables name at seq_lens (the current token joins from
-                # the operands)
-                walk = build_page_walk(block_tables, seq_lens,
-                                       cache.k.shape[2])
-            else:
-                ownership = build_pool_ownership(
-                    block_tables, lens1, npages, cache.k.shape[2])
+    def _attend_qkv(self, plan, qkv, h, ck, cv, l, cos_t, sin_t):
+        """Layer l's attention over a computed QKV projection: head
+        split + rope, append + attend under the step's ``plan``.
+        Returns (att [b, n_q * hd] in h's dtype, ck', cv')."""
+        q, k, v = _split_rope(qkv.astype(h.dtype), plan.seq_lens,
+                              self.num_heads, self.num_kv_heads,
+                              self.head_dim, cos_t, sin_t)
+        att, ck, cv = decode_attend(plan, q, k, v, ck, cv, l)
+        att = att.reshape(*h.shape[:-1], self.num_heads * self.head_dim)
+        return att.astype(h.dtype), ck, cv
 
-        def attend_fn(q, k, v, ck, cv, tbl, base):
-            """One decode-attention step for the active backend:
-            returns (att, ck', cv') with the new token's K/V in the
-            pool — the shared core of the ungrouped _layer_body path
-            and the grouped carried-QKV loop."""
-            if quantized_kv:
-                att, kq2, ks2, vq2, vs2 = \
-                    paged_decode_attention_inplace_q(
-                        q, k, v, ck[0], ck[1], cv[0], cv[1],
-                        seq_lens, tbl, pool_base=base,
-                        pool_pages=npages, ownership=ownership)
-                return att, (kq2, ks2), (vq2, vs2)
-            if fused_stream:
-                return paged_decode_attention_inplace(
-                    q, k, v, ck, cv, seq_lens, tbl,
-                    pool_base=base, walk=walk)
-            ck, cv = write_kv_pages(ck, cv, k, v, seq_lens, tbl + base)
-            att = paged_attention(q, ck, cv, lens1, tbl,
-                                  pool_base=base, pool_pages=npages,
-                                  ownership=ownership)
-            return att, ck, cv
-
-        def run_layer(w, h, ck, cv, tbl, base, linear=None):
-            def attend(q, k, v, _ck, _cv):
-                return attend_fn(q, k, v, ck, cv, tbl, base)
-            return self._layer_body(w, h, seq_lens, None, attend,
-                                    cos_t, sin_t, linear=linear,
-                                    ep_axis=ep_axis, ep_size=ep_size)
-
+    def _loop_grouped(self, weights, x, cache, plan, cos_t, sin_t,
+                      psum_axis=None, overlap=None):
+        """GROUPED loop: QKV is carried through the ``fori_loop`` and
+        each layer issues ONE streamed call — ``stream_layer_tail``: the
+        fused O + LN2 + FFN tail whose last grid phase computes layer
+        l+1's LN1 + QKV, so that projection's weight DMA overlaps layer
+        l's FFN compute (the last layer's prefetched QKV is discarded).
+        Under TP the tail splits at its two reduce seams
+        (``reduce_axis``) and keeps the carried-QKV structure."""
         from ...nn.functional.stream_linear import (stream_layer_tail,
                                                     stream_linear)
 
-        is_moe = bool(self.moe_num_experts)
-        if is_moe and isinstance(weights, (list, tuple)):
-            raise NotImplementedError(
-                "MoE decode takes the stacked weight dict (the "
-                "unstacked experimental path has no expert bank form)")
-        g_flag = flag("decode_grouped")
-        use_grouped = (not is_moe) and (
-            g_flag == "on" or (g_flag == "auto" and not a8w8))
-        prefetch = bool(flag("decode_prefetch"))
-        d_att = self.num_heads * self.head_dim
+        L = self.num_layers
+        w = weights
 
-        def split_rope(qkv, h):
-            return _split_rope(qkv.astype(h.dtype), seq_lens,
-                               self.num_heads, self.num_kv_heads,
-                               self.head_dim, cos_t, sin_t)
+        def body(l, carry):
+            h, qkv, ck, cv = carry
+            att, ck, cv = self._attend_qkv(plan, qkv, h, ck, cv, l,
+                                           cos_t, sin_t)
+            h, qkv = stream_layer_tail(
+                att, h, w["out_weight"], w["ffn1_weight"],
+                w["ffn2_weight"], layer=l, bo=w["out_bias"],
+                b1=w["ffn1_bias"], b2=w["ffn2_bias"],
+                ln2_scale=w["ln2_scale"], ln2_bias=w["ln2_bias"],
+                epsilon=self.epsilon, activation=self.activation,
+                so=w.get("out_scale"), s1=w.get("ffn1_scale"),
+                s2=w.get("ffn2_scale"),
+                next_qkv=dict(w=w["qkv_weight"], b=w["qkv_bias"],
+                              s=w.get("qkv_scale"), ln_s=w["ln1_scale"],
+                              ln_b=w["ln1_bias"],
+                              layer=jnp.minimum(l + 1, L - 1)),
+                out_dtype=h.dtype, reduce_axis=psum_axis,
+                overlap=overlap)
+            return h, qkv, ck, cv
 
-        if adapters is not None:
-            # ADAPTERED decode: per-projection streamed base matmul
-            # plus ONE ragged grouped delta launch per target
-            # projection — tokens sorted by adapter slot once per step,
-            # membership riding the traced work map so the compiled
-            # program is independent of which adapters are loaded. The
-            # fused grouped tail is base-only (a delta join point
-            # cannot live inside its Pallas grid), so this branch runs
-            # the four-call per-layer form. Under TP the delta partial
-            # joins the base partial BEFORE the row-parallel psum
-            # (x·A = Σ_shards x_s·A_s with B replicated), keeping
-            # exactly two collectives per layer.
-            if is_moe:
-                raise NotImplementedError(
-                    "adaptered decode composes with the dense stack "
-                    "only (no MoE expert-bank form yet)")
-            if isinstance(weights, (list, tuple)):
-                raise ValueError(
-                    "adaptered decode takes the STACKED weight dict "
-                    "(banks are layer-stacked [L, S, ...] arrays)")
-            from ...nn.functional.lora import (
-                inverse_order, lora_delta, sort_by_adapter)
-            from ...nn.functional.stream_linear import _apply_activation
+        ln_s, ln_b = (jax.lax.dynamic_index_in_dim(w[n], 0, 0, False)
+                      for n in ("ln1_scale", "ln1_bias"))
+        hn = self._ln(x, ln_s, ln_b, self.epsilon).astype(x.dtype)
+        qkv0 = stream_linear(hn, w["qkv_weight"], layer=0,
+                             bias=w["qkv_bias"], scale=w.get("qkv_scale"),
+                             out_dtype=x.dtype)
+        h, _q, nk, nv = jax.lax.fori_loop(
+            0, L, body, (x, qkv0, cache.k, cache.v))
+        return h, PagedKV(nk, nv)
 
-            lora_backend = flag("lora_delta_backend")
-            S_ad = adapters["qkv_a"].shape[1]
-            order, offsets, _ = sort_by_adapter(
-                adapters["slots"].astype(jnp.int32), S_ad)
-            inv = inverse_order(order)
-            L = self.num_layers
+    def _loop_layerwise(self, weights, x, cache, plan, cos_t, sin_t,
+                        a8w8=False, psum_axis=None, overlap=None,
+                        ep_axis=None, ep_size=1):
+        """LAYERWISE loop: ``_layer_body`` a layer, four projections a
+        layer. MoE stacks take XLA dots over the loop-sliced weights
+        (and the expert bank's own kernels); A8W8 streams each
+        projection through the int8 x int8 act-quant kernel, the two
+        row-parallel ones reduced over ``psum_axis`` inside
+        ``stream_linear`` under TP. Also the reference the grouped loop
+        is tested against."""
+        from ...nn.functional.stream_linear import stream_linear
 
-            def small(name, l):
-                return jax.lax.dynamic_index_in_dim(
-                    weights[name], l, 0, False)
-
-            def delta(xx, kind, l):
-                a4 = adapters.get(f"{kind}_a")
-                if a4 is None:
-                    return None
-                a3 = jax.lax.dynamic_index_in_dim(a4, l, 0, False)
-                b3 = jax.lax.dynamic_index_in_dim(
-                    adapters[f"{kind}_b"], l, 0, False)
-                xs = jnp.take(xx, order, axis=0)
-                d = lora_delta(xs, a3, b3, offsets,
-                               backend=lora_backend)
-                return jnp.take(d, inv, axis=0)
-
-            def proj(xx, kind, l, reduce=False, activation=None):
-                # f32 partial with bias/activation deferred past the
-                # delta join (and past the psum for row-parallel kinds)
-                y = stream_linear(
-                    xx, weights[f"{kind}_weight"], layer=l,
-                    scale=weights.get(f"{kind}_scale"),
-                    act_quant=a8w8, out_dtype=jnp.float32)
-                d = delta(xx, kind, l)
-                if d is not None:
-                    y = y + d
-                if reduce and psum_axis is not None:
-                    from ...distributed.tp import reduce_over_axis
-                    y = reduce_over_axis(y, psum_axis,
-                                         overlap or "psum")
-                y = y + small(f"{kind}_bias", l).astype(jnp.float32)
-                if activation is not None:
-                    y = _apply_activation(y, activation)
-                return y
-
-            def body(l, carry):
-                h, ck, cv = carry
-                hn = self._ln(h, small("ln1_scale", l),
-                              small("ln1_bias", l),
-                              self.epsilon).astype(h.dtype)
-                qkv = proj(hn, "qkv", l)
-                q, k, v = split_rope(qkv, h)
-                att, ck, cv = attend_fn(q, k, v, ck, cv, block_tables,
-                                        l * npages)
-                att = att.reshape(*h.shape[:-1], d_att).astype(h.dtype)
-                h = (h + proj(att, "out", l, reduce=True)) \
-                    .astype(h.dtype)
-                hn = self._ln(h, small("ln2_scale", l),
-                              small("ln2_bias", l),
-                              self.epsilon).astype(h.dtype)
-                ff = proj(hn, "ffn1", l,
-                          activation=self.activation).astype(h.dtype)
-                h = (h + proj(ff, "ffn2", l, reduce=True)) \
-                    .astype(h.dtype)
-                return h, ck, cv
-
-            h, nk, nv = jax.lax.fori_loop(
-                0, L, body, (x, cache.k, cache.v))
-            return h, PagedKV(nk, nv)
-
-        if psum_axis is not None:
-            # tensor-parallel shard body: streamed per-shard matmuls
-            # (QKV / O / FFN1 / FFN2 slices), the two row-parallel ones
-            # reduced over mp INSIDE stream_linear (reduce_axis reduces
-            # the f32 partial before the replicated bias + activation —
-            # the collective stays fused with the projection instead of
-            # breaking the decode stream; ``overlap="ring"`` pipelines
-            # the reduce as chunked ppermute phases under the next
-            # chunk's GEMM). The fused grouped tail cannot span a
-            # collective, so grouped TP runs stream_layer_tail's split
-            # form (reduce_axis=) which breaks at the two reduce seams
-            # while keeping the carried-QKV prefetch structure.
-            L = self.num_layers
-
-            def small(name, l):
-                return jax.lax.dynamic_index_in_dim(
-                    weights[name], l, 0, False)
-
-            def lin(xx, kind, l, **kw):
-                return stream_linear(
-                    xx, weights[f"{kind}_weight"], layer=l,
-                    scale=weights.get(f"{kind}_scale"),
-                    act_quant=a8w8, out_dtype=xx.dtype, **kw)
-
-            def qkv_at(l, hh):
-                hn = self._ln(hh, small("ln1_scale", l),
-                              small("ln1_bias", l),
-                              self.epsilon).astype(hh.dtype)
-                return lin(hn, "qkv", l, bias=weights["qkv_bias"])
-
-            if use_grouped:
-                def tail(att, h, l):
-                    nq = None
-                    if prefetch:
-                        nq = dict(w=weights["qkv_weight"],
-                                  b=weights["qkv_bias"],
-                                  s=weights.get("qkv_scale"),
-                                  ln_s=weights["ln1_scale"],
-                                  ln_b=weights["ln1_bias"],
-                                  layer=jnp.minimum(l + 1, L - 1))
-                    return stream_layer_tail(
-                        att, h, weights["out_weight"],
-                        weights["ffn1_weight"], weights["ffn2_weight"],
-                        layer=l, bo=weights["out_bias"],
-                        b1=weights["ffn1_bias"],
-                        b2=weights["ffn2_bias"],
-                        ln2_scale=weights["ln2_scale"],
-                        ln2_bias=weights["ln2_bias"],
-                        epsilon=self.epsilon,
-                        activation=self.activation,
-                        so=weights.get("out_scale"),
-                        s1=weights.get("ffn1_scale"),
-                        s2=weights.get("ffn2_scale"),
-                        next_qkv=nq, out_dtype=h.dtype,
-                        reduce_axis=psum_axis, overlap=overlap)
-
-                def gbody(l, carry):
-                    h, qkv, ck, cv = carry
-                    q, k, v = split_rope(qkv, h)
-                    att, ck, cv = attend_fn(q, k, v, ck, cv,
-                                            block_tables, l * npages)
-                    att = att.reshape(*h.shape[:-1], d_att) \
-                        .astype(h.dtype)
-                    if prefetch:
-                        h, qkv = tail(att, h, l)
-                    else:
-                        h = tail(att, h, l)
-                        qkv = qkv_at(jnp.minimum(l + 1, L - 1), h)
-                    return h, qkv, ck, cv
-
-                qkv0 = qkv_at(0, x)
-                h, _q, nk, nv = jax.lax.fori_loop(
-                    0, L, gbody, (x, qkv0, cache.k, cache.v))
-                return h, PagedKV(nk, nv)
-
-            def body(l, carry):
-                h, ck, cv = carry
-                qkv = qkv_at(l, h)
-                q, k, v = split_rope(qkv, h)
-                att, ck, cv = attend_fn(q, k, v, ck, cv, block_tables,
-                                        l * npages)
-                att = att.reshape(*h.shape[:-1], d_att).astype(h.dtype)
-                h = (h + lin(att, "out", l, bias=weights["out_bias"],
-                             reduce_axis=psum_axis, overlap=overlap)) \
-                    .astype(h.dtype)
-                hn = self._ln(h, small("ln2_scale", l),
-                              small("ln2_bias", l),
-                              self.epsilon).astype(h.dtype)
-                ff = lin(hn, "ffn1", l, bias=weights["ffn1_bias"],
-                         activation=self.activation)
-                h = (h + lin(ff, "ffn2", l, bias=weights["ffn2_bias"],
-                             reduce_axis=psum_axis, overlap=overlap)) \
-                    .astype(h.dtype)
-                return h, ck, cv
-
-            h, nk, nv = jax.lax.fori_loop(
-                0, L, body, (x, cache.k, cache.v))
-            return h, PagedKV(nk, nv)
-
-        if use_grouped and isinstance(weights, (list, tuple)):
-            # unstacked grouped loop: per-layer dicts, python-unrolled
-            def qkv_call(wl, hh):
-                hn = self._ln(hh, wl["ln1_scale"], wl["ln1_bias"],
-                              self.epsilon).astype(hh.dtype)
-                return stream_linear(hn, wl["qkv_weight"],
-                                     bias=wl["qkv_bias"],
-                                     scale=wl.get("qkv_scale"),
-                                     out_dtype=hh.dtype)
-
-            h, ck, cv = x, cache.k, cache.v
-            qkv = qkv_call(weights[0], h)
-            for l, w in enumerate(weights):
-                q, k, v = split_rope(qkv, h)
-                att, ck, cv = attend_fn(q, k, v, ck, cv, block_tables,
-                                        l * npages)
-                att = att.reshape(*h.shape[:-1], d_att).astype(h.dtype)
-                nxt = weights[l + 1] \
-                    if (prefetch and l + 1 < len(weights)) else None
-                res = stream_layer_tail(
-                    att, h, w["out_weight"], w["ffn1_weight"],
-                    w["ffn2_weight"], bo=w["out_bias"],
-                    b1=w["ffn1_bias"], b2=w["ffn2_bias"],
-                    ln2_scale=w["ln2_scale"], ln2_bias=w["ln2_bias"],
-                    epsilon=self.epsilon, activation=self.activation,
-                    so=w.get("out_scale"), s1=w.get("ffn1_scale"),
-                    s2=w.get("ffn2_scale"),
-                    next_qkv=None if nxt is None else dict(
-                        w=nxt["qkv_weight"], b=nxt["qkv_bias"],
-                        s=nxt.get("qkv_scale"),
-                        ln_s=nxt["ln1_scale"], ln_b=nxt["ln1_bias"]),
-                    out_dtype=h.dtype)
-                if nxt is None:
-                    h = res
-                    if l + 1 < len(weights):
-                        qkv = qkv_call(weights[l + 1], h)
-                else:
-                    h, qkv = res
-            return h, PagedKV(ck, cv)
-
-        if use_grouped:
-            # stacked grouped loop: QKV carried through the fori_loop,
-            # layer l+1's projection computed by layer l's tail kernel
-            L = self.num_layers
-
-            def qkv_at(l, hh):
-                ln_s = jax.lax.dynamic_index_in_dim(
-                    weights["ln1_scale"], l, 0, False)
-                ln_b = jax.lax.dynamic_index_in_dim(
-                    weights["ln1_bias"], l, 0, False)
-                hn = self._ln(hh, ln_s, ln_b, self.epsilon) \
-                    .astype(hh.dtype)
-                return stream_linear(hn, weights["qkv_weight"],
-                                     layer=l, bias=weights["qkv_bias"],
-                                     scale=weights.get("qkv_scale"),
-                                     out_dtype=hh.dtype)
-
-            def tail(att, h, l):
-                nq = None
-                if prefetch:
-                    nq = dict(w=weights["qkv_weight"],
-                              b=weights["qkv_bias"],
-                              s=weights.get("qkv_scale"),
-                              ln_s=weights["ln1_scale"],
-                              ln_b=weights["ln1_bias"],
-                              layer=jnp.minimum(l + 1, L - 1))
-                return stream_layer_tail(
-                    att, h, weights["out_weight"],
-                    weights["ffn1_weight"], weights["ffn2_weight"],
-                    layer=l, bo=weights["out_bias"],
-                    b1=weights["ffn1_bias"], b2=weights["ffn2_bias"],
-                    ln2_scale=weights["ln2_scale"],
-                    ln2_bias=weights["ln2_bias"],
-                    epsilon=self.epsilon, activation=self.activation,
-                    so=weights.get("out_scale"),
-                    s1=weights.get("ffn1_scale"),
-                    s2=weights.get("ffn2_scale"),
-                    next_qkv=nq, out_dtype=h.dtype)
-
-            def body(l, carry):
-                h, qkv, ck, cv = carry
-                q, k, v = split_rope(qkv, h)
-                att, ck, cv = attend_fn(q, k, v, ck, cv, block_tables,
-                                        l * npages)
-                att = att.reshape(*h.shape[:-1], d_att).astype(h.dtype)
-                if prefetch:
-                    # steady state: ONE fused streamed call per layer
-                    # (the last layer's prefetched QKV is discarded)
-                    h, qkv = tail(att, h, l)
-                else:
-                    h = tail(att, h, l)
-                    qkv = qkv_at(jnp.minimum(l + 1, L - 1), h)
-                return h, qkv, ck, cv
-
-            qkv0 = qkv_at(0, x)
-            h, _q, nk, nv = jax.lax.fori_loop(
-                0, L, body, (x, qkv0, cache.k, cache.v))
-            return h, PagedKV(nk, nv)
-
-        if isinstance(weights, (list, tuple)):
-            h, ck, cv = x, cache.k, cache.v
-            for l, w in enumerate(weights):
-                linear = None
-                if a8w8:
-                    def linear(xx, kind, _w=w):
-                        return stream_linear(
-                            xx, _w[f"{kind}_weight"],
-                            bias=_w[f"{kind}_bias"],
-                            scale=_w[f"{kind}_scale"],
-                            act_quant=True, out_dtype=xx.dtype)
-                h, ck, cv = run_layer(w, h, ck, cv, block_tables,
-                                      l * npages, linear)
-            return h, PagedKV(ck, cv)
-
-        # matmul weights stay STACKED: the weight-streaming kernel reads
-        # layer l's block directly via a prefetched index, so the loop
-        # never materializes a per-layer [K, N] slice (a dynamic-slice
-        # operand to the kernel's custom call would copy ~100MB/layer)
-
-        # dtype-aware auto (r5 1.3B b32 end-to-end): bf16 weights run
-        # FASTER through XLA's sliced dots (2916 vs 2749 tok/s — the
-        # ~96 kernel dispatches/step eat the DMA gains), int8 weights
-        # run faster through the streaming kernel whose dequant fuses
-        # into the block DMA (3398 vs 3231). A8W8 always streams: the
-        # act-quant path's int8 x int8 dot lives in the same kernel
-        # (off-TPU it degrades to the identical-math XLA int32 dot).
-        lin_flag = flag("decode_linear")
-        is_int8 = weights["qkv_weight"].dtype == jnp.int8
-        use_stream_lin = (not is_moe) and (
-            a8w8 or (x.shape[0] % 8 == 0 and (
-                lin_flag == "stream"
-                or (lin_flag == "auto" and is_int8))))
-        small = {n: a for n, a in weights.items()
-                 if not n.startswith(("qkv_", "out_", "ffn1_", "ffn2_"))}
+        # A8W8 reads its four matmul stacks UNSLICED through the kernel
+        streamed = ("qkv_", "out_", "ffn1_", "ffn2_") if a8w8 else ()
+        sliced = {n: a for n, a in weights.items()
+                  if not n.startswith(streamed)}
 
         def body(l, carry):
             h, ck, cv = carry
             w = {n: jax.lax.dynamic_index_in_dim(a, l, 0, False)
-                 for n, a in (small if use_stream_lin else weights)
-                 .items()}
+                 for n, a in sliced.items()}
             linear = None
-            if use_stream_lin:
+            if a8w8:
                 def linear(xx, kind):
+                    row = kind in ("out", "ffn2")
                     return stream_linear(
                         xx, weights[f"{kind}_weight"], layer=l,
                         bias=weights[f"{kind}_bias"],
-                        scale=weights.get(f"{kind}_scale"),
-                        act_quant=a8w8, out_dtype=xx.dtype)
-            h, ck, cv = run_layer(w, h, ck, cv, block_tables,
-                                  l * npages, linear)
+                        scale=weights[f"{kind}_scale"], act_quant=True,
+                        out_dtype=xx.dtype,
+                        reduce_axis=psum_axis if row else None,
+                        overlap=overlap)
+
+            def attend(q, k, v, _ck, _cv):
+                return decode_attend(plan, q, k, v, ck, cv, l)
+
+            return self._layer_body(
+                w, h, plan.seq_lens, None, attend, cos_t, sin_t,
+                linear=linear, psum_axis=psum_axis, overlap=overlap,
+                ep_axis=ep_axis, ep_size=ep_size)
+
+        h, nk, nv = jax.lax.fori_loop(
+            0, self.num_layers, body, (x, cache.k, cache.v))
+        return h, PagedKV(nk, nv)
+
+    def _loop_adaptered(self, weights, x, cache, plan, cos_t, sin_t,
+                        adapters, a8w8=False, psum_axis=None,
+                        overlap=None):
+        """ADAPTERED loop: per-projection streamed base matmul plus ONE
+        ragged grouped delta launch per target projection — tokens
+        sorted by adapter slot once per step, membership riding the
+        traced work map so the compiled program is independent of which
+        adapters are loaded. The fused grouped tail is base-only (a
+        delta join point cannot live inside its Pallas grid), so this
+        loop runs the four-call per-layer form. Under TP the delta
+        partial joins the base partial BEFORE the row-parallel psum
+        (x·A = Σ_shards x_s·A_s with B replicated), keeping exactly two
+        collectives per layer."""
+        if self.moe_num_experts:
+            raise NotImplementedError(
+                "adaptered decode composes with the dense stack "
+                "only (no MoE expert-bank form yet)")
+        from ...core.flags import flag
+        from ...nn.functional.lora import (
+            inverse_order, lora_delta, sort_by_adapter)
+        from ...nn.functional.stream_linear import (_apply_activation,
+                                                    stream_linear)
+
+        lora_backend = flag("lora_delta_backend")
+        S_ad = adapters["qkv_a"].shape[1]
+        order, offsets, _ = sort_by_adapter(
+            adapters["slots"].astype(jnp.int32), S_ad)
+        inv = inverse_order(order)
+
+        def small(name, l):
+            return jax.lax.dynamic_index_in_dim(
+                weights[name], l, 0, False)
+
+        def delta(xx, kind, l):
+            a4 = adapters.get(f"{kind}_a")
+            if a4 is None:
+                return None
+            a3 = jax.lax.dynamic_index_in_dim(a4, l, 0, False)
+            b3 = jax.lax.dynamic_index_in_dim(
+                adapters[f"{kind}_b"], l, 0, False)
+            xs = jnp.take(xx, order, axis=0)
+            d = lora_delta(xs, a3, b3, offsets, backend=lora_backend)
+            return jnp.take(d, inv, axis=0)
+
+        def proj(xx, kind, l, reduce=False, activation=None):
+            # f32 partial with bias/activation deferred past the
+            # delta join (and past the psum for row-parallel kinds)
+            y = stream_linear(
+                xx, weights[f"{kind}_weight"], layer=l,
+                scale=weights.get(f"{kind}_scale"),
+                act_quant=a8w8, out_dtype=jnp.float32)
+            d = delta(xx, kind, l)
+            if d is not None:
+                y = y + d
+            if reduce and psum_axis is not None:
+                from ...distributed.tp import reduce_over_axis
+                y = reduce_over_axis(y, psum_axis, overlap or "psum")
+            y = y + small(f"{kind}_bias", l).astype(jnp.float32)
+            if activation is not None:
+                y = _apply_activation(y, activation)
+            return y
+
+        def body(l, carry):
+            h, ck, cv = carry
+            hn = self._ln(h, small("ln1_scale", l), small("ln1_bias", l),
+                          self.epsilon).astype(h.dtype)
+            att, ck, cv = self._attend_qkv(
+                plan, proj(hn, "qkv", l), h, ck, cv, l, cos_t, sin_t)
+            h = (h + proj(att, "out", l, reduce=True)).astype(h.dtype)
+            hn = self._ln(h, small("ln2_scale", l), small("ln2_bias", l),
+                          self.epsilon).astype(h.dtype)
+            ff = proj(hn, "ffn1", l,
+                      activation=self.activation).astype(h.dtype)
+            h = (h + proj(ff, "ffn2", l, reduce=True)).astype(h.dtype)
             return h, ck, cv
 
         h, nk, nv = jax.lax.fori_loop(

@@ -296,8 +296,7 @@ class HybridStack(Layer):
         state', counts int32 [4])``."""
         from ...device import chip as _chip
         from ...nn.functional.paged_attention import (
-            build_page_walk, build_pool_ownership, paged_attention,
-            paged_decode_attention_inplace, write_kv_pages)
+            decode_attend, plan_decode_attention)
         from ...nn.functional.ssm import (causal_conv1d_step,
                                           expand_heads, ssm_decode_update)
 
@@ -310,17 +309,9 @@ class HybridStack(Layer):
         ssm, conv = state if state is not None else (None, None)
         npages = ck.shape[0] // max(p.n_attention, 1) \
             if ck is not None else 0
-        ownership = walk = None
-        fused = False
-        if ck is not None:
-            # layer-independent: built once a step, shared by the layers
-            fused = _chip.on_tpu() and p.attention.head_dim % 128 == 0
-            if fused:
-                walk = build_page_walk(block_tables, seq_lens,
-                                       ck.shape[2])
-            else:
-                ownership = build_pool_ownership(
-                    block_tables, seq_lens + 1, npages, ck.shape[2])
+        # layer-independent: built once a step, shared by the layers
+        plan = plan_decode_attention(ck, block_tables, seq_lens, npages) \
+            if ck is not None else None
         counts = jnp.zeros((4,), jnp.int32)
         h = x
         for l, kind in enumerate(p.kinds()):
@@ -360,18 +351,7 @@ class HybridStack(Layer):
                 q = self._rope(q.astype(h.dtype), seq_lens, cos_t, sin_t)
                 k = self._rope(k.astype(h.dtype), seq_lens, cos_t, sin_t)
                 v = v.astype(h.dtype)
-                base = li * npages
-                if fused:
-                    o, ck, cv = paged_decode_attention_inplace(
-                        q, k, v, ck, cv, seq_lens, block_tables,
-                        pool_base=base, walk=walk)
-                else:
-                    ck, cv = write_kv_pages(ck, cv, k, v, seq_lens,
-                                            block_tables + base)
-                    o = paged_attention(q, ck, cv, seq_lens + 1,
-                                        block_tables, pool_base=base,
-                                        pool_pages=npages,
-                                        ownership=ownership)
+                o, ck, cv = decode_attend(plan, q, k, v, ck, cv, li)
                 o = o.reshape(S, nq * hd).astype(h.dtype)
                 h = self._residual(
                     h, self._proj(o, w["out_weight"], li, stream))

@@ -217,31 +217,18 @@ class GenerationEngine:
             _stats.set_gauge("dist.mp_degree", tp.mp)
             if tp.ep > 1:
                 _stats.set_gauge("dist.ep_degree", tp.ep)
-        # roofline rung names: A8W8 programs report under their own
-        # ``decode.a8w8``/``prefill.a8w8`` keys, and the grouped
-        # weight-stream path (FLAGS_decode_grouped, the r6 default for
-        # non-a8w8 stacks) under ``decode.<dtype>_grouped`` — so the
-        # serving modes' achieved-bandwidth rows never mix (bench.py
-        # picks these up; the flag is read once at engine init, matching
-        # when the decode programs trace)
-        from ..core.flags import flag as _flag
-
-        g = _flag("decode_grouped")
-        is_moe = bool(st.moe_num_experts)
-        self._grouped = (not is_moe) and (
-            g == "on" or (g == "auto" and not self._a8w8))
-        if is_moe:
-            # MoE stacks route the FFN through the ragged grouped-GEMM
-            # path (the fused dense tail doesn't apply) — own rung name
-            self._decode_tag = "decode.moe"
-        elif self._a8w8:
-            self._decode_tag = "decode.a8w8"
-        elif self._grouped:
+        # roofline rung names follow the layer loop the stack says it
+        # runs (``decode_loop``: the one place that decides), so the
+        # serving modes' achieved-bandwidth rows never mix: MoE and A8W8
+        # under their own keys, the grouped weight-stream loop under
+        # ``decode.<dtype>_grouped``
+        if st.decode_loop(a8w8=self._a8w8) == "grouped":
             wname = ("int8" if wd == jnp.int8 else
                      "bf16" if self._cdtype == jnp.bfloat16 else "f32")
             self._decode_tag = f"decode.{wname}_grouped"
         else:
-            self._decode_tag = "decode"
+            self._decode_tag = ("decode.moe" if st.moe_num_experts
+                                else "decode.a8w8")
         # one jitted prefill; decode programs are per-chunk-size (k=1
         # is the single-token step); cache operands are donated. Both
         # dispatch through the explicit-AOT wrapper so each program's
@@ -331,16 +318,10 @@ class GenerationEngine:
 
     def _logits(self, h, head_t, lnf_s, lnf_b):
         """LM head: final LN + pre-transposed [d, vocab] matmul with
-        fp32 accumulation (argmax/sampling happen on fp32 logits);
-        weight-streamed on TPU (stream_linear) like the stack matmuls."""
-        from ..core.flags import flag
-        from ..nn.functional.stream_linear import stream_linear
-
+        fp32 accumulation (argmax/sampling happen on fp32 logits)."""
         hl = FusedMultiTransformer._ln(
             h, lnf_s, lnf_b, self.model.stack.epsilon) \
             .astype(head_t.dtype)
-        if flag("decode_linear") == "stream" and hl.shape[0] % 8 == 0:
-            return stream_linear(hl, head_t, out_dtype=jnp.float32)
         return jax.lax.dot_general(
             hl, head_t, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)

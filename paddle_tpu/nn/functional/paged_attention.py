@@ -7,11 +7,15 @@ cache lives in fixed-size pages addressed through per-sequence block
 tables, so sequences grow without reallocation/copy and memory is shared
 across a continuous batch.
 
-On TPU the hot path is the Pallas paged-attention kernel
-(jax.experimental.pallas.ops.tpu.paged_attention — MXU-tiled online
-softmax reading pages straight from HBM); elsewhere an XLA gather +
-masked dense attention computes the same thing (fake-device test
-precedent, SURVEY §4).
+One decode step picks its attention ONCE, here, from what it can see
+(``plan_decode_attention``; every layer then calls ``decode_attend``):
+an int8 pool takes ``paged_decode_attention_inplace_q``; on the chip a
+bf16/f32 pool whose head_dim fills the lanes takes
+``paged_decode_attention_inplace`` (append + attend in one Pallas call
+over the pages the block tables name); everything else — off the chip,
+narrow heads — takes ``write_kv_pages`` + ``paged_attention``, an XLA
+gather + masked dense attention that computes the same thing and is the
+reference the kernels are tested against.
 
 Layouts (PAGE-MAJOR, head-major pages — r5 redesign):
   q            [batch, num_q_heads, head_dim]        one decode token/seq
@@ -28,11 +32,7 @@ leading dim. Heads-major WITHIN the page (r5, vs r4's [ps, n_kv, d]):
 the streaming decode kernel consumes one kv head at a time, and with
 heads outer each per-head slice of a page is a contiguous
 [page_size, d] block — the r4 token-major page made that a 256-byte
-strided gather that cost ~40% of kernel time (decode ablation r5). The
-stock jax paged_attention kernel wants [n_kv, P, ps, d] and imposes it
-on operands, which fought the scatter's preferred layout (two full-pool
-copies per layer per token); it remains available behind
-FLAGS_paged_attention_backend=pallas via an explicit transpose.
+strided gather that cost ~40% of kernel time (decode ablation r5).
 """
 from __future__ import annotations
 
@@ -46,7 +46,8 @@ import jax.numpy as jnp
 from ...device import chip as _chip
 from ...device.vmem import KERNEL_VMEM_LIMIT_BYTES
 
-__all__ = ["paged_attention", "write_kv_pages", "write_prefill_kv_pages",
+__all__ = ["paged_attention", "plan_decode_attention", "decode_attend",
+           "write_kv_pages", "write_prefill_kv_pages",
            "write_prefill_kv_inplace"]
 
 
@@ -61,34 +62,20 @@ def _enable_x64(flag: bool):
     return jax.enable_x64(flag)
 
 
-def _pallas_paged(q, key_cache, value_cache, seq_lens, block_tables):
-    """Stock jax kernel path: transpose the page-major pool to the
-    [n_kv, P, ps, d] layout it expects (a full-pool copy — opt-in
-    only; the fused kernel below is the fast path)."""
-    from jax.experimental.pallas.ops.tpu.paged_attention import (
-        paged_attention as kernel,
-    )
+def paged_attention(q, key_cache, value_cache, seq_lens, block_tables,
+                    pool_base=None):
+    """Single-token decode attention over a paged KV cache as an XLA
+    gather + masked dense attention: the reference every decode kernel
+    is checked against, and the path ``decode_attend`` takes off the
+    chip and for head sizes the kernels do not tile.
 
-    key_cache = jnp.transpose(key_cache, (1, 0, 2, 3))
-    value_cache = jnp.transpose(value_cache, (1, 0, 2, 3))
-    page_size = key_cache.shape[2]
-    pages_per_seq = block_tables.shape[1]
-    # one compute block ≥ 512 tokens of K keeps the MXU fed
-    ppcb = max(1, min(pages_per_seq, 512 // max(page_size, 1)))
-    while pages_per_seq % ppcb:
-        ppcb -= 1
-    # the kernel computes raw q·k logits — fold the 1/sqrt(d) scale into q
-    out_dtype = q.dtype
-    q = q.astype(jnp.float32) * (q.shape[-1] ** -0.5)
-    with _enable_x64(False), jax.default_matmul_precision("default"):
-        return kernel(
-            q, key_cache, value_cache,
-            seq_lens.astype(jnp.int32), block_tables.astype(jnp.int32),
-            pages_per_compute_block=ppcb,
-        ).astype(out_dtype)
-
-
-def _xla_paged(q, key_cache, value_cache, seq_lens, block_tables):
+    Raw-array functional op. ``seq_lens`` counts the tokens in the cache
+    INCLUDING the current one. ``pool_base``: first physical page of
+    this layer's region in a layer-folded pool (``block_tables`` then
+    hold LAYER-LOCAL page ids; it may be a traced loop index).
+    """
+    if pool_base is not None:
+        block_tables = block_tables + pool_base
     b, n_q, d = q.shape
     _, n_kv, page_size, _ = key_cache.shape
     pages_per_seq = block_tables.shape[1]
@@ -117,145 +104,6 @@ def _xla_paged(q, key_cache, value_cache, seq_lens, block_tables):
     return out.reshape(b, n_q, d).astype(q.dtype)
 
 
-def _fused_paged(q, key_cache, value_cache, seq_lens, block_tables):
-    """Fused Pallas decode attention over the page-major pool.
-
-    One grid program per sequence: pages stream HBM→VMEM through a
-    double-buffered async DMA (whole [ps, n_kv, d] blocks — the layout
-    is built for this), online-softmax accumulates per page. Unlike the
-    XLA gather path this never materializes the gathered K/V (saves a
-    full write+read of every attended byte), and unlike the stock jax
-    kernel it works WITH the scatter's natural layout instead of
-    forcing a transposed pool.
-    """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, n_q, d = q.shape
-    P, n_kv, ps, _ = key_cache.shape
-    pp = block_tables.shape[1]
-    group = n_q // n_kv
-    scale = d ** -0.5
-    NEG = -1e30  # python literal: jnp scalars would be captured consts
-
-    def kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
-               k_buf, v_buf, k_sem, v_sem):
-        i = pl.program_id(0)
-        qf = q_ref[0].astype(jnp.float32) \
-            * jnp.float32(scale)            # [n_q, d]
-        q3 = qf.reshape(n_kv, group, d)
-
-        def _idx(p):
-            # explicit lax arithmetic: weak-type promotion on the
-            # pallas scalar-ref index recurses in jnp operators
-            pi = jax.lax.convert_element_type(p, jnp.int32)
-            ii = jax.lax.convert_element_type(i, jnp.int32)
-            return jax.lax.add(jax.lax.mul(ii, jnp.int32(pp)), pi)
-
-        def start_dma(p, slot):
-            pid = tables_ref[_idx(p)]
-            pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[slot],
-                                  k_sem.at[slot]).start()
-            pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[slot],
-                                  v_sem.at[slot]).start()
-
-        def wait_dma(p, slot):
-            pid = tables_ref[_idx(p)]
-            pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[slot],
-                                  k_sem.at[slot]).wait()
-            pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[slot],
-                                  v_sem.at[slot]).wait()
-
-        start_dma(jnp.int32(0), jnp.int32(0))
-        m0 = jnp.full((n_kv, group, 1), NEG, jnp.float32)
-        l0 = jnp.zeros((n_kv, group, 1), jnp.float32)
-        a0 = jnp.zeros((n_kv, group, d), jnp.float32)
-
-        lens_i = lens_ref[i]
-
-        def body(p, carry):
-            m, l, acc = carry
-            slot = jax.lax.rem(p, jnp.int32(2))
-            nxt = jax.lax.add(p, jnp.int32(1))
-
-            @pl.when(nxt < jnp.int32(pp))
-            def _():
-                start_dma(nxt, jax.lax.rem(nxt, jnp.int32(2)))
-
-            wait_dma(p, slot)
-            # head-major pages: [n_kv, ps, d] already batch-dim-first
-            k = k_buf[slot].astype(jnp.float32)
-            v = v_buf[slot].astype(jnp.float32)
-            # [n_kv, group, ps] <- [n_kv, g, d] x [n_kv, ps, d]
-            logits = jax.lax.dot_general(
-                q3, k, (((2,), (2,)), ((0,), (0,))),
-                precision=jax.lax.Precision.DEFAULT,
-                preferred_element_type=jnp.float32)
-            base = jax.lax.mul(jax.lax.convert_element_type(p, jnp.int32),
-                               jnp.int32(ps))
-            pos = jax.lax.add(
-                jax.lax.broadcasted_iota(jnp.int32, (1, 1, ps), 2),
-                jax.lax.broadcast(base, (1, 1, ps)))
-            valid = jax.lax.lt(
-                pos, jax.lax.broadcast(
-                    jax.lax.convert_element_type(lens_i, jnp.int32),
-                    (1, 1, ps)))
-            logits = jnp.where(valid, logits,
-                               jnp.float32(NEG))
-            pm = jnp.maximum(m, logits.max(-1, keepdims=True))
-            alpha = jnp.exp(m - pm)
-            w = jnp.exp(logits - pm)                     # [n_kv, g, ps]
-            w = jnp.where(valid, w, jnp.float32(0.0))
-            l = l * alpha + w.sum(-1, keepdims=True)
-            # [n_kv, group, d]
-            pv = jax.lax.dot_general(
-                w, v, (((2,), (1,)), ((0,), (0,))),
-                precision=jax.lax.Precision.DEFAULT,
-                preferred_element_type=jnp.float32)
-            acc = acc * alpha + pv
-            return pm, l, acc
-
-        # int32 loop bounds: with x64 enabled (the package default) python
-        # bounds make the index int64, and Mosaic's int64->int32
-        # convert lowering recurses forever
-        m, l, acc = jax.lax.fori_loop(jnp.int32(0), jnp.int32(pp), body,
-                                      (m0, l0, a0))
-        out = acc / jnp.maximum(l, jnp.float32(1e-30))
-        # f32 out ref: in-kernel f32->bf16 (tpu.truncf) fails to
-        # legalize on this toolchain; the caller casts outside
-        o_ref[0] = out.reshape(n_q, d)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, n_q, d), lambda i, *_: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, n_q, d), lambda i, *_: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, n_kv, ps, d), key_cache.dtype),
-            pltpu.VMEM((2, n_kv, ps, d), value_cache.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ])
-    # x64 off for the whole kernel trace: the package enables x64
-    # globally, and weak-typed python scalars become f64/i64 inside the
-    # kernel, which Mosaic cannot legalize
-    with _enable_x64(False), jax.named_scope("pt_paged_attention_fused"):
-        out = pl.pallas_call(
-            kernel,
-            name="pt_paged_attention_fused",
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, n_q, d), jnp.float32),
-            compiler_params=pltpu.CompilerParams(
-                vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
-        )(block_tables.reshape(-1).astype(jnp.int32),
-          seq_lens.astype(jnp.int32), q, key_cache, value_cache)
-    return out.astype(q.dtype)
-
-
 def build_pool_ownership(block_tables, seq_lens, pool_pages, page_size):
     """Token-level inverse of the block tables: for each token slot of
     one layer's page pool, which batch row owns it and at what position.
@@ -266,7 +114,7 @@ def build_pool_ownership(block_tables, seq_lens, pool_pages, page_size):
     treated as unallocated padding (block tables are padded with page 0;
     the reserved scratch page must not inherit an owner). Layer-
     independent for the layer-folded pool — compute ONCE per decode
-    step and share across layers (the stream kernel's mask operands).
+    step and share across layers (the int8 kernel's mask operands).
     """
     b, pp = block_tables.shape
     ps = page_size
@@ -315,155 +163,6 @@ def _pick_chunk_pages(pool_pages: int, page_size: int) -> int:
         if pool_pages % cp == 0:
             return cp
     return 1
-
-
-def _stream_paged(q, key_cache, value_cache, seq_lens, block_tables,
-                  pool_base=None, pool_pages=None, ownership=None):
-    """Pool-STREAMING Pallas decode attention (the r5 winning design).
-
-    The r4 fused kernel gridded one SEQUENCE per program: 32 seqs x 17
-    pages of scalar-driven DMAs with tiny [1, d] x [ps, d] dots — it
-    serialized on the single TensorCore and lost to the XLA gather.
-    This kernel inverts the loop: the sequential grid walks the LAYER'S
-    WHOLE PAGE POOL in multi-page chunks (BlockSpec-driven, so Pallas
-    double-buffers the HBM stream automatically), and every chunk is
-    one batched MXU matmul for ALL sequences at once —
-    [n_kv, b*g, d] x [n_kv, C, d] -> [n_kv, b*g, C] logits, masked by
-    token ownership (which row owns each pool slot), online-softmax
-    accumulated in VMEM scratch across chunks. Each KV byte is read
-    exactly once, in perfectly sequential HBM order, with zero gather
-    materialization (the XLA path writes + re-reads a gathered copy of
-    every attended byte).
-
-    Design target: the reference's dedicated decode kernels
-    (paddle/phi/kernels/fusion/gpu/masked_multihead_attention_kernel.cu,
-    block_multi_head_attention_kernel.cu) — same job, TPU-shaped.
-
-    pool_base: first physical page of this layer's region in a layer-
-    folded pool (block_tables hold LAYER-LOCAL logical page ids).
-    ownership: optional precomputed (owner_tok, pos_tok) from
-    build_pool_ownership — pass it from the decode loop so the 24
-    layers share one computation.
-    """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, n_q, d = q.shape
-    _, n_kv, ps, _ = key_cache.shape
-    P = int(pool_pages) if pool_pages is not None else key_cache.shape[0]
-    g = n_q // n_kv
-    bg = b * g
-    scale = d ** -0.5
-    NEG = -1e30
-
-    cp = _pick_chunk_pages(P, ps)
-    C = cp * ps
-    nchunks = P // cp
-
-    if ownership is None:
-        ownership = build_pool_ownership(block_tables, seq_lens, P, ps)
-    owner_tok, pos_tok = ownership
-    # full [b, tokens] validity mask, computed in XLA (one fused
-    # compare, ~P*ps*b int32) and streamed per chunk as a [1, b, C]
-    # block — satisfies Mosaic tiling, and the kernel does zero mask
-    # arithmetic
-    rows = jnp.arange(b, dtype=jnp.int32)[:, None]
-    valid_full = ((owner_tok[None, :] == rows)
-                  & (pos_tok[None, :]
-                     < seq_lens.astype(jnp.int32)[:, None]))
-    mask3 = jnp.transpose(
-        valid_full.astype(jnp.int32).reshape(b, nchunks, C), (1, 0, 2))
-
-    # q -> [n_kv, b*g, d] in the kernel's batched-dot layout (transpose
-    # done once here in XLA, not per chunk in the kernel)
-    qt = jnp.transpose(q.reshape(b, n_kv, g, d), (1, 0, 2, 3)) \
-        .reshape(n_kv, bg, d).astype(key_cache.dtype)
-
-    # layer base in chunk units (pool_base = l * P and cp | P -> exact);
-    # pool_base may be a traced loop index
-    base_chunk = jnp.reshape(
-        jnp.asarray(0 if pool_base is None else pool_base, jnp.int32)
-        // jnp.int32(cp), (1,))
-
-    def kernel(base_ref, q_ref, mask_ref, k_ref, v_ref, o_ref,
-               m_ref, l_ref, acc_ref):
-        c = pl.program_id(0)
-
-        @pl.when(c == 0)
-        def _():
-            m_ref[...] = jnp.full((n_kv, bg), NEG, jnp.float32)
-            l_ref[...] = jnp.zeros((n_kv, bg), jnp.float32)
-            acc_ref[...] = jnp.zeros((n_kv, bg, d), jnp.float32)
-
-        valid = mask_ref[0] != 0                         # [b, C]
-        if g > 1:
-            valid = jnp.repeat(valid, g, axis=0)         # [bg, C]
-
-        # head loop (python-unrolled): with heads OUTER in the page
-        # layout, each slice is one contiguous [C, d] block — no
-        # relayout, no strided gather (both measured 40-60% of kernel
-        # time in the r5 decode ablation)
-        for h in range(n_kv):
-            k_h = k_ref[:, h].reshape(C, d)
-            v_h = v_ref[:, h].reshape(C, d)
-            logits = jax.lax.dot_general(
-                q_ref[h], k_h, (((1,), (1,)), ((), ())),
-                precision=jax.lax.Precision.DEFAULT,
-                preferred_element_type=jnp.float32) * jnp.float32(scale)
-            logits = jnp.where(valid, logits, jnp.float32(NEG))
-            m = m_ref[h]
-            pm = jnp.maximum(m, logits.max(-1))          # [bg]
-            alpha = jnp.exp(m - pm)
-            w = jnp.exp(logits - pm[:, None])            # [bg, C]
-            w = jnp.where(valid, w, jnp.float32(0.0))
-            l_ref[h] = l_ref[h] * alpha + w.sum(-1)
-            pv = jax.lax.dot_general(
-                w.astype(v_h.dtype), v_h, (((1,), (0,)), ((), ())),
-                precision=jax.lax.Precision.DEFAULT,
-                preferred_element_type=jnp.float32)      # [bg, d]
-            acc_ref[h] = acc_ref[h] * alpha[:, None] + pv
-            m_ref[h] = pm
-
-        @pl.when(c == nchunks - 1)
-        def _():
-            o_ref[...] = acc_ref[...] / jnp.maximum(
-                l_ref[...], jnp.float32(1e-30))[..., None]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nchunks,),
-        in_specs=[
-            pl.BlockSpec((n_kv, bg, d), lambda c, base: (0, 0, 0)),
-            pl.BlockSpec((1, b, C), lambda c, base: (c, 0, 0)),
-            pl.BlockSpec((cp, n_kv, ps, d),
-                         lambda c, base: (base[0] + c, 0, 0, 0)),
-            pl.BlockSpec((cp, n_kv, ps, d),
-                         lambda c, base: (base[0] + c, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((n_kv, bg, d), lambda c, base: (0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((n_kv, bg), jnp.float32),
-            pltpu.VMEM((n_kv, bg), jnp.float32),
-            pltpu.VMEM((n_kv, bg, d), jnp.float32),
-        ])
-    # x64 off for the whole trace (x64 is on globally; weak-typed
-    # python scalars would become f64/i64 inside the kernel); interpret
-    # mode off-TPU so the kernel's numerics are testable on CPU
-    with _enable_x64(False), jax.named_scope("pt_paged_attention_stream"):
-        out = pl.pallas_call(
-            kernel,
-            name="pt_paged_attention_stream",
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((n_kv, bg, d), jnp.float32),
-            # double-buffered multi-MB stream chunks overflow the
-            # conservative 16MB default scoped-VMEM budget; v5e has
-            # 128MB physical
-            compiler_params=pltpu.CompilerParams(
-                vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
-            interpret=not _chip.on_tpu(),
-        )(base_chunk, qt, mask3, key_cache, value_cache)
-    out = jnp.transpose(out.reshape(n_kv, b, g, d), (1, 0, 2, 3))
-    return out.reshape(b, n_q, d).astype(q.dtype)
 
 
 class PageWalk(NamedTuple):
@@ -843,61 +542,6 @@ def paged_decode_attention_inplace(q, new_k, new_v, key_cache,
           nv_t, nk_w, nv_w, slotmask, key_cache, value_cache)
     out = jnp.transpose(out.reshape(n_kv, b, g, d), (1, 0, 2, 3))
     return out.reshape(b, n_q, d).astype(q.dtype), ck, cv
-
-
-def paged_attention(q, key_cache, value_cache, seq_lens, block_tables,
-                    pool_base=None, pool_pages=None, ownership=None):
-    """Single-token decode attention over a paged KV cache.
-
-    Raw-array functional op (used inside compiled decode steps).
-    ``pool_base``/``pool_pages`` describe a layer-folded pool: the
-    block_tables hold LAYER-LOCAL page ids and the layer's region
-    starts at physical page ``pool_base`` (defaults: whole pool).
-
-    Backend selection (FLAGS_paged_attention_backend:
-    auto|stream|fused|xla|pallas): ``auto`` uses the pool-streaming
-    Pallas kernel on TPU when its layout constraints hold (head_dim a
-    lane multiple, layer region a whole number of stream chunks) and
-    the XLA gather+masked-attention path otherwise. The r4 measured
-    ranking (stock jax kernel forces a pool relayout the scatter hates;
-    the per-sequence fused kernel serializes) is documented on each
-    backend's function.
-    """
-    from ...core.flags import flag
-
-    backend = flag("paged_attention_backend")
-    if backend not in ("auto", "stream", "fused", "xla", "pallas"):
-        raise ValueError(
-            f"FLAGS_paged_attention_backend={backend!r}: valid values "
-            "are 'auto', 'stream', 'fused', 'xla', 'pallas'")
-    P = int(pool_pages) if pool_pages is not None else key_cache.shape[0]
-    base = 0 if pool_base is None else pool_base
-    if backend == "auto":
-        d = q.shape[-1]
-        backend = "stream" if (_chip.on_tpu() and d % 128 == 0
-                               and pool_base is not None) else "xla"
-    if backend == "stream":
-        if q.shape[-1] % 128 != 0:
-            raise ValueError(
-                "paged_attention backend 'stream' requires head_dim to "
-                f"be a multiple of 128 (lane width); got {q.shape[-1]}. "
-                "Use 'auto' to fall back automatically.")
-        return _stream_paged(q, key_cache, value_cache, seq_lens,
-                             block_tables, pool_base=pool_base,
-                             pool_pages=pool_pages, ownership=ownership)
-    abs_tables = block_tables + base if pool_base is not None \
-        else block_tables
-    if backend == "pallas":
-        return _pallas_paged(q, key_cache, value_cache, seq_lens,
-                             abs_tables)
-    if backend == "fused":
-        # r4 kernel: one sequence per grid program — numerically
-        # verified but serializes on the single TensorCore and loses to
-        # the XLA gather end-to-end (2019 vs 2531 tok/s, 1.3B b32);
-        # kept for comparison
-        return _fused_paged(q, key_cache, value_cache, seq_lens,
-                            abs_tables)
-    return _xla_paged(q, key_cache, value_cache, seq_lens, abs_tables)
 
 
 def write_kv_pages(key_cache, value_cache, new_k, new_v, positions,
@@ -1508,3 +1152,67 @@ def paged_decode_attention_inplace_q(q, new_k, new_v, kq_pool, ks_plane,
     return (out.reshape(b, n_q, d).astype(q.dtype),
             kq2.reshape(kq_pool.shape), ks2,
             vq2.reshape(vq_pool.shape), vs2)
+
+
+class DecodeAttention(NamedTuple):
+    """What the layers of one decode step share: which of the three
+    paths runs (``kind``, static) and the layer-independent operands it
+    masks with. Built by ``plan_decode_attention``."""
+    kind: str                  # "inplace_q" | "inplace" | "xla"
+    seq_lens: jax.Array        # [b] tokens cached, the current one excluded
+    block_tables: jax.Array    # [b, pp] LAYER-LOCAL page ids
+    pages_per_layer: int
+    walk: PageWalk | None      # "inplace": the pages the tables name
+    ownership: tuple | None    # "inplace_q": build_pool_ownership's pair
+
+
+def plan_decode_attention(key_cache, block_tables, seq_lens,
+                          pages_per_layer) -> DecodeAttention:
+    """Choose the decode attention of one step — THE place that does —
+    and build what its layers share. Call once a step, outside the
+    layer loop; hand the result to every layer's ``decode_attend``.
+
+    The choice follows from what can be observed: a quantized pool (the
+    ``(int8 rows, f32 scale plane)`` pair) takes the int8 kernel and its
+    whole-region ownership mask; on the chip a pool whose head_dim is a
+    lane multiple takes the fused append + attend kernel and the step's
+    page walk; anything else the XLA scatter + gather."""
+    if isinstance(key_cache, tuple):
+        ownership = build_pool_ownership(
+            block_tables, seq_lens.astype(jnp.int32), pages_per_layer,
+            key_cache[0].shape[2])
+        return DecodeAttention("inplace_q", seq_lens, block_tables,
+                               pages_per_layer, None, ownership)
+    _, _, page_size, head_dim = key_cache.shape
+    if _chip.on_tpu() and head_dim % 128 == 0:
+        walk = build_page_walk(block_tables, seq_lens, page_size)
+        return DecodeAttention("inplace", seq_lens, block_tables,
+                               pages_per_layer, walk, None)
+    return DecodeAttention("xla", seq_lens, block_tables, pages_per_layer,
+                           None, None)
+
+
+def decode_attend(plan: DecodeAttention, q, k, v, key_cache, value_cache,
+                  layer):
+    """Layer ``layer``'s decode attention under ``plan``: append the
+    current token's ``k``/``v`` ``[b, n_kv, d]`` to the layer's region of
+    the folded pool (``layer`` may be a traced loop index) and attend
+    ``q [b, n_q, d]`` over it, scaled by ``d ** -0.5``. Returns
+    ``(att [b, n_q, d], key_cache', value_cache')``."""
+    lens, tables = plan.seq_lens, plan.block_tables
+    base = layer * plan.pages_per_layer
+    if plan.kind == "inplace_q":
+        att, kq, ks, vq, vs = paged_decode_attention_inplace_q(
+            q, k, v, *key_cache, *value_cache, lens, tables,
+            pool_base=base, pool_pages=plan.pages_per_layer,
+            ownership=plan.ownership)
+        return att, (kq, ks), (vq, vs)
+    if plan.kind == "inplace":
+        return paged_decode_attention_inplace(
+            q, k, v, key_cache, value_cache, lens, tables,
+            pool_base=base, walk=plan.walk)
+    key_cache, value_cache = write_kv_pages(
+        key_cache, value_cache, k, v, lens, tables + base)
+    att = paged_attention(q, key_cache, value_cache, lens + 1, tables,
+                          pool_base=base)
+    return att, key_cache, value_cache

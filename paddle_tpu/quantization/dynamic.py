@@ -26,7 +26,7 @@ Grouped-decode interaction (r6): the GROUPED weight-stream path
 its GEMMs via in-kernel weight dequant (weight-only math) — the int8
 weight STREAM (the bound resource) is preserved while the act-quant
 int8 x int8 MXU form stays exclusive to the ungrouped kernel, which is
-why ``FLAGS_decode_grouped=auto`` keeps A8W8 ungrouped.
+why ``FusedMultiTransformer.decode_loop`` keeps A8W8 layerwise.
 """
 from __future__ import annotations
 

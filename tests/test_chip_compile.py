@@ -40,7 +40,6 @@ KERNELS = [
     "stream_layer_tail.bf16.next_qkv",
     "stream_layer_tail.int8",
     "stream_layer_tail.int8.next_qkv",
-    "paged_attention.stream",
     "paged_attention.decode_inplace",
     "paged_attention.decode_inplace_q",
     "flash_varlen.packed_fwd",
@@ -376,3 +375,49 @@ def test_decode_program_never_copies_the_pool(stack, one_chip, as_on_chip):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < side // 4, mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes >= 2 * side      # both sides donated
+
+
+def _avals(jaxpr):
+    """(shape, dtype) of every value a jaxpr computes, sub-jaxprs
+    (loops, calls) included."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield tuple(v.aval.shape), v.aval.dtype
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _avals(sub)
+
+
+@pytest.mark.parametrize("stack", ["uniform", "pattern"])
+def test_both_stacks_launch_the_same_decode_attention(stack, monkeypatch):
+    """Both stacks reach the decode attention through the one call
+    (``plan_decode_attention`` / ``decode_attend``): dry-traced as on
+    the chip at their cells' shapes each launches
+    ``pt_paged_attention_decode_inplace`` and none of its siblings, off
+    the chip none of them; and neither way is a mask over the layer's
+    whole region built (``build_pool_ownership``'s ``[pages * page]``
+    owner list: the XLA gather never read it, the in-place kernel walks
+    the tables). No topology, nothing compiles: a trace."""
+    from paddle_tpu.analysis.audit import record_pallas_calls
+    from paddle_tpu.analysis.sites import _force_tpu_routing
+    from paddle_tpu.device import chip
+
+    build = (_uniform_decode_program if stack == "uniform"
+             else _hybrid_decode_program)
+    region = build()[2].shape[0] // (L if stack == "uniform" else 1) * PAGE
+
+    def trace():
+        jitted, args, _ = build()       # a fresh function: no cached trace
+        with record_pallas_calls() as records:
+            closed = jax.make_jaxpr(jitted)(*args)
+        attn = [r.name for r in records
+                if r.name.startswith("pt_paged_attention")]
+        masks = [a for a in _avals(closed.jaxpr)
+                 if a == ((region,), jnp.int32)]
+        return attn, masks
+
+    with _force_tpu_routing():
+        attn, masks = trace()
+    assert set(attn) == {"pt_paged_attention_decode_inplace"} and not masks
+    monkeypatch.setattr(chip, "on_tpu", lambda: False)
+    attn, masks = trace()
+    assert not attn and not masks

@@ -486,8 +486,8 @@ class TestPreflightGate:
     def test_bench_and_profile_tools_wired(self):
         """The chip-time entry points all run the preflight gate and
         expose the --no-lint escape hatch."""
-        for rel in ("bench.py", "tools/decode_profile.py",
-                    "tools/bert_profile.py", "tools/train_profile.py"):
+        for rel in ("bench.py", "tools/bert_profile.py",
+                    "tools/train_profile.py"):
             src = open(os.path.join(REPO, rel), encoding="utf-8").read()
             assert "preflight(" in src, rel
             assert "--no-lint" in src or "no_lint" in src, rel

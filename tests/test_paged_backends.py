@@ -1,10 +1,7 @@
-"""Paged-attention backend dispatch + page-major layout invariants.
-
-The fused Pallas kernel itself is TPU-only (numerically verified on the
-chip against the XLA path across MHA/GQA/bench geometries — see the
-decode_ablations_r4 record in bench_profile.json); these tests cover
-what runs everywhere: the flag dispatch, layout contracts, and
-write-path round-trips on the page-major pool.
+"""Decode paged attention: the one choice (``plan_decode_attention`` /
+``decode_attend``), the XLA gather reference, the in-place kernels
+against it (Pallas interpret mode off the chip), page-major layout
+invariants and write-path round-trips.
 """
 import numpy as np
 import pytest
@@ -14,25 +11,11 @@ import jax.numpy as jnp
 
 import paddle_tpu as paddle
 from paddle_tpu.nn.functional.paged_attention import (
-    _xla_paged, paged_attention, write_kv_pages)
-
-
-def test_invalid_backend_flag_raises():
-    paddle.set_flags({"paged_attention_backend": "palas"})
-    try:
-        with pytest.raises(ValueError, match="valid values"):
-            paged_attention(jnp.zeros((1, 4, 8)),
-                            jnp.zeros((4, 4, 4, 8)),
-                            jnp.zeros((4, 4, 4, 8)),
-                            jnp.ones((1,), jnp.int32),
-                            jnp.zeros((1, 4), jnp.int32))
-    finally:
-        paddle.set_flags({"paged_attention_backend": "auto"})
+    decode_attend, paged_attention, plan_decode_attention, write_kv_pages)
 
 
 def test_auto_backend_off_tpu_is_xla():
-    # conftest pins CPU: auto must route to the XLA gather path and
-    # compute correctly
+    # the public name IS the XLA gather path: it must compute correctly
     rng = np.random.RandomState(0)
     b, n, d, ps, pp = 2, 4, 8, 4, 3
     q = jnp.asarray(rng.randn(b, n, d).astype(np.float32))
@@ -42,8 +25,7 @@ def test_auto_backend_off_tpu_is_xla():
     tables = jnp.asarray(
         np.arange(b * pp, dtype=np.int32).reshape(b, pp))
     out = paged_attention(q, kc, vc, lens, tables)
-    # independent dense reference (not _xla_paged — auto IS _xla_paged
-    # off-TPU, which would compare the function to itself)
+    # independent dense reference
     max_len = pp * ps
     k_full = np.zeros((b, max_len, n, d), np.float32)
     v_full = np.zeros((b, max_len, n, d), np.float32)
@@ -80,30 +62,6 @@ def test_page_major_scatter_roundtrip_dtype_cast():
     np.testing.assert_allclose(np.asarray(ck2[1], np.float32), 0.0)
 
 
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="fused kernel is TPU-only")
-def test_fused_kernel_matches_xla_on_tpu():
-    from paddle_tpu.nn.functional.paged_attention import _fused_paged
-
-    rng = np.random.RandomState(0)
-    b, n_q, n_kv, d, ps, pp = 4, 16, 8, 128, 16, 5
-    P = b * pp + 1
-    q = jnp.asarray(rng.randn(b, n_q, d).astype(np.float32)) \
-        .astype(jnp.bfloat16)
-    kc = jnp.asarray(rng.randn(P, n_kv, ps, d).astype(np.float32)) \
-        .astype(jnp.bfloat16)
-    vc = jnp.asarray(rng.randn(P, n_kv, ps, d).astype(np.float32)) \
-        .astype(jnp.bfloat16)
-    lens = jnp.asarray(rng.randint(1, pp * ps, (b,)).astype(np.int32))
-    tables = jnp.asarray(
-        (1 + np.arange(b * pp, dtype=np.int32)).reshape(b, pp))
-    out_f = np.asarray(_fused_paged(q, kc, vc, lens, tables)
-                       .astype(jnp.float32))
-    out_x = np.asarray(_xla_paged(q, kc, vc, lens, tables)
-                       .astype(jnp.float32))
-    np.testing.assert_allclose(out_f, out_x, atol=0.03)
-
-
 def _dense_paged_ref(q, kc, vc, lens, tables, ps):
     """NumPy dense reference over gathered pages."""
     b, n_q, d = q.shape
@@ -126,41 +84,117 @@ def _dense_paged_ref(q, kc, vc, lens, tables, ps):
     return np.einsum("bngl,blnd->bngd", w, v_full).reshape(b, n_q, d)
 
 
-@pytest.mark.parametrize("g", [1, 2])
-def test_stream_kernel_parity(g):
-    """Pool-streaming kernel vs dense reference: MHA + GQA, ragged
-    lens incl. a zero-length (idle slot) row, layer-folded base offset.
-    Runs in Pallas interpret mode off-TPU, compiled on the chip."""
-    from paddle_tpu.nn.functional.paged_attention import (
-        _stream_paged, build_pool_ownership)
-
-    rng = np.random.RandomState(1)
+def _ragged_case(rng, g, int8=False):
+    """MHA (g=1) / GQA rows of ragged length incl. an idle slot (length
+    0) over a two-layer folded pool; each row holds the pages its length
+    + 1 needs. Returns (q, new k, new v, k pool, v pool, lens, tables,
+    P, ps) as NumPy arrays."""
     b, n_kv, d, ps, pp = 4, 4, 128, 4, 6
-    n_q = n_kv * g
     P, L = 24, 2
-    q = jnp.asarray(rng.randn(b, n_q, d).astype(np.float32))
-    kpool = jnp.asarray(rng.randn(L * P, n_kv, ps, d).astype(np.float32))
-    vpool = jnp.asarray(rng.randn(L * P, n_kv, ps, d).astype(np.float32))
-    lens_np = np.array([5, 17, 0, 24], np.int32)
-    tables_np = np.zeros((b, pp), np.int32)
-    perm = rng.permutation(np.arange(1, P))
-    i = 0
-    for r in range(b):
-        n = -(-int(lens_np[r]) // ps)
-        tables_np[r, :n] = perm[i:i + n]
-        i += n
-    lens, tables = jnp.asarray(lens_np), jnp.asarray(tables_np)
-    own = build_pool_ownership(tables, lens, P, ps)
-    for base in (0, P):
-        out = np.asarray(_stream_paged(
-            q, kpool, vpool, lens, tables, pool_base=base,
-            pool_pages=P, ownership=own))
-        ref = _dense_paged_ref(q, kpool[base:base + P],
-                               vpool[base:base + P], lens_np, tables_np,
-                               ps)
-        # the zero-length row is defined as 0 output by the kernel
-        ref[lens_np == 0] = 0.0
-        np.testing.assert_allclose(out, ref, atol=3e-2)
+    q = rng.randn(b, n_kv * g, d).astype(np.float32)
+    nk = rng.randn(b, n_kv, d).astype(np.float32)
+    nv = rng.randn(b, n_kv, d).astype(np.float32)
+    kpool = rng.randn(L * P, n_kv, ps, d).astype(np.float32)
+    vpool = rng.randn(L * P, n_kv, ps, d).astype(np.float32)
+    lens = np.array([5, 17, 0, 23], np.int32)
+    return q, nk, nv, kpool, vpool, lens, \
+        _alloc_tables(rng, lens, pp, ps, P), P, ps
+
+
+@pytest.mark.parametrize("g", [1, 2])
+def test_reference_decode_attend_parity(g):
+    """The reference path of ``decode_attend`` (what the plan picks off
+    the chip: scatter + XLA gather) vs the dense NumPy reference: MHA +
+    GQA, ragged lens incl. a zero-length (idle slot) row — which attends
+    to its own token alone — and the layer-folded base offset, through
+    ``paged_attention(pool_base=)`` directly too."""
+    rng = np.random.RandomState(1)
+    q, nk, nv, kpool, vpool, lens_np, tables_np, P, ps = \
+        _ragged_case(rng, g)
+    j = jnp.asarray
+    plan = plan_decode_attention(j(kpool), j(tables_np), j(lens_np), P)
+    assert plan.kind == "xla" and plan.walk is None \
+        and plan.ownership is None
+    for layer, base in enumerate((0, P)):
+        out, ck, cv = decode_attend(plan, j(q), j(nk), j(nv), j(kpool),
+                                    j(vpool), layer)
+        ck_np, cv_np = kpool.copy(), vpool.copy()
+        for r, n in enumerate(lens_np):
+            pg = base + tables_np[r, n // ps]
+            ck_np[pg, :, n % ps] = nk[r]
+            cv_np[pg, :, n % ps] = nv[r]
+        np.testing.assert_array_equal(np.asarray(ck), ck_np)
+        np.testing.assert_array_equal(np.asarray(cv), cv_np)
+        ref = _dense_paged_ref(q, ck_np[base:base + P],
+                               cv_np[base:base + P], lens_np + 1,
+                               tables_np, ps)
+        np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5)
+        # the idle row saw its own token only
+        np.testing.assert_allclose(
+            np.asarray(out)[2], np.repeat(nv[2], g, axis=0), atol=2e-5)
+        direct = paged_attention(j(q), ck, cv, j(lens_np + 1),
+                                 j(tables_np), pool_base=base)
+        np.testing.assert_allclose(np.asarray(direct), ref, atol=2e-5)
+
+
+def _record_choice(pool_dtype, d, on_chip):
+    """Names of the Pallas calls one plan + attend records when
+    dry-traced (``jax.eval_shape``: no kernel runs), the probe answering
+    ``on_chip``; and the plan's kind."""
+    import contextlib
+
+    from paddle_tpu.analysis.audit import record_pallas_calls
+    from paddle_tpu.analysis.sites import _force_tpu_routing
+
+    b, n_kv, ps, pp, P = 8, 2, 16, 8, 128
+    sds = jax.ShapeDtypeStruct
+    bf = jnp.bfloat16
+    side = sds((2 * P, n_kv, ps, d), pool_dtype)
+    if pool_dtype == jnp.int8:
+        side = (side, sds((n_kv, 2 * P * ps), jnp.float32))
+    kinds = []
+
+    def fn(q, k, v, ck, cv, lens, tables):
+        plan = plan_decode_attention(ck, tables, lens, P)
+        kinds.append(plan.kind)
+        return decode_attend(plan, q, k, v, ck, cv, 1)
+
+    routed = _force_tpu_routing() if on_chip else contextlib.nullcontext()
+    with routed, record_pallas_calls() as records:
+        jax.eval_shape(fn, sds((b, n_kv, d), bf), sds((b, n_kv, d), bf),
+                       sds((b, n_kv, d), bf), side, side,
+                       sds((b,), jnp.int32), sds((b, pp), jnp.int32))
+    return [r.name for r in records], kinds[0]
+
+
+@pytest.mark.parametrize("case", ["bf16_head128", "bf16_head64",
+                                  "int8_pool", "off_chip"])
+def test_decode_attention_choice(case):
+    """The choice ``plan_decode_attention`` makes from what it observes
+    (pool form, the probe, head_dim), by the Pallas calls it records."""
+    if case == "bf16_head128":
+        assert _record_choice(jnp.bfloat16, 128, True) == (
+            ["pt_paged_attention_decode_inplace"], "inplace")
+    elif case == "bf16_head64":    # narrower than the lanes: no kernel
+        assert _record_choice(jnp.bfloat16, 64, True) == ([], "xla")
+    elif case == "int8_pool":      # the pair: its own kernel, anywhere
+        for on_chip in (True, False):
+            assert _record_choice(jnp.int8, 128, on_chip) == (
+                ["pt_paged_attention_decode_inplace_q"], "inplace_q")
+    else:
+        assert _record_choice(jnp.bfloat16, 128, False) == ([], "xla")
+        # and off the chip the same call computes the dense reference
+        rng = np.random.RandomState(2)
+        q, nk, nv, kpool, vpool, lens_np, tables_np, P, ps = \
+            _ragged_case(rng, 2)
+        j = jnp.asarray
+        plan = plan_decode_attention(j(kpool), j(tables_np), j(lens_np),
+                                     P)
+        out, ck, cv = decode_attend(plan, j(q), j(nk), j(nv), j(kpool),
+                                    j(vpool), 1)
+        ref = _dense_paged_ref(q, np.asarray(ck)[P:], np.asarray(cv)[P:],
+                               lens_np + 1, tables_np, ps)
+        np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5)
 
 
 def _alloc_tables(rng, lens_np, pp, ps, P):
@@ -232,7 +266,7 @@ def test_fused_inplace_kernel_parity(case, g):
     whose free pages hold NaN gives the same finite result. Interpret
     mode off-TPU, compiled on the chip."""
     from paddle_tpu.nn.functional.paged_attention import (
-        _xla_paged, paged_decode_attention_inplace, write_kv_pages)
+        paged_decode_attention_inplace)
 
     rng = np.random.RandomState(5)
     ps, pp, P, lens_np, tables_np, nan_free = _inplace_case(case, rng)
@@ -257,7 +291,7 @@ def test_fused_inplace_kernel_parity(case, g):
         ck_ref, cv_ref = write_kv_pages(
             kpool[base:base + P], vpool[base:base + P], nk, nv, lens,
             tables)
-        ref = _xla_paged(q, ck_ref, cv_ref, lens + 1, tables)
+        ref = paged_attention(q, ck_ref, cv_ref, lens + 1, tables)
         assert np.isfinite(np.asarray(out)).all()
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=3e-2)
@@ -384,8 +418,7 @@ def test_int8_kv_fused_kernel_parity():
     written int8 rows + scale-plane columns in place, and leave other
     layers' regions untouched."""
     from paddle_tpu.nn.functional.paged_attention import (
-        _xla_paged, paged_decode_attention_inplace_q, quantize_kv_rows,
-        write_kv_pages)
+        paged_decode_attention_inplace_q, quantize_kv_rows)
 
     rng = np.random.RandomState(7)
     b, n_kv, d, ps = 4, 2, 128, 4
@@ -428,7 +461,7 @@ def test_int8_kv_fused_kernel_parity():
             * s_v[base:base + P][..., None]
         ck_ref, cv_ref = write_kv_pages(
             jnp.asarray(kd), jnp.asarray(vd), nk, nv, lens, tables)
-        ref = _xla_paged(q, ck_ref, cv_ref, lens + 1, tables)
+        ref = paged_attention(q, ck_ref, cv_ref, lens + 1, tables)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=0.08)
         kq2n, ks2n = np.asarray(kq2), np.asarray(ks2)

@@ -12,10 +12,12 @@ Three contracts pinned on CPU:
    matmul calls per transformer layer (ONE in steady state with
    cross-layer prefetch): counted at trace level, since the fori_loop
    body traces once.
-3. ENGINE PARITY — GenerationEngine greedy tokens with
-   ``FLAGS_decode_grouped`` on vs off are identical for the fp32
-   stack, and decode_raw hidden states agree within quant tolerance
-   for int8 stacks.
+3. ENGINE PARITY — GenerationEngine greedy tokens (the grouped loop)
+   equal those of the same engine stepping the layerwise loop for the
+   fp32 stack, and the two loops' hidden states agree within quant
+   tolerance for int8 stacks. No flag picks between them: the tests
+   call ``FusedMultiTransformer._loop_grouped`` /
+   ``_loop_layerwise`` directly.
 """
 import numpy as np
 import pytest
@@ -28,18 +30,6 @@ from paddle_tpu.nn.functional.stream_linear import (stream_layer_tail,
                                                     stream_linear)
 
 EPS = 1e-5
-
-
-def _flags(**kw):
-    paddle.set_flags(kw)
-
-
-@pytest.fixture(autouse=True)
-def _restore_flags():
-    yield
-    paddle.set_flags({"decode_grouped": "auto",
-                      "decode_prefetch": True,
-                      "decode_linear": "auto"})
 
 
 def _mk(rng, L, Ka, d, dff, Nq, dtype=np.float32, int8=False):
@@ -272,12 +262,13 @@ class TestGroupedKernelParity:
                 ln2_bias=jnp.asarray(p["l2b"]), epsilon=EPS)
 
 
-def _tiny_stack(L=3, d=32, heads=4, dff=64):
+def _tiny_stack(L=3, d=32, heads=4, dff=64, moe=None):
     from paddle_tpu.incubate.nn.fused_transformer import (
         FusedMultiTransformer, PagedKV, rope_table)
 
     paddle.seed(11)
-    st = FusedMultiTransformer(d, heads, dff, L, max_position=64)
+    st = FusedMultiTransformer(d, heads, dff, L, max_position=64,
+                               moe_num_experts=moe)
     cos, sin = rope_table(64, st.head_dim)
     npages = 4
     cache = PagedKV(
@@ -289,125 +280,129 @@ def _tiny_stack(L=3, d=32, heads=4, dff=64):
     return st, cache, tables, lens, cos, sin
 
 
+def _count_streamed(decode):
+    """Python-level calls of the two streamed entry points while
+    ``decode()`` traces (the fori_loop body traces once, so these ARE
+    the per-layer counts plus the loop prologue)."""
+    import paddle_tpu.nn.functional.stream_linear as sl
+
+    calls = {"linear": 0, "tail": 0}
+    orig_lin, orig_tail = sl.stream_linear, sl.stream_layer_tail
+
+    def lin(*a, **k):
+        calls["linear"] += 1
+        return orig_lin(*a, **k)
+
+    def tail(*a, **k):
+        calls["tail"] += 1
+        return orig_tail(*a, **k)
+
+    sl.stream_linear, sl.stream_layer_tail = lin, tail
+    try:
+        out = decode()
+    finally:
+        sl.stream_linear, sl.stream_layer_tail = orig_lin, orig_tail
+    return calls, out
+
+
 class TestCallStructure:
-    """Contract 2: the decode loop's TRACE issues <=2 streamed weight
-    matmul calls per transformer layer (1 fused tail in steady state
-    with prefetch; +1 per-layer QKV stream with prefetch off). The
-    fori_loop body traces once, so python-level call counts ARE the
-    per-layer counts (plus the one loop-prologue QKV call)."""
-
-    def _count(self, prefetch, weights=None):
-        import paddle_tpu.nn.functional.stream_linear as sl
-
-        _flags(decode_grouped="on", decode_prefetch=prefetch)
-        st, cache, tables, lens, cos, sin = _tiny_stack()
-        calls = {"linear": 0, "tail": 0}
-        orig_lin, orig_tail = sl.stream_linear, sl.stream_layer_tail
-
-        def lin(*a, **k):
-            calls["linear"] += 1
-            return orig_lin(*a, **k)
-
-        def tail(*a, **k):
-            calls["tail"] += 1
-            return orig_tail(*a, **k)
-
-        sl.stream_linear, sl.stream_layer_tail = lin, tail
-        try:
-            w = weights(st) if weights else st._stack()
-            h, _ = st.decode_raw(w, jnp.ones((2, 32)), cache, tables,
-                                 lens, cos, sin)
-        finally:
-            sl.stream_linear, sl.stream_layer_tail = orig_lin, orig_tail
-        assert np.isfinite(np.asarray(h)).all()
-        return calls
+    """Contract 2: the grouped loop's TRACE issues ONE streamed weight
+    matmul call per transformer layer (the fused tail, which also
+    computes the next layer's QKV) plus the one loop-prologue QKV
+    call."""
 
     def test_prefetch_on_one_streamed_call_per_layer(self):
-        calls = self._count(True)
+        st, cache, tables, lens, cos, sin = _tiny_stack()
+        calls, (h, _) = _count_streamed(lambda: st.decode_raw(
+            st._stack(), jnp.ones((2, 32)), cache, tables, lens, cos,
+            sin))
+        assert np.isfinite(np.asarray(h)).all()
         # fori_loop body: 1 fused tail, 0 standalone QKV (carried);
         # prologue: 1 QKV stream outside the loop
-        assert calls["tail"] == 1
-        assert calls["linear"] == 1
+        assert calls == {"tail": 1, "linear": 1}
 
-    def test_prefetch_off_two_streamed_calls_per_layer(self):
-        calls = self._count(False)
-        assert calls["tail"] == 1
-        assert calls["linear"] == 2  # prologue + per-layer QKV
 
-    def test_unstacked_prefetch_on(self):
-        calls = self._count(
-            True, weights=lambda st: st.unstack_weights())
-        L = 3
-        # python-unrolled: 1 tail per layer + 1 prologue QKV
-        assert calls["tail"] == L
-        assert calls["linear"] == 1
+@pytest.mark.parametrize("case,loop,tails,linears", [
+    # grouped: the prologue's QKV stream + ONE fused tail a traced body
+    ("dense", "grouped", 1, 1),
+    ("int8_weights", "grouped", 1, 1),
+    # layerwise: four act-quant streams a layer / XLA dots + the bank
+    ("a8w8", "layerwise", 0, 4),
+    ("moe", "layerwise", 0, 0)])
+def test_decode_loop_choice(case, loop, tails, linears):
+    """Which loop ``decode_raw`` runs follows from the stack and the
+    call (``decode_loop``), by the streamed calls its trace issues; the
+    Python-unrolled list form went with the flags."""
+    st, cache, tables, lens, cos, sin = _tiny_stack(
+        moe=4 if case == "moe" else None)
+    if case in ("int8_weights", "a8w8"):
+        st.quantize_weight_only_int8()
+    kw = dict(a8w8=case == "a8w8")
+    assert st.decode_loop(**kw) == loop
+    x = jnp.asarray(np.random.RandomState(0).randn(2, 32), jnp.float32)
+    calls, (h, cache2) = _count_streamed(lambda: st.decode_raw(
+        st._stack(), x, cache, tables, lens, cos, sin, **kw))
+    assert calls == {"tail": tails, "linear": linears}
+    assert np.isfinite(np.asarray(h)).all()
+    # the step wrote each row's token into every layer's region
+    assert np.asarray(cache2.k).any(axis=(1, 2, 3)).sum() == 2 * 3
+    per_layer = [{n: a[l] for n, a in st._stack().items()}
+                 for l in range(3)]
+    with pytest.raises(TypeError, match="STACKED weight dict"):
+        st.decode_raw(per_layer, x, cache, tables, lens, cos, sin, **kw)
+
+
+def _two_loops(weights=None):
+    """(hidden, K pool) of one decode step through the grouped and the
+    layerwise loop of the same stack, same operands."""
+    from paddle_tpu.nn.functional.paged_attention import (
+        plan_decode_attention)
+
+    st, cache, tables, lens, cos, sin = _tiny_stack()
+    w = weights(st) if weights else st._stack()
+    plan = plan_decode_attention(cache.k, tables, lens,
+                                 st._pages_per_layer(cache))
+    x = jnp.ones((2, 32)) * 0.1
+    out = []
+    for loop in (st._loop_grouped, st._loop_layerwise):
+        h, cache2 = loop(w, x, cache, plan, cos, sin)
+        out.append((np.asarray(h), np.asarray(cache2.k)))
+    return out
 
 
 class TestDecodeParity:
-    """Contract 3: grouped vs ungrouped decode agree."""
+    """Contract 3: the grouped and the layerwise loop agree."""
 
-    def _decode(self, grouped, weights=None, prefetch=True):
-        _flags(decode_grouped=grouped, decode_prefetch=prefetch)
-        st, cache, tables, lens, cos, sin = _tiny_stack()
-        w = weights(st) if weights else st._stack()
-        h, cache2 = st.decode_raw(w, jnp.ones((2, 32)) * 0.1, cache,
-                                  tables, lens, cos, sin)
-        return np.asarray(h), np.asarray(cache2.k)
-
-    @pytest.mark.parametrize("prefetch", [True, False])
-    def test_stacked_grouped_matches_ungrouped_f32(self, prefetch):
-        h0, k0 = self._decode("off")
-        h1, k1 = self._decode("on", prefetch=prefetch)
+    def test_stacked_grouped_matches_ungrouped_f32(self):
+        (h1, k1), (h0, k0) = _two_loops()
         np.testing.assert_allclose(h1, h0, rtol=1e-5, atol=1e-6)
         np.testing.assert_allclose(k1, k0, rtol=1e-5, atol=1e-6)
-
-    def test_unstacked_grouped_matches_ungrouped(self):
-        h0, _ = self._decode("off")
-        h1, _ = self._decode("on",
-                             weights=lambda st: st.unstack_weights())
-        np.testing.assert_allclose(h1, h0, rtol=1e-5, atol=1e-6)
 
     def test_int8_grouped_matches_ungrouped_stream(self):
         def quant(st):
             st.quantize_weight_only_int8()
             return st._stack()
 
-        h0, _ = self._decode("off", weights=quant)
-        h1, _ = self._decode("on", weights=quant)
+        (h1, _), (h0, _) = _two_loops(quant)
         np.testing.assert_allclose(h1, h0, rtol=2e-3, atol=2e-3)
 
-    def test_a8w8_auto_stays_ungrouped_but_on_forces_grouped(self):
-        import paddle_tpu.nn.functional.stream_linear as sl
-
+    def test_a8w8_stays_layerwise_and_launches_no_tail(self):
         st, cache, tables, lens, cos, sin = _tiny_stack()
         st.quantize_weight_only_int8()
-        w = st._stack()
-        calls = {"tail": 0}
-        orig = sl.stream_layer_tail
-
-        def tail(*a, **k):
-            calls["tail"] += 1
-            return orig(*a, **k)
-
-        sl.stream_layer_tail = tail
-        try:
-            _flags(decode_grouped="auto")
-            st.decode_raw(w, jnp.ones((2, 32)), cache, tables, lens,
-                          cos, sin, a8w8=True)
-            assert calls["tail"] == 0  # auto: a8w8 keeps act-quant path
-            _flags(decode_grouped="on")
-            h, _ = st.decode_raw(w, jnp.ones((2, 32)), cache, tables,
-                                 lens, cos, sin, a8w8=True)
-            assert calls["tail"] == 1  # forced grouped accepts a8w8
-            assert np.isfinite(np.asarray(h)).all()
-        finally:
-            sl.stream_layer_tail = orig
+        assert st.decode_loop(a8w8=True) == "layerwise"
+        calls, (h, _) = _count_streamed(lambda: st.decode_raw(
+            st._stack(), jnp.ones((2, 32)), cache, tables, lens, cos,
+            sin, a8w8=True))
+        # A8W8 keeps the act-quant kernel: four streamed projections a
+        # layer, no fused tail
+        assert calls == {"tail": 0, "linear": 4}
+        assert np.isfinite(np.asarray(h)).all()
 
 
 class TestEngineParity:
-    """Engine-level greedy-token parity grouped vs ungrouped (fp32 on
-    CPU — the grouped fallback mirrors the ungrouped math op-for-op,
-    so the token sequences must be identical)."""
+    """Engine-level greedy-token parity of the two loops (fp32 on CPU —
+    the grouped fallback mirrors the layerwise math op-for-op, so the
+    token sequences must be identical)."""
 
     def _gen(self):
         from paddle_tpu.inference import FusedCausalLM
@@ -417,28 +412,34 @@ class TestEngineParity:
                              dim_feedforward=64, num_layers=2,
                              max_position=128)
 
-    def test_generate_tokens_identical(self):
+    def test_generate_tokens_identical(self, monkeypatch):
+        from paddle_tpu.incubate.nn.fused_transformer import (
+            FusedMultiTransformer)
         from paddle_tpu.inference import GenerationEngine
 
         rng = np.random.RandomState(3)
         ids = rng.randint(0, 64, (2, 6))
-        outs = {}
-        for mode in ("off", "on"):
-            _flags(decode_grouped=mode)
-            model = self._gen()
-            eng = GenerationEngine(model, page_size=4, max_length=64)
-            outs[mode] = eng.generate(ids, max_new_tokens=8)
-        np.testing.assert_array_equal(outs["on"], outs["off"])
+
+        def gen():
+            eng = GenerationEngine(self._gen(), page_size=4,
+                                   max_length=64)
+            return eng.generate(ids, max_new_tokens=8)
+
+        grouped = gen()
+        # the same engine with its stack stepping the layerwise loop
+        monkeypatch.setattr(
+            FusedMultiTransformer, "_loop_grouped",
+            lambda self, *a, psum_axis=None, overlap=None:
+            self._loop_layerwise(*a))
+        np.testing.assert_array_equal(gen(), grouped)
 
     def test_grouped_engine_reports_grouped_rung(self):
         from paddle_tpu.inference import GenerationEngine
 
-        _flags(decode_grouped="on")
         eng = GenerationEngine(self._gen(), page_size=4, max_length=64)
+        assert eng.model.stack.decode_loop() == "grouped"
         assert eng._decode_tag == "decode.f32_grouped"
-        _flags(decode_grouped="off")
-        eng = GenerationEngine(self._gen(), page_size=4, max_length=64)
-        assert eng._decode_tag == "decode"
+        assert eng._decode_rung(8) == "decode.f32_grouped[k=8]"
 
 
 class TestBenchGateRungs:
@@ -457,11 +458,3 @@ class TestBenchGateRungs:
         assert compared >= 2 and len(bad) == 2
         bad, _ = bg.gate(prev, dict(prev))
         assert not bad
-
-    def test_decode_profile_has_grouped_ablation_rows(self):
-        import tools.decode_profile as dp
-
-        for row in ("weights_only_grouped", "prefetch_on",
-                    "prefetch_off", "engine_grouped_b32",
-                    "engine_ungrouped_b32"):
-            assert row in dp.MODES

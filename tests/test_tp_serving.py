@@ -376,11 +376,6 @@ class TestToolsTP:
         bad, n = bg.gate(prev, {"decode_tp2_tokens_per_sec": 4000.0})
         assert n >= 1 and bad
 
-    def test_decode_profile_has_mp2_row(self):
-        import tools.decode_profile as dp
-
-        assert "engine_grouped_mp2_b32" in dp.MODES
-
     def test_serve_bench_has_mp_flag(self):
         import os
 
