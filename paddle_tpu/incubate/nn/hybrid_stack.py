@@ -26,8 +26,9 @@ The two raw phases mirror ``FusedMultiTransformer``'s:
     as they were.
 
 Both return pick counts of the expert layers (all picks, picks on held
-experts, held experts hit, held experts offered) so the engine can
-publish them with the tokens it fetches anyway.
+experts, held experts hit, held experts offered; a prefill chunk also
+the work units its grouped GEMMs walked and those that owned a row) so
+the engine can publish them with the tokens it fetches anyway.
 """
 from __future__ import annotations
 
@@ -164,7 +165,8 @@ class HybridStack(Layer):
 
     def _ffn(self, w, h, l, rows, decode, counts):
         """``h + r * (MoE(x) + SharedMLP(x))`` over flat rows ``[T, d]``
-        and the layer's pick counts added to ``counts``."""
+        and the layer's pick counts (prefill rows: and the grouped
+        GEMMs' unit counts) added to ``counts``."""
         from ...nn.functional.moe_gated import (
             gated_mlp, moe_gated_grouped, moe_gated_stream, pick_counts,
             route_topk_softmax)
@@ -173,17 +175,19 @@ class HybridStack(Layer):
         moe = p.moe
         x = self._rms(h, w["f_norm"][l], p.epsilon).astype(h.dtype)
         gates, idx = route_topk_softmax(x, w["f_router"][l], moe.top_k)
+        units = []              # the streamed experts have no schedule
         if decode:
             y = moe_gated_stream(x, gates, idx, w["e_w1"], w["e_w2"], l,
                                  moe.held)
         else:
-            y = moe_gated_grouped(x, gates, idx, w["e_w1"], w["e_w2"], l,
-                                  moe.held)
+            y, walked_live = moe_gated_grouped(
+                x, gates, idx, w["e_w1"], w["e_w2"], l, moe.held)
+            units = [walked_live]
         if moe.shared_dim:
             y = y + gated_mlp(x, w["s_w1"][l], w["s_w2"][l])
         c = pick_counts(idx, rows, moe.held)
         counts = counts + jnp.concatenate(
-            [c, jnp.full((1,), moe.held[1], jnp.int32)])
+            [c, jnp.full((1,), moe.held[1], jnp.int32)] + units)
         return self._residual(h, y), counts
 
     def _mamba_split(self, zxbcdt):
@@ -209,7 +213,7 @@ class HybridStack(Layer):
         attention layers; ``state`` this sequence's recurrent arrays
         (``ssm [Lm, N, d_inner]`` float32, ``conv [Lm, k-1, conv_dim]``)
         as they stood before the chunk. Returns ``(hidden [1, c, d],
-        cache', state', counts int32 [4])``."""
+        cache', state', counts int32 [6])``."""
         from ...nn.functional.flash_varlen import paged_prefill_attention
         from ...nn.functional.paged_attention import (
             write_prefill_kv_inplace)
@@ -230,7 +234,7 @@ class HybridStack(Layer):
         ssm, conv = state if state is not None else (None, None)
         npages = ck.shape[0] // max(p.n_attention, 1) \
             if ck is not None else 0
-        counts = jnp.zeros((4,), jnp.int32)
+        counts = jnp.zeros((6,), jnp.int32)
         h = x[0]
         for l, kind in enumerate(p.kinds()):
             li = p.kind_index(l)
@@ -279,8 +283,8 @@ class HybridStack(Layer):
             h, counts = self._ffn(w, h, l, valid, False, counts)
         # held experts hit / offered are a reading of the DECODE steps
         # (is the expert stream's time free of the data?): a prefill
-        # chunk reports its picks alone
-        counts = counts * jnp.asarray([1, 1, 0, 0], jnp.int32)
+        # chunk reports its picks and its grouped GEMMs' units alone
+        counts = counts * jnp.asarray([1, 1, 0, 0, 1, 1], jnp.int32)
         cache2 = PagedKV(ck, cv) if ck is not None else None
         state2 = (ssm, conv) if ssm is not None else None
         return h[None], cache2, state2, counts

@@ -815,14 +815,15 @@ class ContinuousBatchingEngine:
 
     def _fetch(self, out):
         """A program's first result on the host. A pattern-built model
-        returns its expert layers' pick counts beside it: both come in
-        the one fetch and the counts go to the ``serving.moe.*``
-        counters."""
+        returns its expert layers' pick counts beside it (a prefill
+        chunk also its grouped GEMMs' work units): both come in the one
+        fetch and the counts go to the ``serving.moe.*`` counters."""
         if self._rs is None:
             return np.asarray(out)
         first, counts = jax.device_get(out)
         for name, n in zip(("picks", "picks_here", "experts_hit",
-                            "experts_held"), counts):
+                            "experts_held", "units_walked", "units_live"),
+                           counts):
             _stats.inc("serving.moe." + name, int(n))
         return first
 

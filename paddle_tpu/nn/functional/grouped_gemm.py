@@ -21,12 +21,15 @@ into the GEMM):
   varlen flash kernel's ``varlen_block_map`` (PR 13). A work unit is
   one (expert, row-tile) visit; row tiles shared by two experts get one
   unit per expert, tiles past the last real row get a phantom unit that
-  zero-fills them, so the grid visits ONLY tiles with live rows plus
-  the O(E) boundary/pad units.
+  zero-fills them. The schedule's SHAPE is static (``t_pad/bm + 2E +
+  1`` units whatever the offsets are), so the grid visits every unit;
+  the ones that own no row (``hi <= lo``: phantom tiles, empty experts,
+  the trailing inactive units) cost a grid step, not a matmul.
 - :func:`grouped_gemm` — the Pallas kernel: grid ``(nb, nwu)`` with the
   unit axis fastest, per-expert ``[K, bn]`` weight blocks streamed
-  double-buffered through their BlockSpec (the same per-dtype block
-  geometry as ``stream_linear``), bias add + activation fused on the
+  double-buffered through their BlockSpec (the whole matrix as one
+  block while two fit VMEM, else the per-dtype block geometry of
+  ``stream_linear``: ``_geometry``), bias add + activation fused on the
   fp32 accumulator in-kernel, and the output tile accumulated across
   the consecutive units that share it (expert-boundary tiles).
 - ``custom_vjp`` backward: dx walks the forward map with the per-expert
@@ -172,40 +175,51 @@ def _grouped_fwd_pallas(x_pad, w3, b3, gids, tids, lo, hi, bm, bn,
     nb = N // bn
     nwu = gids.shape[0]
 
-    def kernel(gids_r, tids_r, lo_r, hi_r, x_ref, w_ref, b_ref, o_ref):
+    def kernel(gids_r, tids_r, lo_r, hi_r, xt_r, x_ref, w_ref, b_ref,
+               o_ref):
         u = pl.program_id(1)
-        rows = tids_r[u] * bm \
-            + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
-        acc = jax.lax.dot_general(
-            x_ref[...], w_ref[0].astype(x_ref.dtype),
-            (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.DEFAULT,
-            preferred_element_type=jnp.float32)            # [bm, bn]
-        acc = acc + b_ref[0].astype(jnp.float32)
-        acc = _apply_activation(acc, activation)
-        mask = jnp.logical_and(rows >= lo_r[u], rows < hi_r[u])
-        contrib = jnp.where(mask, acc, jnp.float32(0.0))
         first = jnp.logical_or(
             u == 0, tids_r[jnp.maximum(u - 1, 0)] != tids_r[u])
 
+        # every tile's first visit zero-fills it, live or not: rows past
+        # offsets[E] must read exact zeros
         @pl.when(first)
         def _():
             o_ref[...] = jnp.zeros_like(o_ref)
 
-        o_ref[...] += contrib
+        # a unit that owns no row added an exact +0.0 to every row:
+        # skipping it leaves every output bit where it was
+        @pl.when(hi_r[u] > lo_r[u])
+        def _():
+            rows = tids_r[u] * bm \
+                + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
+            acc = jax.lax.dot_general(
+                x_ref[...], w_ref[0].astype(x_ref.dtype),
+                (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.DEFAULT,
+                preferred_element_type=jnp.float32)        # [bm, bn]
+            acc = acc + b_ref[0].astype(jnp.float32)
+            acc = _apply_activation(acc, activation)
+            mask = jnp.logical_and(rows >= lo_r[u], rows < hi_r[u])
+            o_ref[...] += jnp.where(mask, acc, jnp.float32(0.0))
 
+    # x tiles past the last live row are never read, so never fetched:
+    # their units' x block index repeats the last live tile's (their
+    # weight block index already repeats the last expert's)
+    xt = jnp.minimum(tids, jnp.maximum(jnp.max(hi) - 1, 0) // bm)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(nb, nwu),
         in_specs=[
-            pl.BlockSpec((bm, K), lambda j, u, g, t, lo_, hi_: (t[u], 0)),
-            pl.BlockSpec((1, K, bn),
-                         lambda j, u, g, t, lo_, hi_: (g[u], 0, j)),
-            pl.BlockSpec((1, 1, bn),
-                         lambda j, u, g, t, lo_, hi_: (g[u], 0, j)),
+            pl.BlockSpec((bm, K), lambda j, u, g, t, lo_, hi_, xt_:
+                         (xt_[u], 0)),
+            pl.BlockSpec((1, K, bn), lambda j, u, g, t, lo_, hi_, xt_:
+                         (g[u], 0, j)),
+            pl.BlockSpec((1, 1, bn), lambda j, u, g, t, lo_, hi_, xt_:
+                         (g[u], 0, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn),
-                               lambda j, u, g, t, lo_, hi_: (t[u], j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda j, u, g, t, lo_, hi_, xt_:
+                               (t[u], j)),
         scratch_shapes=[])
     with _enable_x64(False), jax.named_scope("pt_grouped_gemm_fwd"):
         out = pl.pallas_call(
@@ -216,7 +230,7 @@ def _grouped_fwd_pallas(x_pad, w3, b3, gids, tids, lo, hi, bm, bn,
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
             interpret=interpret,
-        )(gids, tids, lo, hi, x_pad, w3, b3)
+        )(gids, tids, lo, hi, xt, x_pad, w3, b3)
     return out
 
 
@@ -224,15 +238,16 @@ def _grouped_fwd_xla(x_pad, w3, b3, gids, tids, lo, hi, bm, bn,
                      activation):
     """Math-identical tiled XLA walk: the SAME (bm, K) x (K, bn) dots
     over the SAME units in the same order, fp32 accumulation from a
-    zero output — bitwise-equal to the interpreter-run kernel (every
-    non-owning unit contributes an exact +0.0 to a row)."""
+    zero output — bitwise-equal to the interpreter-run kernel (a unit
+    that owns no row is skipped here as there: it would add an exact
+    +0.0 to every row)."""
     t_pad, K = x_pad.shape
     E, _, N = w3.shape
     nb = N // bn
     nwu = gids.shape[0]
     rows_in_tile = jnp.arange(bm, dtype=jnp.int32)[:, None]
 
-    def unit(u, out):
+    def live_unit(u, out):
         tid = tids[u]
         gid = gids[u]
         xt = jax.lax.dynamic_slice(x_pad, (_i32(tid * bm), _I0), (bm, K))
@@ -257,6 +272,10 @@ def _grouped_fwd_xla(x_pad, w3, b3, gids, tids, lo, hi, bm, bn,
 
         return jax.lax.fori_loop(0, nb, col, out)
 
+    def unit(u, out):
+        return jax.lax.cond(hi[u] > lo[u], live_unit,
+                            lambda u, out: out, u, out)
+
     out0 = jnp.zeros((t_pad, N), jnp.float32)
     return jax.lax.fori_loop(0, nwu, unit, out0)
 
@@ -277,14 +296,6 @@ def _grouped_dw_pallas(x_pad, dz_pad, gids, tids, lo, hi, bm, bn,
 
     def kernel(gids_r, tids_r, lo_r, hi_r, x_ref, dz_ref, o_ref):
         u = pl.program_id(1)
-        rows = tids_r[u] * bm \
-            + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
-        mask = jnp.logical_and(rows >= lo_r[u], rows < hi_r[u])
-        xm = jnp.where(mask, x_ref[...], jnp.zeros_like(x_ref))
-        contrib = jax.lax.dot_general(
-            xm, dz_ref[...], (((0,), (0,)), ((), ())),
-            precision=jax.lax.Precision.DEFAULT,
-            preferred_element_type=jnp.float32)            # [K, bn]
         first = jnp.logical_or(
             u == 0, gids_r[jnp.maximum(u - 1, 0)] != gids_r[u])
 
@@ -292,7 +303,18 @@ def _grouped_dw_pallas(x_pad, dz_pad, gids, tids, lo, hi, bm, bn,
         def _():
             o_ref[...] = jnp.zeros_like(o_ref)
 
-        o_ref[...] += contrib[None]
+        # as in the forward kernel: a unit without rows adds nothing
+        @pl.when(hi_r[u] > lo_r[u])
+        def _():
+            rows = tids_r[u] * bm \
+                + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
+            mask = jnp.logical_and(rows >= lo_r[u], rows < hi_r[u])
+            xm = jnp.where(mask, x_ref[...], jnp.zeros_like(x_ref))
+            contrib = jax.lax.dot_general(
+                xm, dz_ref[...], (((0,), (0,)), ((), ())),
+                precision=jax.lax.Precision.DEFAULT,
+                preferred_element_type=jnp.float32)        # [K, bn]
+            o_ref[...] += contrib[None]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -317,7 +339,7 @@ def _grouped_dw(x_pad, dz_pad, E, gids, tids, lo, hi, bm, bn, backend):
         nwu = gids.shape[0]
         rows_in_tile = jnp.arange(bm, dtype=jnp.int32)[:, None]
 
-        def unit(u, dw):
+        def live_unit(u, dw):
             tid = tids[u]
             gid = gids[u]
             xt = jax.lax.dynamic_slice(x_pad, (_i32(tid * bm), _I0), (bm, K))
@@ -338,6 +360,10 @@ def _grouped_dw(x_pad, dz_pad, E, gids, tids, lo, hi, bm, bn, backend):
                     dw, cur + contrib[None], (gid, _I0, _i32(j * bn)))
 
             return jax.lax.fori_loop(0, nb, col, dw)
+
+        def unit(u, dw):
+            return jax.lax.cond(hi[u] > lo[u], live_unit,
+                                lambda u, dw: dw, u, dw)
 
         dw0 = jnp.zeros((E, K, N), jnp.float32)
         return jax.lax.fori_loop(0, nwu, unit, dw0)
@@ -365,8 +391,19 @@ def _grouped_dw(x_pad, dz_pad, E, gids, tids, lo, hi, bm, bn, backend):
 # Public entry (custom_vjp)
 # ---------------------------------------------------------------------
 
+#: an expert matrix up to this size is ONE column block. Every column
+#: block walks the whole static schedule again (a grid step a unit, the
+#: dead ones too) and fetches every x tile again, so fewer blocks win
+#: while two of them sit in VMEM beside the tiles (kernel alone on a
+#: v5e, PR 34: ``[4096, 1536]`` bf16 as one block 0.75 ms, as two of 768
+#: 0.78, as four 0.79; the row tile moved nothing: 64, 128, 256 within 1%)
+_WHOLE_MATRIX_BYTES = 16 << 20
+
+
 def _geometry(K: int, N: int, itemsize: int):
     """(bm, bn) for the kernel path, or None when N can't tile."""
+    if N % 128 == 0 and K * N * itemsize <= _WHOLE_MATRIX_BYTES:
+        return DEFAULT_BLOCK_ROWS, N
     bn = _pick_bn(K, N, itemsize)
     return (DEFAULT_BLOCK_ROWS, bn) if bn else None
 
@@ -379,9 +416,11 @@ def _pad_rows(x, t_pad):
 
 
 def _raw_grouped(x, w, b, offsets, activation, backend, first_group=0):
-    """One ragged grouped GEMM, f32 output [T, N] (no autodiff). The
-    ``len(offsets) - 1`` groups are rows ``first_group ..`` of the bank
-    ``w`` (0 and the whole bank, except through ``grouped_gemm_banked``)."""
+    """One ragged grouped GEMM (no autodiff): the f32 output [T, N] and
+    int32 ``[2]``, the (unit, column block) grid steps its schedule
+    walks and those of them that own a row. The ``len(offsets) - 1``
+    groups are rows ``first_group ..`` of the bank ``w`` (0 and the
+    whole bank, except through ``grouped_gemm_banked``)."""
     T, K = x.shape
     E, _, N = w.shape
     geo = _geometry(K, N, w.dtype.itemsize)
@@ -404,7 +443,9 @@ def _raw_grouped(x, w, b, offsets, activation, backend, first_group=0):
         out = _grouped_fwd_pallas(
             x_pad, w, b3, gids, tids, lo, hi, bm, bn, activation,
             interpret=(backend == "interpret" or not _chip.on_tpu()))
-    return out[:T]
+    units = (N // bn) * jnp.stack(
+        [jnp.int32(gids.shape[0]), jnp.sum(hi > lo, dtype=jnp.int32)])
+    return out[:T], units
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
@@ -415,9 +456,8 @@ def _grouped_core(x, w, b, offsets, activation, backend, out_dtype):
 
 
 def _grouped_core_fwd(x, w, b, offsets, activation, backend, out_dtype):
-    y = _raw_grouped(x, w, b, offsets, activation, backend) \
-        .astype(out_dtype)
-    return y, (x, w, b, offsets)
+    y, _ = _raw_grouped(x, w, b, offsets, activation, backend)
+    return y.astype(out_dtype), (x, w, b, offsets)
 
 
 def _act_fn(activation):
@@ -437,15 +477,15 @@ def _grouped_core_bwd(activation, backend, out_dtype, res, g):
     if activation:
         # recompute the pre-activation with one more grouped GEMM
         # (cheaper than carrying the [T, N] residual through fwd)
-        z = _raw_grouped(x, w, b, offsets, None, backend)
+        z, _ = _raw_grouped(x, w, b, offsets, None, backend)
         _, act_vjp = jax.vjp(_act_fn(activation), z)
         (dz,) = act_vjp(g32)
     else:
         dz = g32
     # dx walks the forward map against the per-expert transposed bank
     zero_bk = jnp.zeros((E, K), jnp.float32)
-    dx = _raw_grouped(dz, jnp.swapaxes(w, 1, 2), zero_bk, offsets,
-                      None, backend)
+    dx, _ = _raw_grouped(dz, jnp.swapaxes(w, 1, 2), zero_bk, offsets,
+                         None, backend)
     # dw accumulates per expert segment (expert-sorted units)
     geo = _geometry(K, N, w.dtype.itemsize)
     dwb = _resolve_backend(backend, geo is not None)
@@ -511,7 +551,9 @@ def grouped_gemm_banked(x, bank, offsets, first_group: int, *,
     first_group + E - 1`` — the kernel's weight block index is shifted,
     so no per-layer slice of the bank is ever materialised (a slice
     handed to a Pallas call is copied: 432 MB a layer at granite's
-    widths). No bias, no activation, no autodiff; float32 ``[T, N]``."""
+    widths). No bias, no activation, no autodiff. Returns float32 ``[T,
+    N]`` and int32 ``[2]``: the (unit, column block) grid steps the
+    schedule walked and those that owned a row."""
     G, _, N = bank.shape
     if offsets.shape[0] - 1 + int(first_group) > G:
         raise ValueError(

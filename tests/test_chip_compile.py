@@ -204,6 +204,48 @@ def test_kernel_compiles_for_v5e(name, one_chip, as_on_chip):
         assert shown == set(site.kernels)
 
 
+#: granite-4.0-h-small's prefill chunk: 256 tokens x top-10 sorted rows
+#: against the layer-stacked bank of 10 layers x 36 held experts, read in
+#: place from layer 5's first group (gate/up d -> 2f, then down f -> d)
+_BANKED = {"up": (4096, 1536), "down": (768, 4096)}
+
+
+@pytest.mark.parametrize("gemm", sorted(_BANKED))
+def test_banked_grouped_gemm_compiles_at_the_cell_shapes(gemm, one_chip,
+                                                         as_on_chip):
+    """``grouped_gemm_banked`` as ``moe_gated_grouped`` calls it in the
+    rag cell (the ``grouped_gemm.fwd`` site compiles ``grouped_gemm`` at
+    the 1.3B widths, never the bank read in place): it compiles for the
+    chip under ``KERNEL_VMEM_LIMIT_BYTES`` with the blocks ``_geometry``
+    chooses for these widths, as one ``pt_grouped_gemm_fwd`` launch and
+    with no copy of the bank."""
+    from paddle_tpu.device.vmem import KERNEL_VMEM_LIMIT_BYTES
+    from paddle_tpu.nn.functional.grouped_gemm import (_geometry,
+                                                       grouped_gemm_banked)
+
+    K, N = _BANKED[gemm]
+    rows, held, layers = 256 * 10, 36, 10
+    bm, bn = _geometry(K, N, 2)
+    assert (bm, bn) == (128, N)        # each expert matrix: one block
+    # x tile, weight and zero-bias blocks, f32 out tile, each in two
+    # buffers
+    blocks = 2 * (bm * K * 2 + K * bn * 2 + bn * 4 + bm * bn * 4)
+    assert blocks < KERNEL_VMEM_LIMIT_BYTES // 2
+
+    def fn(x, bank, offsets):
+        return grouped_gemm_banked(x, bank, offsets, 5 * held,
+                                   backend="pallas")
+
+    bank = _sds((layers * held, K, N), jnp.bfloat16)
+    args = (_sds((rows, K), jnp.bfloat16), bank,
+            _sds((held + 1,), jnp.int32))
+    compiled = jax.jit(fn).lower(*_placed(args, one_chip)).compile()
+    text = compiled.as_text()
+    assert _kernel_names(text) == {"pt_grouped_gemm_fwd"}
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 2 * math.prod(bank.shape) // 100
+
+
 def _uniform_stack():
     """A parameterless ``FusedMultiTransformer`` at the gpt3-1.3b cells'
     widths and depth (as ``_tp_view`` builds one: the raw methods read

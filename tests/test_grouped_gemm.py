@@ -6,7 +6,8 @@ against a dense per-row reference, BITWISE equality between the
 interpreter-run Pallas kernel and the tiled XLA fallback (fwd and
 grads — the off-TPU path must be the exact serving numerics), gradient
 parity against jax autodiff of the dense reference, and the ragged
-edge cases (empty experts, total skew, pad rows past offsets[E]).
+edge cases (empty experts, total skew, pad rows past offsets[E]), and
+what a work unit that owns no row costs: no dot, in either walk.
 """
 import numpy as np
 import pytest
@@ -15,7 +16,8 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.nn.functional.grouped_gemm import (
-    DEFAULT_BLOCK_ROWS, grouped_gemm, grouped_work_map, moe_route)
+    DEFAULT_BLOCK_ROWS, grouped_gemm, grouped_gemm_banked,
+    grouped_work_map, moe_route)
 
 
 def _mk(T=200, K=256, N=384, E=4, seed=0, dtype=np.float32):
@@ -192,6 +194,112 @@ class TestGroupedGemm:
         yi = grouped_gemm(x, w, offsets, bias=b, backend="interpret")
         yx = grouped_gemm(x, w, offsets, bias=b, backend="xla")
         assert np.array_equal(np.asarray(yi), np.asarray(yx))
+
+
+def _prefill_offsets(seed, T=256, k=10, experts=72, held=36):
+    """Offsets of a prefill chunk at the rag cell's routing shape: T
+    tokens pick k of ``experts`` at random, ``held`` are here, the
+    absent picks sort behind ``offsets[held]``."""
+    rng = np.random.RandomState(seed)
+    idx = np.stack([rng.permutation(experts)[:k] for _ in range(T)])
+    counts = np.bincount(idx[idx < held], minlength=held)
+    return np.concatenate([[0], np.cumsum(counts)]).astype(np.int32)
+
+
+def _recount_units(offsets, t_pad, bm):
+    """(walked, live) by hand: the static schedule's length, and one
+    unit for every row tile an expert's interval touches."""
+    E = len(offsets) - 1
+    live = sum(-(-int(b) // bm) - int(a) // bm
+               for a, b in zip(offsets[:-1], offsets[1:]) if b > a)
+    return t_pad // bm + 2 * E + 1, live
+
+
+def _dots(jaxpr, guarded=False):
+    """(equation, under a ``cond``?) of every dot_general in ``jaxpr``,
+    kernel bodies and loops included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn, guarded
+        inner = guarded or eqn.primitive.name == "cond"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _dots(sub, inner)
+
+
+class TestDeadUnits:
+    """The rag cell's prefill chunk, scaled down in K and N only: 2,560
+    sorted rows, 36 held experts of 72, top-10. Half the static
+    schedule owns no row; such a unit must cost no matmul and still
+    leave its tile zero."""
+
+    T_ROWS, E, K, N = 2560, 36, 128, 256
+
+    def _operands(self, seed, groups=None):
+        rng = np.random.RandomState(seed)
+        G = groups or self.E
+        x = rng.randn(self.T_ROWS, self.K).astype(np.float32)
+        bank = (rng.randn(G, self.K, self.N) * 0.05).astype(np.float32)
+        return x, bank, _prefill_offsets(seed)
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_reported_units_equal_a_recount(self, seed):
+        x, bank, offsets = self._operands(seed)
+        _, units = grouped_gemm_banked(
+            jnp.asarray(x), jnp.asarray(bank), jnp.asarray(offsets), 0,
+            backend="xla")
+        walked, live = _recount_units(offsets, self.T_ROWS,
+                                      DEFAULT_BLOCK_ROWS)
+        # un-tileable or not, N = 256 is one column block here
+        assert [int(u) for u in units] == [walked, live]
+        assert units.dtype == jnp.int32
+        assert walked == 93 and live < 0.55 * walked
+
+    @pytest.mark.parametrize("backend", ["xla", "interpret"])
+    def test_a_dead_unit_does_no_matmul(self, backend):
+        """Every dot of the walk sits under a ``cond`` (``pl.when`` in
+        the kernel body) on the unit's ``hi > lo``."""
+        x, bank, offsets = self._operands(0)
+
+        def fn(x, bank, offsets):
+            return grouped_gemm_banked(x, bank, offsets, 0,
+                                       backend=backend)
+
+        dots = list(_dots(jax.make_jaxpr(fn)(x, bank, offsets).jaxpr))
+        assert dots, "no dot traced: the walk was not taken"
+        assert all(guarded for _, guarded in dots)
+
+    @pytest.mark.parametrize("backend", ["xla", "interpret"])
+    def test_rows_past_the_live_picks_stay_exact_zeros(self, backend):
+        """NaN where a dead unit would read: the absent picks' rows of
+        x, and the weight block the phantom and trailing units alias
+        (the last group's, here made empty). Masked today, skipped now:
+        either way nothing of it may reach the output."""
+        x, bank, offsets = self._operands(3)
+        offsets[-1] = offsets[-2]               # the last expert: no row
+        live = int(offsets[-1])
+        x[live:] = np.nan
+        bank[-1] = np.nan
+        y, units = grouped_gemm_banked(
+            jnp.asarray(x), jnp.asarray(bank), jnp.asarray(offsets), 0,
+            backend=backend)
+        y = np.asarray(y)
+        assert (y[live:] == 0).all()
+        eids = np.repeat(np.arange(self.E), np.diff(offsets))
+        ref = np.einsum("tk,tkn->tn", x[:live], bank[eids])
+        np.testing.assert_allclose(y[:live], ref, atol=2e-4)
+        assert int(units[1]) == _recount_units(
+            offsets, self.T_ROWS, DEFAULT_BLOCK_ROWS)[1]
+
+    def test_banked_groups_are_the_banks_rows_in_place(self):
+        """``first_group`` shifts the weight block index: layer 1's 36
+        experts of a two-layer bank, bitwise the unshifted call on
+        that layer's slice."""
+        x, bank, offsets = self._operands(4, groups=2 * self.E)
+        x, bank, offsets = map(jnp.asarray, (x, bank, offsets))
+        y, _ = grouped_gemm_banked(x, bank, offsets, self.E, backend="xla")
+        z, _ = grouped_gemm_banked(x, bank[self.E:], offsets, 0,
+                                   backend="xla")
+        assert np.array_equal(np.asarray(y), np.asarray(z))
 
 
 class TestRouter:
