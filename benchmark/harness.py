@@ -152,6 +152,7 @@ class Tracer:
         self.active = False
         self.m0 = self.m1 = None
         self._ann = None
+        self.stats_m1 = None        # the program's counters at ``m1``
 
     def start(self):
         if not self.on:
@@ -181,6 +182,9 @@ class Tracer:
 
         self.m1 = time.monotonic()
         self._ann.__exit__(None, None, None)
+        # the stop holds the calling loop for tens of seconds: whatever a
+        # reader takes from a traced run, it takes from before ``m1``
+        self.stats_m1 = program_stats()
         jax.profiler.stop_trace()
         self.active = False
 
@@ -212,6 +216,14 @@ class Tracer:
                                   span_prefix=TRACE_PREFIX)
         shutil.rmtree(self.dir, ignore_errors=True)
         return out
+
+
+def program_stats():
+    """The program's counters, gauges and histogram totals as they stand:
+    what a driver samples at the window's edges (``stats0`` / ``stats1``)."""
+    from paddle_tpu.profiler import stats
+
+    return stats.sample_values()
 
 
 def setup_environment(root: str):
@@ -255,6 +267,11 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     verdict = driver.check()       # the plain reference, after the window
 
     facts = driver.facts()
+    if facts.get("stats1") is not None and tracer.stats_m1 is not None:
+        # a traced run's counters end with its traced part: stopping the
+        # profiler holds the loop while an open loop's arrivals go on, and
+        # what follows is the catching up, not the cell
+        facts["stats1"] = tracer.stats_m1
     facts["setup_s"] = setup_s
     facts["compile_s_in_setup"] = compiles.seconds_before(driver.t_open)
     facts["window_compiles"] = compiles.count_between(driver.t_open,

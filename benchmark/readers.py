@@ -37,6 +37,16 @@ def in_traced(ctx, t):
     return t0 is not None and t1 is not None and t0 <= t < t1
 
 
+def seen_in_traced(ctx, t):
+    """True where the stepping loop began a step between ``t`` and the end
+    of the traced part. What is submitted during the loop's last step before
+    the profiler stops (``Tracer.stop()`` holds the loop for tens of
+    seconds) is first seen after it, and waited for the profiler."""
+    t1 = ctx["traced"][1]
+    return in_traced(ctx, t) and any(
+        t <= s[0] < t1 for s in ctx["facts"].get("steps", ()))
+
+
 def module_time(ctx, pattern):
     """(seconds, runs) of the programs whose name matches, in the trace."""
     tr = ctx["trace"]
@@ -65,12 +75,15 @@ def device_idle(ctx, minus_span=None):
     return 100.0 * idle / tr.window_s
 
 
-# The programs of the serving engine, as today's trace names them (the
-# program gives its jitted functions no stable names yet: PERF.md, Open
-# questions). Every prefill-chunk size shares the first name; the decode
-# program is a ``functools.partial`` and so is called ``_unknown``.
-PREFILL_PROGRAM = r"^jit__chunk_prefill_fn\("
-DECODE_PROGRAM = r"^jit__unknown\("
+# The programs of the serving engine. Each pattern takes two spellings:
+# the name today's trace gives (the program declares none yet; every
+# prefill-chunk size shares the first, and the decode program is a
+# ``functools.partial`` and so is called ``_unknown``), and ONE declared
+# name in the form ``readers_granite.py`` matches, which a later program PR
+# may give the uniform stack's programs without silencing eight readers.
+PREFILL_PROGRAM = (r"^(?:jit__chunk_prefill_fn\("
+                   r"|jit_+pt_fused_prefill_chunk(?!\w))")
+DECODE_PROGRAM = r"^(?:jit__unknown\(|jit_+pt_fused_decode_chunk(?!\w))"
 
 
 def prefill_chunks(ctx):
@@ -123,5 +136,6 @@ def decode_work(ctx):
         steps += k
     return flops, nbytes, steps
 
-#: the compiled train step (``TrainStep._pure_step`` under jit)
-TRAIN_PROGRAM = r"^jit__pure_step\("
+#: the compiled train step: ``TrainStep._pure_step`` under jit today, or
+#: the one name a later program PR may declare for it
+TRAIN_PROGRAM = r"^(?:jit__pure_step\(|jit_+pt_train_step(?!\w))"

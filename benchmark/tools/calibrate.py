@@ -7,8 +7,9 @@ benchmark PR can repeat them. None of them is part of a run.
                 stats) to chiprun_out/, to be read by hand before a reader
                 is written against them
     sweep       one engine, one window per arrival rate: completions, the
-                waiting queue at the middle and at the end, TTFT and TPOT;
-                the knee is the highest rate that keeps up
+                waiting queue at the middle and at the end, the slots and
+                pages held, TTFT and TPOT; the knee is the highest rate
+                that keeps up
     gaps        the numbers ``correct`` compares, for the program and for
                 the controls, over seeds, in one process
 """
@@ -89,6 +90,16 @@ def sweep(args):
         def stepper(d=d, mid=mid, orig=orig):
             out = orig()
             now = time.monotonic()
+            if d.t_open is not None and d.t_open <= now < d.t_close:
+                # what could stop an admission: slots, pages, the queue
+                eng, mgr = d.engine, d.engine._mgr
+                held = eng.num_active + eng.num_prefilling
+                dt = now - mid.get("t", now)
+                mid["t"] = now
+                mid["slot_s"] = mid.get("slot_s", 0.0) + held * dt
+                mid["slots_max"] = max(mid.get("slots_max", 0), held)
+                mid["pages"] = max(mid.get("pages", 0),
+                                   mgr.num_pages - mgr.free_pages)
             if "q" not in mid and d.t_open is not None and \
                     now >= (d.t_open + d.t_close) / 2:
                 mid["q"] = d.engine.queue_depth
@@ -104,6 +115,9 @@ def sweep(args):
         say({"rate_rps": rate, "attempted": f["attempted"],
              "failed": f["failed"], "queue_mid": mid.get("q"),
              "queue_end": mid.get("q_end"),
+             "slots_mean": mid.get("slot_s", 0.0) / args.seconds,
+             "slots_max": mid.get("slots_max"),
+             "pages_max": mid.get("pages"),
              "ttft_p50_ms": f.get("ttft_p50_ms"),
              "ttft_p95_ms": f.get("ttft_p95_ms"),
              "tpot_p50_ms": f.get("tpot_p50_ms"),

@@ -65,7 +65,7 @@ def make_root(tmp, extra_cells=(), extra_metrics=(), vocab=512):
     dump(root, "benchmark/traffic/toy-open.json", TOY_OPEN)
     dump(root, "benchmark/traffic/toy-closed.json", TOY_CLOSED)
     dump(root, "benchmark/traffic/toy-train.json", TOY_TRAIN)
-    rename = {"gpt3-1.3b.chat-steady": "toy-gpt.toy-open",
+    rename = {"gpt3-1.3b.chat-knee80": "toy-gpt.toy-open",
               "gpt3-1.3b.reason-saturated": "toy-gpt.toy-closed",
               "gpt3-1.3b.pretrain-s2048": "toy-gpt.toy-train"}
     cells = [{"name": new, "config": "toy-gpt",

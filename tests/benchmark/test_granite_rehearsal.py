@@ -24,7 +24,8 @@ RAG = ["decode_step_ms.rag", "decode_step_roofline.rag",
        "prefill_step_mfu.rag", "ssm_decode_roofline.rag",
        "ssd_prefill_roofline.rag", "moe_experts_roofline.rag",
        "experts_hit_share.rag", "picks_here_share.rag", "step_host_ms.rag",
-       "device_idle.rag", "window_compiles.rag"]
+       "device_idle.rag", "window_compiles.rag", "kv_walked_share.rag",
+       "units_live_share.rag"]
 
 
 # ------------------------------------------------------ the real files
@@ -194,9 +195,10 @@ def summary(ops=(), modules=()):
     return s
 
 
-def counters(picks, here, hit, held):
+def counters(picks, here, hit, held, walked=1860, live=930):
     c = {"serving.moe.picks": picks, "serving.moe.picks_here": here,
-         "serving.moe.experts_hit": hit, "serving.moe.experts_held": held}
+         "serving.moe.experts_hit": hit, "serving.moe.experts_held": held,
+         "serving.moe.units_walked": walked, "serving.moe.units_live": live}
     return ({k: 100 for k in c}, {}, {}), \
         ({k: 100 + v for k, v in c.items()}, {}, {})
 
@@ -317,6 +319,11 @@ def test_counter_and_span_readers():
     assert reader("picks_here_share.rag").read(ctx(None, none)) is None
     assert reader("experts_hit_share.rag").read(ctx(None, none)) is None
     assert reader("window_compiles.rag").read(ctx(None, f)) == 0
+    # 20 grouped GEMMs of 93 units walked, half of them owning a row
+    assert reader("units_live_share.rag").read(ctx(None, f)) == 50.0
+    assert reader("units_live_share.rag").read(ctx(None, none)) is None
+    f["stats1"] = counters(2000, 1000, 990, 1000, walked=0, live=0)[1]
+    assert reader("units_live_share.rag").read(ctx(None, f)) is None
     f["stats0"] = ({}, {}, {"serve.step.total_ms": (10, 100.0),
                             "serve.step.run_ms": (10, 90.0)})
     f["stats1"] = ({}, {}, {"serve.step.total_ms": (30, 400.0),

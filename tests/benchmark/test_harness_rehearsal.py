@@ -95,11 +95,41 @@ def test_new_cell_and_metric_are_found_by_name(tmp_path, monkeypatch):
                if p != "BENCHMARK.json")
 
 
+TRAFFIC = os.path.join(bench_toy.REPO, "benchmark", "traffic")
+MIXES = sorted(f[:-len(".json")] for f in os.listdir(TRAFFIC)
+               if f.endswith(".json"))
+#: a p95 wants ten samples beyond it (choosing-metrics, section 1), which
+#: is 200 requests; four fifths of a knee of 4.0 requests/s in the 50 s that
+#: ``run_seconds`` allows gives 160 and eight beyond (PERF.md section 4).
+#: The floor keeps a mix from falling back to a tail that is ONE request.
+MIN_DUE_FOR_A_P95 = 150
+
+
+@pytest.mark.parametrize("mix", MIXES)
+def test_a_mix_has_its_cell_and_a_tail_its_samples(mix):
+    """The real files: every mix is some cell's traffic, and an open loop
+    whose cell is judged by a 95th percentile offers enough requests in
+    one window of ``run_seconds`` that the percentile is not one
+    request's draw (chat-steady held 24: PERF.md section 6, PR 33)."""
+    bench = bench_toy.real_benchmark()
+    cells = [w["name"] for w in bench["workloads"] if w["traffic"] == mix]
+    assert len(cells) == 1
+    cell = harness.Cell(bench_toy.REPO, cells[0])
+    arrivals = cell.traffic.get("arrivals", {})
+    tails = [m["name"] for m in cell.end_to_end if "_p95_" in m["name"]]
+    if "rate_rps" not in arrivals:
+        assert not tails          # a closed loop or a trainer: a rate judges
+        return
+    assert tails
+    due = round(float(arrivals["rate_rps"]) * bench["run_seconds"])
+    assert due >= MIN_DUE_FOR_A_P95, (mix, due)
+
+
 def test_command_fails_off_chip_and_prints_no_result():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
         [sys.executable, os.path.join(bench_toy.REPO, "benchmark", "run.py"),
-         "--workload", "gpt3-1.3b.chat-steady", "--seed", "1",
+         "--workload", "gpt3-1.3b.chat-knee80", "--seed", "1",
          "--seconds", "1", "--trace", "0"],
         env=env, capture_output=True, text=True, timeout=300)
     assert p.returncode != 0

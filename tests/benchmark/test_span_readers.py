@@ -144,6 +144,104 @@ def test_train_attn_roofline():
     assert read(ctx(None, facts)) is None
 
 
+def hole_facts(hole_s):
+    """An open loop of 4 requests/s due from t = 10 (the traced part is
+    10..18) to t = 30, each admitted 40 ms + a tenth of its index after it
+    was due and given 9 tokens 30 ms apart (33 for every other one, request
+    31's last ones past t = 18); ``hole_s`` seconds in which nothing steps
+    follow the traced part (``Tracer.stop()``), so whatever was due or
+    decoding in the hole waits for its end."""
+    reqs = []
+    # two more are due during the loop's last step before the stop (it
+    # begins at 17.9): the loop first sees them after the hole
+    dues = [10.0 + i / 4.0 for i in range(80)] + [17.93, 17.97]
+    for i, due in enumerate(dues):
+        r = Req(i, np.zeros(64, np.int32), 9)
+        r.due = due
+        r.state = "ok"
+        gap = 0.030 if i % 2 else 0.033
+        # admitted by the first step that begins once it is due
+        first = (r.due if r.due < 17.9 else 18.0) + 0.050 + i * 1e-4
+        r.token_t = [first + gap * k for k in range(9)]
+        if hole_s and 18.0 <= r.token_t[-1] < 18.0 + hole_s:
+            # stalled by the profiler: the tokens still owed come after it
+            r.token_t = [t if t < 18.0 else 18.0 + hole_s + (t - 18.0)
+                         for t in r.token_t]
+        r.admitted = r.token_t[0] - 0.010
+        r.done_t = r.token_t[-1]
+        reqs.append(r)
+    steps = [(9.0 + k / 10.0, 9.1 + k / 10.0, "decode") for k in range(90)]
+    steps += [(18.0 + hole_s + k / 10.0, 18.1 + hole_s + k / 10.0, "decode")
+              for k in range(150)]
+    return {"requests": reqs, "due": reqs, "steps": steps}
+
+
+@pytest.mark.parametrize("name,want", [
+    # 32 requests due in the traced part and seen there (not the two due
+    # during its last step): rank 31 waited 40 + 3.0 ms
+    ("queue_wait_p95_ms.chat", 40.0 + 30 * 0.1),
+    # 31 finished inside it (request 31's tokens run past its end):
+    # fifteen at 30 ms a token, sixteen at 33; the median is the 16th
+    ("tpot_p50_ms.sat", 33.0)])
+def test_host_clock_readers_leave_out_the_profilers_hole(name, want):
+    read = reader(name).read
+    without, with_hole = hole_facts(0.0), hole_facts(20.0)
+    assert read(ctx(None, without)) == pytest.approx(want)
+    assert read(ctx(None, with_hole)) == pytest.approx(want)
+    # the hole is in the facts: over every request it reads seconds
+    stalled = [r for r in with_hole["requests"]
+               if r.admitted - r.due > 1.0 or r.done_t - r.token_t[0] > 1.0]
+    assert len(stalled) == 51
+    # no traced part (a run without the profiler): nothing to read
+    assert read(dict(ctx(None, without), traced=(None, None))) is None
+
+
+PROGRAMS = {"PREFILL_PROGRAM": ("jit__chunk_prefill_fn(77)",
+                                "jit_pt_fused_prefill_chunk(77)"),
+            "DECODE_PROGRAM": ("jit__unknown(5)",
+                               "jit__pt_fused_decode_chunk"),
+            "TRAIN_PROGRAM": ("jit__pure_step(123)",
+                              "jit_pt_train_step(9)")}
+NEAR_MISSES = ["jit__chunk_prefill_fn_v2(77)", "jit__unknown_1(5)",
+               "jit__pure_step2(123)", "jit_pt_fused_prefill_chunks(7)",
+               "jit_pt_fused_decode_chunk_q(5)", "jit_pt_train_stepper(9)",
+               "jit_pt_hybrid_prefill_chunk(99)", "xjit__unknown(5)",
+               "pt_fused_decode_chunk(5)"]
+
+
+@pytest.mark.parametrize("const", sorted(PROGRAMS))
+def test_program_patterns_take_two_spellings_and_no_near_miss(const):
+    """Today's module name and the ONE declared name each pattern accepts
+    (the form ``readers_granite.py`` matches) read alike; the other
+    programs' names and near misses read nothing."""
+    from benchmark import readers
+
+    pattern = getattr(readers, const)
+    for name in PROGRAMS[const]:
+        tr = summary(modules=[(name, 1.5, 3)]
+                     + [(miss, 9.0, 1) for miss in NEAR_MISSES])
+        assert readers.module_time(ctx(tr, {}), pattern) == (1.5, 3)
+    others = [n for k, pair in PROGRAMS.items() if k != const for n in pair]
+    tr = summary(modules=[(n, 9.0, 1) for n in NEAR_MISSES + others])
+    assert readers.module_time(ctx(tr, {}), pattern) is None
+
+
+@pytest.mark.parametrize("name,const,per", [
+    ("prefill_chunk_ms.chat", "PREFILL_PROGRAM", 1),
+    ("decode_step_ms.sat", "DECODE_PROGRAM", 16),
+    ("train_step_device_ms", "TRAIN_PROGRAM", 1)])
+def test_a_declared_program_name_reads_as_todays(name, const, per):
+    """A reader of each program through its file: 12 ms a call under
+    today's name, the same under the declared one, None under a
+    stranger's."""
+    read = reader(name).read
+    for mod in PROGRAMS[const]:
+        tr = summary(modules=[(mod, 0.048, 4)])
+        assert read(ctx(tr, decode_facts())) == pytest.approx(12.0 / per)
+    tr = summary(modules=[("jit_pt_hybrid_decode_chunk(1)", 0.048, 4)])
+    assert read(ctx(tr, decode_facts())) is None
+
+
 def hists(**totals):
     return ({}, {}, {"serve.step." + k: v for k, v in totals.items()})
 
