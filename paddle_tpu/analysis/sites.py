@@ -614,6 +614,78 @@ def _expected_moe_stream_experts():
             + _B((M, d), "float32"))             # the accumulated output
 
 
+# latent attention over the paged latent pool (ISSUE 37) at the published
+# widths of the configuration that drives it: 32 heads, a 384-lane row
+# (256 latent + 64 rope + 64 pad), page 16; a 256-token chunk (two tiles of
+# 128 tokens x 32 heads) and 8 decode rows over tables of 64 pages
+_MLA = dict(H=32, W=384, R=256, ps=16, P=512, pp=64, c=256, S=8)
+
+
+def _build_mla_paged_prefill():
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional.mla_attention import mla_prefill_attend
+
+    H, W, R, ps, P, pp, c = (_MLA[k] for k in
+                             ("H", "W", "R", "ps", "P", "pp", "c"))
+
+    def fn(q, rows, pool, tables, start, lens):
+        return mla_prefill_attend(q, rows, pool, tables, start, lens,
+                                  v_width=R)
+
+    return fn, (_sds((c, H, W), jnp.bfloat16), _sds((c, W), jnp.bfloat16),
+                _sds((P, ps, W), jnp.bfloat16), _sds((1, pp), jnp.int32),
+                _sds((1,), jnp.int32), _sds((1,), jnp.int32))
+
+
+def _expected_mla_paged_prefill():
+    # a tile is 128 query tokens x 32 heads = 4096 rows; a 256-row chunk
+    # at any offset touches 256/16 + 1 = 17 pages; the walk gathers 16
+    # pages (256 tokens) a step into one half of a double buffer; the
+    # pool itself stays in HBM (aliased, memory_space=ANY)
+    H, W, R, ps = (_MLA[k] for k in ("H", "W", "R", "ps"))
+    M, npg, cpk = 128 * H, 17, 16
+    return (2 * _B((M, W), "bfloat16")             # q tile stream
+            + _B((npg, ps, W), "bfloat16")         # the chunk, page-shaped
+            + _B((npg, ps, 1), "float32")          # row mask
+            + 2 * _B((M, R), "float32")            # out tile stream
+            + _B((npg, ps, W), "bfloat16")         # page RMW scratch
+            + _B((2, cpk, ps, W), "bfloat16")      # walk double buffer
+            + 2 * _B((M, 1), "float32")            # running max, sum
+            + _B((M, R), "float32"))               # accumulator
+
+
+def _build_mla_paged_decode():
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional.mla_attention import mla_decode_attend
+
+    H, W, R, ps, P, pp, S = (_MLA[k] for k in
+                             ("H", "W", "R", "ps", "P", "pp", "S"))
+
+    def fn(q, rows, pool, tables, lens):
+        return mla_decode_attend(q, rows, pool, tables, lens, 256,
+                                 v_width=R)
+
+    return fn, (_sds((S, H, W), jnp.bfloat16), _sds((S, W), jnp.bfloat16),
+                _sds((P, ps, W), jnp.bfloat16), _sds((S, pp), jnp.int32),
+                _sds((S,), jnp.int32))
+
+
+def _expected_mla_paged_decode():
+    # a grid step a sequence: its 32 query rows, its new row, its slot
+    # selector and its output stream; the walk gathers 64 pages (1024
+    # tokens) a step into one half of a double buffer
+    H, W, R, ps = (_MLA[k] for k in ("H", "W", "R", "ps"))
+    cpk = 64
+    return (2 * _B((1, H, W), "bfloat16")          # q rows
+            + 2 * _B((1, 1, W), "float32")         # the new row
+            + 2 * _B((1, ps, 1), "float32")        # slot selector
+            + 2 * _B((1, H, R), "float32")         # out rows
+            + _B((ps, W), "bfloat16")              # page RMW scratch
+            + _B((2, cpk, ps, W), "bfloat16"))     # walk double buffer
+
+
 KERNEL_SITES: List[KernelSite] = [
     KernelSite("stream_linear.bf16", "nn/functional/stream_linear.py",
                _build_stream_linear, _expected_stream_linear),
@@ -672,6 +744,13 @@ KERNEL_SITES: List[KernelSite] = [
                _build_ssm_decode_update, _expected_ssm_decode_update),
     KernelSite("moe.stream_experts", "nn/functional/moe_gated.py",
                _build_moe_stream_experts, _expected_moe_stream_experts),
+    # latent attention over the paged latent pool (ISSUE 37): a prefill
+    # chunk written in place and attended over its prefix, and the
+    # one-token append + attend, both in the absorbed form
+    KernelSite("mla.paged_prefill", "nn/functional/mla_attention.py",
+               _build_mla_paged_prefill, _expected_mla_paged_prefill),
+    KernelSite("mla.paged_decode", "nn/functional/mla_attention.py",
+               _build_mla_paged_decode, _expected_mla_paged_decode),
 ]
 
 

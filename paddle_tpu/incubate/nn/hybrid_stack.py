@@ -1,18 +1,27 @@
 """A served stack BUILT from a ``LayerPattern``: a period of layer kinds
-(Mamba-2 mixers, NoPE / rotary GQA attention), each followed by an FFN
-of routed gated experts plus a shared gated MLP, RMSNorm, no biases,
-residual / attention multipliers from the description.
+(Mamba-2 mixers, NoPE / rotary GQA attention, multi-head latent
+attention), each followed by an FFN of routed gated experts plus a
+shared gated MLP, RMSNorm, no biases, residual / attention multipliers
+from the description.
 
     h = h + r * Mixer(RMSNorm(h))
     h = h + r * (MoE(x) + SharedMLP(x)),   x = RMSNorm(h)
 
-A weight stack a kind (``m_*`` the mamba layers, ``qkv_weight`` /
-``out_weight`` / ``a_norm`` the attention layers, ``f_*`` / ``e_*`` /
-``s_*`` the FFN of every layer), a cache group a kind: the attention
-layers share the serving engine's paged pool (layer-folded over
-``n_attention`` layers, touched only by the three paged Pallas kernels
-``FusedMultiTransformer`` uses), the mamba layers a slot-indexed
-``RecurrentState`` that the decode program updates in place.
+A weight stack a kind, a cache group a kind:
+
+``"mamba"``             ``m_*``; a slot-indexed ``RecurrentState`` that
+    the decode program updates in place.
+``"attention"``         ``qkv_weight`` / ``out_weight`` / ``a_norm``; the
+    serving engine's paged pool ``PagedKV`` (layer-folded over
+    ``n_attention`` layers, touched only by the three paged Pallas
+    kernels ``FusedMultiTransformer`` uses).
+``"latent_attention"``  ``l_*`` (down / up projections of the queries,
+    the latent + rope-key projection padded to the pool's row, the
+    per-head ``W_uk`` / ``W_uv`` the absorbed form contracts with); the
+    paged latent pool ``LatentKV`` (one row a token and layer, touched
+    only by ``pt_mla_paged_prefill`` / ``pt_mla_paged_decode``).
+
+``f_*`` / ``e_*`` / ``s_*`` are the FFN of every layer.
 
 The two raw phases mirror ``FusedMultiTransformer``'s:
 
@@ -39,8 +48,9 @@ import jax
 import jax.numpy as jnp
 
 from ...nn.layer_base import Layer
+from ...nn.functional.mla_attention import LatentKV
 from .fused_transformer import PagedKV, _apply_rope
-from .layer_pattern import MAMBA, LayerPattern
+from .layer_pattern import LATENT, MAMBA, LayerPattern
 
 __all__ = ["HybridStack", "RecurrentState"]
 
@@ -71,7 +81,9 @@ class HybridStack(Layer):
             raise NotImplementedError(
                 "HybridStack serves RMSNorm, bias-free, SiLU-gated "
                 "routed-expert blocks; the LayerNorm / biased GELU "
-                "one-kind pattern is FusedMultiTransformer's")
+                "one-kind pattern is FusedMultiTransformer's (kinds a "
+                "period may mix here: mamba, attention, "
+                "latent_attention)")
         self.pattern = pattern
         self.embed_dim = pattern.d_model
         self.num_layers = pattern.num_layers
@@ -97,7 +109,26 @@ class HybridStack(Layer):
             mk(name, arr)
             self._names.append(name)
 
-        Lm, La = pattern.n_mamba, pattern.n_attention
+        Lm, La, Ll = (pattern.n_mamba, pattern.n_attention,
+                      pattern.n_latent)
+        if Ll:
+            lt = pattern.latent
+            H, R = lt.num_heads, lt.kv_lora_rank
+            add("l_norm", ones(Ll, d))
+            add("l_dq", normal(Ll, d, lt.q_lora_rank))
+            add("l_qnorm", ones(Ll, lt.q_lora_rank))
+            add("l_uq", normal(Ll, lt.q_lora_rank,
+                               H * (lt.qk_nope_head_dim
+                                    + lt.qk_rope_head_dim)))
+            # [latent | rope key | 0]: the projection lands on the
+            # pool's row, pad lanes included
+            add("l_dkv", jnp.pad(
+                normal(Ll, d, lt.row_used),
+                ((0, 0), (0, 0), (0, lt.row_width - lt.row_used))))
+            add("l_kvnorm", ones(Ll, R))
+            add("l_uk", normal(Ll, H, lt.qk_nope_head_dim, R))
+            add("l_uv", normal(Ll, H, R, lt.v_head_dim))
+            add("l_o", normal(Ll, H * lt.v_head_dim, d))
         if Lm:
             m = pattern.mamba
             add("m_norm", ones(Lm, d))
@@ -205,16 +236,75 @@ class HybridStack(Layer):
         return self._residual(
             h, self._proj(g, w["m_out"], lm, rows_are_decode))
 
+    def _latent_inputs(self, w, li, h, positions, cos_t, sin_t, stream):
+        """Rows ``h [T, d]`` at ``positions [T]`` of latent layer ``li``
+        -> ``(q [T, H, W]`` the absorbed queries, scale and temperature
+        folded in, over the lanes of a cache row; ``rows [T, W]`` the
+        tokens' cache rows ``[normed latent | rotated rope key | 0]``)."""
+        from ...nn.functional.mla_attention import (query_temperature,
+                                                    rope_interleaved)
+
+        p = self.pattern
+        lt = p.latent
+        H, n, r = lt.num_heads, lt.qk_nope_head_dim, lt.qk_rope_head_dim
+        R, W = lt.kv_lora_rank, lt.row_width
+        T = h.shape[0]
+        hn = self._rms(h, w["l_norm"][li], p.epsilon).astype(h.dtype)
+        cq = self._rms(self._proj(hn, w["l_dq"], li, stream),
+                       w["l_qnorm"][li], p.epsilon).astype(h.dtype)
+        qf = self._proj(cq, w["l_uq"], li, stream).reshape(T, H, n + r)
+        ckv = self._proj(hn, w["l_dkv"], li, stream)          # [T, W]
+        cos, sin = cos_t[positions], sin_t[positions]         # [T, r/2]
+        pad = jnp.zeros((T, W - R - r), jnp.float32)
+        rows = jnp.concatenate(
+            [self._rms(ckv[:, :R], w["l_kvnorm"][li], p.epsilon),
+             rope_interleaved(ckv[:, R: R + r], cos, sin), pad], -1)
+        q_abs = jnp.einsum("thn,hnr->thr", qf[..., :n].astype(h.dtype),
+                           w["l_uk"][li],
+                           preferred_element_type=jnp.float32)
+        q_rope = rope_interleaved(qf[..., n:], cos[:, None], sin[:, None])
+        temp = lt.softmax_scale * query_temperature(
+            positions, lt.temperature_beta, lt.temperature_period)
+        q = jnp.concatenate(
+            [q_abs, q_rope, jnp.broadcast_to(pad[:, None], (T, H, W - R - r))],
+            -1) * temp[:, None, None]
+        return q.astype(h.dtype), rows.astype(h.dtype)
+
+    def _latent_finish(self, w, li, h, o, stream):
+        """``o [T, H, kv_rank]`` float32, the attended latents -> the
+        residual stream: per-head ``W_uv``, then ``W_o``."""
+        att = jnp.einsum("thr,hrv->thv", o.astype(h.dtype), w["l_uv"][li],
+                         preferred_element_type=jnp.float32)
+        att = att.reshape(h.shape[0], -1).astype(h.dtype)
+        return self._residual(h, self._proj(att, w["l_o"], li, stream))
+
+    @staticmethod
+    def _split_cache(cache):
+        """``(latent pool | None, K side | None, V side | None)``."""
+        if isinstance(cache, LatentKV):
+            return cache.rows, None, None
+        if cache is None:
+            return None, None, None
+        return None, cache.k, cache.v
+
+    @staticmethod
+    def _join_cache(pool, ck, cv):
+        if pool is not None:
+            return LatentKV(pool)
+        return PagedKV(ck, cv) if ck is not None else None
+
     # ------------------------------------------------------- prefill
 
     def prefill_chunk_raw(self, weights, x, cache, state, block_tables,
                           start, chunk_lens, cos_t=None, sin_t=None):
-        """x ``[1, c, d]``; ``cache`` the paged pool (PagedKV) of the
-        attention layers; ``state`` this sequence's recurrent arrays
+        """x ``[1, c, d]``; ``cache`` the paged pool of the attention
+        layers (``PagedKV``) or of the latent-attention layers
+        (``LatentKV``); ``state`` this sequence's recurrent arrays
         (``ssm [Lm, N, d_inner]`` float32, ``conv [Lm, k-1, conv_dim]``)
         as they stood before the chunk. Returns ``(hidden [1, c, d],
         cache', state', counts int32 [6])``."""
         from ...nn.functional.flash_varlen import paged_prefill_attention
+        from ...nn.functional.mla_attention import mla_prefill_attend
         from ...nn.functional.paged_attention import (
             write_prefill_kv_inplace)
         from ...nn.functional.ssm import (causal_conv1d_chunk,
@@ -230,10 +320,11 @@ class HybridStack(Layer):
         chunk_lens = chunk_lens.astype(jnp.int32)
         valid = jnp.arange(c, dtype=jnp.int32) < chunk_lens[0]   # [c]
         positions = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None]
-        ck, cv = (cache.k, cache.v) if cache is not None else (None, None)
+        pool, ck, cv = self._split_cache(cache)
         ssm, conv = state if state is not None else (None, None)
-        npages = ck.shape[0] // max(p.n_attention, 1) \
-            if ck is not None else 0
+        paged = pool if pool is not None else ck
+        npages = paged.shape[0] // max(p.n_paged, 1) \
+            if paged is not None else 0
         counts = jnp.zeros((6,), jnp.int32)
         h = x[0]
         for l, kind in enumerate(p.kinds()):
@@ -259,6 +350,13 @@ class HybridStack(Layer):
                 ssm = ssm.at[li].set(s_new)
                 conv = conv.at[li].set(tail[0])
                 h = self._mamba_finish(w, li, h, y, z, False)
+            elif kind == LATENT:
+                q, rows = self._latent_inputs(w, li, h, positions[0],
+                                              cos_t, sin_t, False)
+                o, pool = mla_prefill_attend(
+                    q, rows, pool, block_tables + li * npages, start,
+                    chunk_lens, v_width=p.latent.kv_lora_rank)
+                h = self._latent_finish(w, li, h, o, False)
             else:
                 att = p.attention
                 hn = self._rms(h, w["a_norm"][li], p.epsilon) \
@@ -285,9 +383,8 @@ class HybridStack(Layer):
         # (is the expert stream's time free of the data?): a prefill
         # chunk reports its picks and its grouped GEMMs' units alone
         counts = counts * jnp.asarray([1, 1, 0, 0, 1, 1], jnp.int32)
-        cache2 = PagedKV(ck, cv) if ck is not None else None
         state2 = (ssm, conv) if ssm is not None else None
-        return h[None], cache2, state2, counts
+        return h[None], self._join_cache(pool, ck, cv), state2, counts
 
     # -------------------------------------------------------- decode
 
@@ -299,6 +396,7 @@ class HybridStack(Layer):
         by ``pt_ssm_decode_update``). Returns ``(hidden, cache',
         state', counts int32 [4])``."""
         from ...device import chip as _chip
+        from ...nn.functional.mla_attention import mla_decode_attend
         from ...nn.functional.paged_attention import (
             decode_attend, plan_decode_attention)
         from ...nn.functional.ssm import (causal_conv1d_step,
@@ -309,10 +407,11 @@ class HybridStack(Layer):
         S = x.shape[0]
         stream = _chip.on_tpu() and S % 8 == 0
         seq_lens = seq_lens.astype(jnp.int32)
-        ck, cv = (cache.k, cache.v) if cache is not None else (None, None)
+        pool, ck, cv = self._split_cache(cache)
         ssm, conv = state if state is not None else (None, None)
-        npages = ck.shape[0] // max(p.n_attention, 1) \
-            if ck is not None else 0
+        paged = pool if pool is not None else ck
+        npages = paged.shape[0] // max(p.n_paged, 1) \
+            if paged is not None else 0
         # layer-independent: built once a step, shared by the layers
         plan = plan_decode_attention(ck, block_tables, seq_lens, npages) \
             if ck is not None else None
@@ -341,6 +440,13 @@ class HybridStack(Layer):
                 ssm, y = ssm_decode_update(ssm, li, decay, dtx, Bm, Cm)
                 y = y + expand_heads(w["m_D"][li], m.head_dim)[None] * u
                 h = self._mamba_finish(w, li, h, y, z, stream)
+            elif kind == LATENT:
+                q, rows = self._latent_inputs(w, li, h, seq_lens, cos_t,
+                                              sin_t, stream)
+                o, pool = mla_decode_attend(
+                    q, rows, pool, block_tables, seq_lens, li * npages,
+                    v_width=p.latent.kv_lora_rank)
+                h = self._latent_finish(w, li, h, o, stream)
             else:
                 att = p.attention
                 nq, nkv, hd = att.num_heads, att.num_kv_heads, att.head_dim
@@ -360,6 +466,5 @@ class HybridStack(Layer):
                 h = self._residual(
                     h, self._proj(o, w["out_weight"], li, stream))
             h, counts = self._ffn(w, h, l, active, True, counts)
-        cache2 = PagedKV(ck, cv) if ck is not None else None
         state2 = RecurrentState(ssm, conv) if ssm is not None else None
-        return h, cache2, state2, counts
+        return h, self._join_cache(pool, ck, cv), state2, counts

@@ -21,6 +21,7 @@ import numpy as np
 from ..core.tensor import Tensor
 from ..incubate.nn.fused_transformer import (
     FusedMultiTransformer, PagedKV, rope_table)
+from ..incubate.nn.layer_pattern import LATENT
 from ..nn.layer_base import Layer
 from ..profiler import RecordEvent
 from ..profiler import roofline as _roofline
@@ -29,7 +30,8 @@ from .kv_cache import BlockKVCacheManager, gather_rows, restore_scatter_jit
 
 __all__ = ["FusedCausalLM", "GenerationEngine",
            "ContinuousBatchingEngine", "GenRequest",
-           "DEFAULT_DECODE_CHUNK", "RecurrentStateUnsupported"]
+           "DEFAULT_DECODE_CHUNK", "RecurrentStateUnsupported",
+           "LatentPoolUnsupported"]
 
 
 class RecurrentStateUnsupported(NotImplementedError):
@@ -45,6 +47,24 @@ def _refuse_recurrent(what: str):
     raise RecurrentStateUnsupported(
         f"{what} moves K/V pages only; this model's recurrent layers "
         "keep slot-indexed state that it would leave behind")
+
+
+
+class LatentPoolUnsupported(NotImplementedError):
+    """A path that moves, shares or re-types K/V pages was asked of a model
+    whose attention layers keep ONE latent row a token in the pool
+    (``LatentKV``): prefix reuse, host-tier spill, slot export / import,
+    page streaming, speculative verify, the int8 pool. None of them has
+    been shown on latent pages, so each refuses instead of guessing.
+    Counted in ``serving.latent.refusals``."""
+
+
+def _refuse_latent(what: str):
+    _stats.inc("serving.latent.refusals")
+    raise LatentPoolUnsupported(
+        f"{what} is written for K and V pages side by side; this model's "
+        "pool holds one latent row a token and nothing here has been "
+        "shown to move or share those")
 
 #: auto-picked decode scan-chunk: 128 measured best on the 1.3B bench
 #: geometry (chunk 64 -> 128: +7% tok/s, bench_profile.json r5 — one
@@ -686,33 +706,43 @@ class ContinuousBatchingEngine:
         pattern = st.pattern
         recurrent = pattern.recurrent
         att = pattern.attention
-        if att is None:
+        if pattern.paged_kind is None:
             raise NotImplementedError(
-                "a pattern without attention layers has no paged pool: "
-                "the engines page every sequence")
+                "a pattern without attention or latent_attention layers "
+                "has no paged pool: the engines page every sequence")
+        # a pattern-built model's programs take the recurrent state (or
+        # None) after the pool and return pick counts beside their result
+        self._pattern_built = getattr(gen_cls, "pattern_built", False)
+        self._latent = pattern.paged_kind == LATENT
+        if self._latent and (kv_dtype == "int8" or kv_dtype == jnp.int8):
+            _refuse_latent("the int8 cache-KV pool")
         self._pages_per_seq = -(-self.max_length // self.page_size)
         requested = (num_pages or self.max_batch * self._pages_per_seq) + 1
         tp = self._gen._tp
+        heads, width = (1, pattern.latent.row_width) if self._latent \
+            else (att.num_kv_heads, att.head_dim)
         self._mgr = BlockKVCacheManager(
-            pattern.n_attention, att.num_kv_heads, att.head_dim,
-            self.page_size,
+            pattern.n_paged, heads, width, self.page_size,
             num_pages=_round_pool_pages(requested, self.page_size),
             dtype=self._gen._kv_dtype, reserve_scratch=True,
             mp_degree=tp.mp if tp else 1,
             mesh=tp.mesh if tp else None,
-            recurrent=recurrent, slots=self.max_batch)
+            recurrent=recurrent, slots=self.max_batch,
+            latent=self._latent)
         _stats.set_gauge("serving.pool_pages_requested", requested)
         _stats.set_gauge("serving.pool_pages", self._mgr.num_pages)
         cache = self._mgr.fresh_cache()
-        self._ck, self._cv = cache.k, cache.v
-        # None on a model without recurrent layers: the programs then
-        # take and return the pool's two sides alone
+        # the pool's two operands: K and V sides, or the one latent pool
+        # and None
+        self._ck, self._cv = (cache.rows, None) if self._latent \
+            else (cache.k, cache.v)
+        # None on a model without recurrent layers
         self._rs = self._mgr.fresh_recurrent_state(self._gen._cdtype)
-        if recurrent is None:
+        if not self._pattern_built:
             self._cos, self._sin = rope_table(
                 st.max_position, st.head_dim, st.rope_theta)
             self._gen._cos, self._gen._sin = self._cos, self._sin
-        else:
+        elif recurrent is not None:
             _stats.set_gauge("serving.recurrent.state_bytes",
                              recurrent.bytes_per_slot() * self.max_batch)
         self._gen._mgr = self._mgr
@@ -755,6 +785,8 @@ class ContinuousBatchingEngine:
         if speculative and recurrent is not None:
             _refuse_recurrent("speculative verify (rejected drafts roll "
                               "the page table back)")
+        if speculative and self._latent:
+            _refuse_latent("speculative verify")
         if speculative:
             from .speculative import build_speculative_decoder
 
@@ -805,7 +837,7 @@ class ContinuousBatchingEngine:
         sides and, on a model with recurrent layers, the slot-indexed
         state) is donated and rebound; returns the program's first
         result."""
-        if self._rs is None:
+        if not self._pattern_built:
             out, self._ck, self._cv = program(
                 *lead, self._ck, self._cv, *tail)
         else:
@@ -818,7 +850,7 @@ class ContinuousBatchingEngine:
         returns its expert layers' pick counts beside it (a prefill
         chunk also its grouped GEMMs' work units): both come in the one
         fetch and the counts go to the ``serving.moe.*`` counters."""
-        if self._rs is None:
+        if not self._pattern_built:
             return np.asarray(out)
         first, counts = jax.device_get(out)
         for name, n in zip(("picks", "picks_here", "experts_hit",
@@ -886,6 +918,14 @@ class ContinuousBatchingEngine:
                        int(named.sum()) * layers)
             _stats.inc("serving.kv.pages_region",
                        self._mgr.num_pages * layers * k)
+            if self._latent:
+                # latent rows the decode kernel reads in this chunk, a
+                # step and layer: every cached token of every decoding
+                # row, once for all heads
+                rows = (cur[:, None] + np.arange(k)[None, :])[
+                    [r is not None for r in self._slots]]
+                _stats.inc("serving.mla.rows_read",
+                           int(rows.sum()) * layers)
         lnf_s, lnf_b = self._gen._lnf()
         a_slots, a_banks = self._adapter_operands(active)
         adaptered = a_banks is not None
@@ -899,7 +939,7 @@ class ContinuousBatchingEngine:
                 self._gen._head_t, lnf_s, lnf_b,
                 jnp.asarray(self._last_tok, jnp.int32),
                 jnp.asarray(cur, jnp.int32))
-        if self._rs is not None:
+        if self._pattern_built:
             # rows that decode; the others (idle slots, slots whose
             # prompt is still prefilling) keep pool and state
             extra = (jnp.asarray([r is not None for r in self._slots]),)
@@ -1024,13 +1064,23 @@ class ContinuousBatchingEngine:
         pad = np.repeat(a[tuple(idx)], b - n, axis=axis)
         return np.concatenate([a, pad], axis=axis)
 
+    def _needs_kv_pages(self, what: str):
+        """Refuse ``what`` by type where K/V pages are not the whole of a
+        sequence's cached state (recurrent layers) or the pool holds no K/V
+        pages at all (a latent pool)."""
+        if self._rs is not None:
+            _refuse_recurrent(what)
+        if self._latent:
+            _refuse_latent(what)
+
     def can_migrate(self) -> bool:
         """Page-granular KV export/import is supported for plain
         (unsharded, non-int8) pools; int8 cache-KV carries scale
         planes and TP pools shard by kv-head — both fall back to the
         preemption-by-recompute path on a fleet drain."""
         return not isinstance(self._ck, tuple) \
-            and self._mgr._mesh is None and self._rs is None
+            and self._mgr._mesh is None and self._rs is None \
+            and not self._latent
 
     def export_slot(self, i: int) -> dict:
         """Export decode slot ``i``'s live state for page-granular
@@ -1040,8 +1090,7 @@ class ContinuousBatchingEngine:
         ``BlockKVCacheManager.phys_rows``). Pages are NOT freed here;
         the caller releases the slot only after the import lands, so
         a failed migration leaves this engine untouched."""
-        if self._rs is not None:
-            _refuse_recurrent("slot export")
+        self._needs_kv_pages("slot export")
         if not self.can_migrate():
             raise NotImplementedError(
                 "KV-page migration needs a plain pool (no int8 "
@@ -1068,8 +1117,7 @@ class ContinuousBatchingEngine:
         replicated weights) are byte-identical. False when the slot is
         occupied or the pool can't cover the pages (the caller falls
         back to recompute)."""
-        if self._rs is not None:
-            _refuse_recurrent("slot import")
+        self._needs_kv_pages("slot import")
         if not self.can_migrate():
             raise NotImplementedError(
                 "KV-page migration needs a plain pool (no int8 "
@@ -1113,6 +1161,7 @@ class ContinuousBatchingEngine:
         host memory. Lock-free for complete pages: the pool arrays are
         functional (decode steps REPLACE them), so a snapshot reference
         carries byte-identical rows for any already-complete page."""
+        self._needs_kv_pages("page streaming (export_pages)")
         if not self.can_migrate():
             raise NotImplementedError(
                 "KV-page migration needs a plain pool (no int8 "
@@ -1132,6 +1181,7 @@ class ContinuousBatchingEngine:
         picked at ``import_finish``). Returns an opaque ticket, or
         None when the pool can't cover the reservation. Call under
         this engine's step lock."""
+        self._needs_kv_pages("page streaming (import_begin)")
         if not self.can_migrate():
             raise NotImplementedError(
                 "KV-page migration needs a plain pool (no int8 "
@@ -1212,7 +1262,8 @@ class ContinuousBatchingEngine:
         """Host-DRAM spill/restore supports plain AND int8 pools; only
         TP kv-head-sharded pools fall back (a one-shard blob could not
         restore into a differently-sharded peer pool)."""
-        return self._mgr._mesh is None and self._rs is None
+        return self._mgr._mesh is None and self._rs is None \
+            and not self._latent
 
     def _scale_cols(self, rows_np: np.ndarray) -> np.ndarray:
         """Scale-plane columns of the given pool rows: row r position t
@@ -1227,8 +1278,7 @@ class ContinuousBatchingEngine:
         layer-major page-inner layout per ``phys_rows``, so the blob
         scatters back via ``import_kv_pages`` on any engine with the
         same geometry. int8 pools add the per-token scale columns."""
-        if self._rs is not None:
-            _refuse_recurrent("host-tier page spill")
+        self._needs_kv_pages("host-tier page spill")
         if not self.can_spill():
             raise NotImplementedError(
                 "host-tier KV spill needs an unsharded pool — TP "
@@ -1254,6 +1304,7 @@ class ContinuousBatchingEngine:
         pages (the restore half — ``kv_cache.restore_scatter``, the
         donated ``serve.kv_restore`` program). Swaps the functional
         pool arrays; call from the step thread / under the step lock."""
+        self._needs_kv_pages("host-tier page restore")
 
         rows_np = self._mgr.phys_rows(list(pages))
         nr = len(rows_np)
@@ -1392,10 +1443,11 @@ class ContinuousBatchingEngine:
         ``i``. (The serving frontend overrides this with chunked
         prefill: the prompt fills in fixed-size chunks interleaved with
         decode steps instead of one monolithic program.)"""
-        if self._rs is not None:
+        if self._pattern_built:
             raise NotImplementedError(
-                "a model with recurrent layers prefills in chunks that "
-                "carry its state: serve it through "
+                "a pattern-built model (mamba, attention or "
+                "latent_attention layers) prefills in chunks that carry "
+                "its state and write its pool: serve it through "
                 "paddle_tpu.serving.ServingEngine")
         self._slots[i] = req
         _stats.inc("serving.admitted")

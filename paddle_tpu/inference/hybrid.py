@@ -2,7 +2,8 @@
 
 ``HybridCausalLM`` is ``FusedCausalLM``'s sibling: token embedding
 (times ``embedding_multiplier``), the pattern-built stack, a final
-RMSNorm, the head tied to the embedding (logits divided by
+RMSNorm, the head tied to the embedding or (``tie_embeddings`` false in
+the pattern) a matrix of its own (logits divided by
 ``logits_scaling``). It goes behind the SAME engines
 (``ContinuousBatchingEngine`` / ``serving.ServingEngine``): the engines
 ask the model for its program family (``_gen_cls``) and otherwise treat
@@ -11,7 +12,10 @@ admit / plan / run / emit framing of a step.
 
 ``HybridPrograms`` is that family: the chunked-prefill and decode-chunk
 programs with one more donated operand, the slot-indexed
-``RecurrentState``, which both return rebound. Their XLA module names
+``RecurrentState`` (None where the pattern has no recurrent layer),
+which both return rebound; the pool's two operands hold ``PagedKV``'s K
+and V sides or, for a latent-attention pattern, the ONE latent pool and
+None. Their XLA module names
 are fixed here (``PREFILL_PROGRAM_NAME`` / ``DECODE_PROGRAM_NAME``), not
 taken from whatever the Python methods happen to be called, so a trace
 reader's pattern survives a rename.
@@ -25,7 +29,8 @@ import jax.numpy as jnp
 
 from ..incubate.nn.fused_transformer import PagedKV, rope_table
 from ..incubate.nn.hybrid_stack import HybridStack, RecurrentState
-from ..incubate.nn.layer_pattern import LayerPattern
+from ..incubate.nn.layer_pattern import LATENT, LayerPattern
+from ..nn.functional.mla_attention import LatentKV, yarn_rope_table
 from ..nn.layer_base import Layer
 from ..profiler import roofline as _roofline
 from .engine import GenerationEngine
@@ -54,6 +59,10 @@ class HybridPrograms(GenerationEngine):
     ``_gen``). Same operand order as ``GenerationEngine``'s programs with
     the recurrent state after the pool's two sides."""
 
+    #: the engines hand these programs the recurrent state (or None)
+    #: after the pool and read pick counts beside their first result
+    pattern_built = True
+
     def _init_serving_state(self, kv_dtype, quant=None, mesh=None,
                             mp_degree=None, ep_degree=None):
         if quant is not None or mesh is not None or mp_degree \
@@ -68,17 +77,33 @@ class HybridPrograms(GenerationEngine):
         self._a8w8 = False
         self._cdtype = st.e_w1._data.dtype
         self._kv_dtype = kv_dtype or self._cdtype
-        # the head is the embedding itself, contracted over d_model: a
-        # transposed copy would cost the table's bytes again
-        self._head_t = self.model.embed._data
+        # the head ``[vocab, d]`` is contracted over d_model: a
+        # transposed copy would cost the table's bytes again; tied, it is
+        # the embedding itself
+        head = getattr(self.model, "head", None)
+        self._head_t = (head if head is not None
+                        else self.model.embed)._data
         self._decode_tag = "decode.hybrid"
         self._decode_k_jit = {}
-        att = st.pattern.attention
-        if att is not None and att.rope_theta is not None:
+        pat = st.pattern
+        self._latent = pat.paged_kind == LATENT
+        att = pat.attention
+        if self._latent:
+            lt = pat.latent
+            self._cos, self._sin = yarn_rope_table(
+                self.max_length + 1, lt.qk_rope_head_dim, lt.rope_theta,
+                lt.yarn)
+        elif att is not None and att.rope_theta is not None:
             self._cos, self._sin = rope_table(
                 self.max_length + 1, att.head_dim, att.rope_theta)
         else:
             self._cos = self._sin = None
+
+    def _cache(self, ck, cv):
+        return LatentKV(ck) if self._latent else PagedKV(ck, cv)
+
+    def _sides(self, cache):
+        return (cache.rows, None) if self._latent else (cache.k, cache.v)
 
     def _weights(self):
         return self.model.stack._stack()
@@ -132,20 +157,24 @@ class HybridPrograms(GenerationEngine):
         last valid row (meaningful on a prompt's final chunk)."""
         st = self.model.stack
         s = slot[0]
-        ssm = jax.lax.dynamic_index_in_dim(rs.ssm, s, 1, False)
-        conv = jax.lax.dynamic_index_in_dim(rs.conv, s, 1, False)
-        ssm = jnp.where(fresh[0], jnp.zeros_like(ssm), ssm)
-        conv = jnp.where(fresh[0], jnp.zeros_like(conv), conv)
+        state = None
+        if rs is not None:
+            ssm = jax.lax.dynamic_index_in_dim(rs.ssm, s, 1, False)
+            conv = jax.lax.dynamic_index_in_dim(rs.conv, s, 1, False)
+            state = (jnp.where(fresh[0], jnp.zeros_like(ssm), ssm),
+                     jnp.where(fresh[0], jnp.zeros_like(conv), conv))
         x = self._embed_rows(embed, ids)
-        h, cache, (ssm, conv), counts = st.prefill_chunk_raw(
-            weights, x, PagedKV(ck, cv), (ssm, conv), tables, start,
+        h, cache, state, counts = st.prefill_chunk_raw(
+            weights, x, self._cache(ck, cv), state, tables, start,
             chunk_len, self._cos, self._sin)
-        rs = RecurrentState(
-            jax.lax.dynamic_update_index_in_dim(rs.ssm, ssm, s, 1),
-            jax.lax.dynamic_update_index_in_dim(rs.conv, conv, s, 1))
+        if rs is not None:
+            rs = RecurrentState(
+                jax.lax.dynamic_update_index_in_dim(rs.ssm, state[0], s, 1),
+                jax.lax.dynamic_update_index_in_dim(rs.conv, state[1], s,
+                                                    1))
         hl = h[jnp.arange(h.shape[0]), chunk_len - 1]
         tok = self._argmax(self._logits(hl, head, norm_s))
-        return (tok, counts), cache.k, cache.v, rs
+        return (tok, counts), *self._sides(cache), rs
 
     def _decode_k_fn(self, weights, embed, head, norm_s, _nb, tok,
                      seq_lens, ck, cv, rs, tables, active, *, k):
@@ -155,21 +184,19 @@ class HybridPrograms(GenerationEngine):
         st = self.model.stack
 
         def step(carry, _):
-            tok, lens, ck, cv, ssm, conv, counts = carry
+            tok, lens, ck, cv, rs, counts = carry
             x = self._embed_rows(embed, tok)
-            h, cache, state, c = st.decode_raw(
-                weights, x, PagedKV(ck, cv), RecurrentState(ssm, conv),
-                tables, lens, active, self._cos, self._sin)
+            h, cache, rs, c = st.decode_raw(
+                weights, x, self._cache(ck, cv), rs, tables, lens, active,
+                self._cos, self._sin)
             nxt = self._argmax(self._logits(h, head, norm_s))
-            return (nxt, lens + 1, cache.k, cache.v, state.ssm,
-                    state.conv, counts + c), nxt
+            return (nxt, lens + 1, *self._sides(cache), rs,
+                    counts + c), nxt
 
-        init = (tok, seq_lens, ck, cv, rs.ssm, rs.conv,
-                jnp.zeros((4,), jnp.int32))
-        (_, _, ck, cv, ssm, conv, counts), toks = jax.lax.scan(
+        init = (tok, seq_lens, ck, cv, rs, jnp.zeros((4,), jnp.int32))
+        (_, _, ck, cv, rs, counts), toks = jax.lax.scan(
             step, init, None, length=k)
-        return (jnp.swapaxes(toks, 0, 1), counts), ck, cv, \
-            RecurrentState(ssm, conv)
+        return (jnp.swapaxes(toks, 0, 1), counts), ck, cv, rs
 
     def generate(self, *a, **kw):
         raise NotImplementedError(
@@ -178,7 +205,9 @@ class HybridPrograms(GenerationEngine):
 
 
 class HybridCausalLM(Layer):
-    """Token embedding (tied head) + ``HybridStack`` + final RMSNorm."""
+    """Token embedding + ``HybridStack`` + final RMSNorm + the head (the
+    embedding itself, or ``head [vocab, d]`` where the pattern says
+    ``tie_embeddings=False``)."""
 
     #: the program family the engines build for this model
     _gen_cls = HybridPrograms
@@ -195,6 +224,10 @@ class HybridCausalLM(Layer):
         self.embed = Parameter(_draw(
             default_generator().next_key(),
             (vocab_size, pattern.d_model), jnp.dtype(dtype), 0.02))
+        if not pattern.tie_embeddings:
+            self.head = Parameter(_draw(
+                default_generator().next_key(),
+                (vocab_size, pattern.d_model), jnp.dtype(dtype), 0.02))
         self.stack = HybridStack(pattern, dtype=dtype)
         self.norm_scale = Parameter(
             jnp.ones((pattern.d_model,), jnp.float32))

@@ -10,6 +10,13 @@ contiguous head-major block — see nn/functional/paged_attention.py
 layout notes);
 the manager hands out LOGICAL page ids from a free list so sequences of
 different lengths share one pool with no copies.
+
+``latent=True`` is the pool of latent-attention layers: ONE array
+``[num_layers * num_pages, page_size, head_dim]`` (``LatentKV``; a row
+is the normed latent, the rope key and the pad to whole lane tiles),
+never K and V side by side. Allocation, growth, refcounts and block
+tables do not know the difference; the paths that move page CONTENTS
+(``phys_rows`` users) are the engines' and refuse a latent pool by type.
 """
 from __future__ import annotations
 
@@ -20,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..incubate.nn.fused_transformer import PagedKV
+from ..nn.functional.mla_attention import LatentKV
 
 __all__ = ["BlockKVCacheManager", "restore_scatter",
            "restore_scatter_jit", "gather_rows"]
@@ -69,8 +77,10 @@ class BlockKVCacheManager:
                  page_size: int = 16, num_pages: int = 512,
                  dtype=jnp.float32, reserve_scratch: bool = False,
                  mp_degree: int = 1, mesh=None, mp_axis: str = "mp",
-                 recurrent=None, slots: int = 0):
+                 recurrent=None, slots: int = 0, latent: bool = False):
         self.num_layers = num_layers
+        # one array family a token row (``LatentKV``) instead of K and V
+        self.latent = bool(latent)
         # recurrent layers (a ``LayerPattern.recurrent`` spec): their
         # state is indexed by decode SLOT, not by page — allocated with
         # the slot, zeroed at admission, carried from prefill chunk to
@@ -114,6 +124,11 @@ class BlockKVCacheManager:
             self.kv_heads_per_shard = num_kv_heads
             self.kv_replication = 1
         self._pool_heads = self.kv_heads_per_shard * self.mp_degree
+        if self.latent and (self._mesh is not None or self.dtype == "int8"
+                            or self.dtype == jnp.int8):
+            raise NotImplementedError(
+                "a latent pool is served unsharded in bf16 / f32: its row "
+                "has no kv-head axis to shard and no int8 kernel")
         if self._mesh is not None and \
                 (self.dtype == "int8" or self.dtype == jnp.int8):
             raise NotImplementedError(
@@ -132,7 +147,11 @@ class BlockKVCacheManager:
         # and a retry is clean (one attribute test when disabled)
         self._faults = None
 
-    def fresh_cache(self) -> PagedKV:
+    def fresh_cache(self):
+        if self.latent:
+            return LatentKV(jnp.zeros(
+                (self.num_layers * self.num_pages, self.page_size,
+                 self.head_dim), self.dtype))
         # layer-FOLDED page-major pool (see PagedKV): layer l's logical
         # page p is physical page l * num_pages + p — decode updates it
         # in place; each page is one contiguous DMA block.
@@ -218,6 +237,9 @@ class BlockKVCacheManager:
         moves roughly half the bytes of its bf16 equivalent."""
         elems = (self.num_layers * self._pool_heads
                  * self.page_size * self.head_dim)
+        if self.latent:
+            # one array: the row as STORED, pad lanes included
+            return elems * jnp.dtype(self.dtype).itemsize
         if self.dtype == "int8" or self.dtype == jnp.int8:
             scale = (self._pool_heads * self.num_layers
                      * self.page_size * 4)

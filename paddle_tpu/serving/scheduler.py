@@ -90,7 +90,7 @@ from .faults import (DeadlineExceeded, PoolSizingError, ServerOverloaded,
                      TokenCorruption, WatchdogTimeout)
 from .journal import FlightRecorder
 from .prefix_cache import PrefixCache
-from ..inference.engine import _refuse_recurrent
+from ..inference.engine import _refuse_latent, _refuse_recurrent
 from .request import Request
 from .slo import SLOMonitor
 
@@ -246,6 +246,11 @@ class ServingEngine(ContinuousBatchingEngine):
             if want_prefix:
                 _refuse_recurrent("SLOConfig(prefix_cache=True): prefix "
                                   "reuse")
+            want_prefix = False
+        if self._latent:
+            if want_prefix:
+                _refuse_latent("SLOConfig(prefix_cache=True): prefix "
+                               "reuse")
             want_prefix = False
         if want_prefix or want_prefix is None:
             self.prefix_cache = PrefixCache(
@@ -1425,7 +1430,7 @@ class ServingEngine(ContinuousBatchingEngine):
         key = (c, adaptered)
         if key not in self._chunk_jit:
             rung = self._chunk_rung(c, adaptered)
-            if self._rs is not None:
+            if self._pattern_built:
                 # a pattern-built model brings its own chunk program
                 # (the recurrent state rides with the pool)
                 prog = self._gen._get_chunk_prefill(rung)
@@ -1477,7 +1482,7 @@ class ServingEngine(ContinuousBatchingEngine):
                          rid=stt.req.id):
             t0 = time.perf_counter()
             out = self._run_program(program, lead, tail)
-            if self._rs is None:
+            if not self._pattern_built:
                 tok = int(np.asarray(
                     self._gen._argmax(jnp.asarray(out)))[0])
             else:
@@ -1581,13 +1586,19 @@ class ServingEngine(ContinuousBatchingEngine):
                      self.adapters.operands(tp=self._gen._tp))
             _stats.inc("lora.grouped_launches",
                        4 * self.model.stack.num_layers)
-        if self._rs is not None:
+        if self._pattern_built:
             # which slot's state the chunk continues, and whether it
             # starts from zeros (the first chunk after an admission)
             fresh = self._mgr.recurrent_is_fresh(i)
-            if not fresh:
+            if self._rs is not None and not fresh:
                 _stats.inc("serving.recurrent.resumed_chunks")
             extra = (jnp.asarray([i], jnp.int32), jnp.asarray([fresh]))
+        if self._latent:
+            # causal query-key pairs of this chunk, a layer: real row r
+            # attends the stt.pos + r + 1 positions up to its own
+            _stats.inc("serving.mla.prefill_pairs",
+                       (n * stt.pos + n * (n + 1) // 2)
+                       * self._mgr.num_layers)
         lead = (self._gen._weights(), self._gen._embed(),
                 self._gen._head_t, lnf_s, lnf_b, jnp.asarray(ids),
                 jnp.asarray([stt.pos], jnp.int32),

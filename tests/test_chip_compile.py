@@ -56,6 +56,8 @@ KERNELS = [
     "ssd.chunk_scan",
     "ssm.decode_update",
     "moe.stream_experts",
+    "mla.paged_prefill",
+    "mla.paged_decode",
 ]
 
 L, D, DFF, NQ, HEADS, HEAD_DIM, PAGE = 24, 2048, 8192, 3 * 2048, 16, 128, 16
@@ -417,6 +419,87 @@ def test_decode_program_never_copies_the_pool(stack, one_chip, as_on_chip):
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < side // 4, mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes >= 2 * side      # both sides donated
+
+
+def _latent_program(phase: str):
+    """``HybridStack`` over latent-attention layers at the published widths
+    of Mistral-Small-4-119B-2603 and its cell's shapes (24 rows or a
+    1,024-token chunk, tables of 2,096 pages, a latent pool of 50,368
+    pages a layer, ``[100736, 16, 384]``, donated), cut to TWO layers and 4
+    held experts of the 128: the cell's five layers only repeat these, and
+    the other experts do not touch the pool."""
+    from paddle_tpu.incubate.nn.hybrid_stack import HybridStack
+    from paddle_tpu.incubate.nn.layer_pattern import (
+        LATENT, LatentAttentionSpec, LayerPattern, MoESpec, YarnSpec)
+    from paddle_tpu.nn.functional.mla_attention import LatentKV
+
+    d, nl, held, pages, pp = 4096, 2, 4, 50368, 2096
+    lt = LatentAttentionSpec(
+        32, 1024, 256, 64, 64, 128,
+        yarn=YarnSpec(128.0, 8192, 32.0, 1.0, 1.0, 1.0),
+        temperature_beta=0.1, temperature_period=8192)
+    p = LayerPattern(
+        d_model=d, period=(LATENT,), n_periods=nl, latent=lt,
+        moe=MoESpec(128, 4, 2048, shared_dim=2048,
+                    experts_held=(0, held)),
+        norm="rmsnorm", gated=True, bias=False, activation="silu",
+        epsilon=1e-6, tie_embeddings=False)
+    st = object.__new__(HybridStack)
+    object.__setattr__(st, "pattern", p)
+    bf, f32, W, R = jnp.bfloat16, jnp.float32, lt.row_width, 256
+    w = {"l_norm": ((nl, d), f32), "l_dq": ((nl, d, 1024), bf),
+         "l_qnorm": ((nl, 1024), f32), "l_uq": ((nl, 1024, 4096), bf),
+         "l_dkv": ((nl, d, W), bf), "l_kvnorm": ((nl, R), f32),
+         "l_uk": ((nl, 32, 64, R), bf), "l_uv": ((nl, 32, R, 128), bf),
+         "l_o": ((nl, 4096, d), bf), "f_norm": ((nl, d), f32),
+         "f_router": ((nl, d, 128), f32),
+         "e_w1": ((nl, held, d, 4096), bf),
+         "e_w2": ((nl, held, 2048, d), bf),
+         "s_w1": ((nl, d, 4096), bf), "s_w2": ((nl, 2048, d), bf)}
+    w = {n: _sds(*sd) for n, sd in w.items()}
+    pool = _sds((nl * pages, PAGE, W), bf)
+    rope = _sds((33537, 32), f32)
+    if phase == "decode":
+        def fn(w, x, rows, tables, lens, active, cos, sin):
+            h, cache, _, counts = st.decode_raw(
+                w, x, LatentKV(rows), None, tables, lens, active, cos, sin)
+            return h, cache.rows, counts
+
+        args = (w, _sds((24, d), bf), pool, _sds((24, pp), jnp.int32),
+                _sds((24,), jnp.int32), _sds((24,), jnp.bool_), rope, rope)
+    else:
+        def fn(w, x, rows, tables, start, lens, cos, sin):
+            h, cache, _, counts = st.prefill_chunk_raw(
+                w, x, LatentKV(rows), None, tables, start, lens, cos, sin)
+            return h, cache.rows, counts
+
+        args = (w, _sds((1, 1024, d), bf), pool, _sds((1, pp), jnp.int32),
+                _sds((1,), jnp.int32), _sds((1,), jnp.int32), rope, rope)
+    return jax.jit(fn, donate_argnums=(2,)), args, pool
+
+
+@pytest.mark.parametrize("phase", ["prefill", "decode"])
+def test_latent_programs_never_copy_the_pool(phase, one_chip, as_on_chip):
+    """Both phases of a latent-attention stack at the cell's shapes and
+    the published widths: the ONE-array pool passes through the phase's
+    latent kernel (aliased) and no other instruction, nothing of its shape
+    is copied, it stays donated and the program's temp stays far under
+    it. A 320-wide row is refused by the chip's compiler (the minor
+    dimension is stored in 128-lane tiles): the row is 384 wide."""
+    jitted, args, pool = _latent_program(phase)
+    compiled = jitted.lower(*_placed(args, one_chip)).compile()
+    text = compiled.as_text()
+    assert not _pool_copies(text, pool)
+    names = _kernel_names(text)
+    if phase == "decode":
+        assert names == {"pt_mla_paged_decode", "pt_moe_stream_experts",
+                         "pt_stream_linear_bf16"}
+    else:
+        assert names == {"pt_mla_paged_prefill", "pt_grouped_gemm_fwd"}
+    size = 2 * math.prod(pool.shape)                # bf16 bytes
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < size // 4, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes == size          # the pool, donated
 
 
 def _avals(jaxpr):

@@ -273,7 +273,7 @@ def test_trace_names_are_one_per_pallas_call():
             by_site.setdefault((rec.path, rec.line), set()).add(name)
     assert all(len(names) == 1 for names in by_site.values())
     names = [next(iter(v)) for v in by_site.values()]
-    assert len(set(names)) == len(names) == 17     # 16 of ours + JAX's
+    assert len(set(names)) == len(names) == 19     # 18 of ours + JAX's
 
 
 def test_no_pallas_call_without_a_name():
@@ -289,7 +289,7 @@ def test_no_pallas_call_without_a_name():
             named += m.group(1) == "name"
         assert len(re.findall(r"pallas_call\(", src)) \
             == len(re.findall(r"pl\.pallas_call\(\s*kernel,", src))
-    assert calls == named == 16
+    assert calls == named == 18
 
 
 # ------------------------------------------ (e) the programs' module names
