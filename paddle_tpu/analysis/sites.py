@@ -694,6 +694,158 @@ def _expected_mla_paged_decode():
             + _B((2, cpk, ps, W), "bfloat16"))     # walk double buffer
 
 
+# the two attention kinds of MiMo-V2-Flash's layers at its published
+# widths: 64 query heads of 192 (q / k) and 128 (v) lanes; full layers over
+# 4 kv heads whose K|V rows are packed 1,280 lanes a token (page 16), a
+# 256-token chunk at eight heads a grid step and 32 decode rows over tables
+# of 64 pages; window layers over 8 kv heads and a ring of 128 rows a slot
+# (K heads stored in 256 lanes), a 256-token chunk in blocks of 128 rows
+_MIX = dict(H=64, Gf=4, Gw=8, dk=192, kl=256, dv=128, RW=1280, ps=16,
+            P=512, pp=64, c=256, S=32, Wr=128, g=8, L=5)
+
+
+def _build_gqa_paged_prefill():
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional.mixed_attention import gqa_prefill_attend
+
+    H, G, dk, dv, RW, ps, P, pp, c = (_MIX[k] for k in
+                                      ("H", "Gf", "dk", "dv", "RW", "ps",
+                                       "P", "pp", "c"))
+
+    def fn(q, rows, pool, tables, start, lens):
+        return gqa_prefill_attend(q, rows, pool, tables, start, lens,
+                                  n_kv=G, v_dim=dv)
+
+    return fn, (_sds((c, H, dk), jnp.bfloat16), _sds((c, RW), jnp.bfloat16),
+                _sds((P, ps, RW), jnp.bfloat16), _sds((1, pp), jnp.int32),
+                _sds((1,), jnp.int32), _sds((1,), jnp.int32))
+
+
+def _expected_gqa_paged_prefill():
+    # a grid step is 8 query heads of ONE kv head over all 256 rows (their
+    # queries padded to 256 lanes); the chunk, page-shaped, touches 17
+    # pages; the walk gathers 16 pages a step into one half of a double
+    # buffer and picks the kv head's K and V out with a [1280, 384] 0/1
+    # matrix; the pool stays in HBM (aliased)
+    kl, dv, RW, ps, c, g = (_MIX[k] for k in
+                            ("kl", "dv", "RW", "ps", "c", "g"))
+    npg, cpk = 17, 16
+    return (2 * _B((c, g * kl), "bfloat16")        # the group's queries
+            + 2 * _B((1, RW, kl + dv), "bfloat16")  # the kv head's picker
+            + _B((npg, ps, RW), "bfloat16")        # the chunk, page-shaped
+            + _B((npg, ps, 1), "float32")          # row mask
+            + 2 * _B((c, g * dv), "float32")       # the group's answer
+            + _B((npg, ps, RW), "bfloat16")        # page RMW scratch
+            + _B((2, cpk, ps, RW), "bfloat16")     # walk double buffer
+            + 2 * _B((g, c, 1), "float32")         # running max, sum
+            + _B((g, c, dv), "float32"))           # accumulator
+
+
+def _build_gqa_paged_decode():
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional.mixed_attention import gqa_decode_attend
+
+    H, G, dk, dv, RW, ps, P, pp, S = (_MIX[k] for k in
+                                      ("H", "Gf", "dk", "dv", "RW", "ps",
+                                       "P", "pp", "S"))
+
+    def fn(q, k, v, pool, tables, lens):
+        return gqa_decode_attend(q, k, v, pool, tables, lens, 256)
+
+    return fn, (_sds((S, H, dk), jnp.bfloat16), _sds((S, G, dk), jnp.bfloat16),
+                _sds((S, G, dv), jnp.bfloat16),
+                _sds((P, ps, RW), jnp.bfloat16), _sds((S, pp), jnp.int32),
+                _sds((S,), jnp.int32))
+
+
+def _expected_gqa_paged_decode():
+    # the latent decode kernel's blocks over a 1,280-lane packed row: a
+    # sequence's 64 zero-padded query rows, its new row, its slot selector
+    # and its output (every kv head's V lanes); 64 pages a walk step
+    H, G, dv, RW, ps = (_MIX[k] for k in ("H", "Gf", "dv", "RW", "ps"))
+    cpk = 64
+    return (2 * _B((1, H, RW), "bfloat16")         # q rows
+            + 2 * _B((1, 1, RW), "float32")        # the new row
+            + 2 * _B((1, ps, 1), "float32")        # slot selector
+            + 2 * _B((1, H, G * dv), "float32")    # out rows
+            + _B((ps, RW), "bfloat16")             # page RMW scratch
+            + _B((2, cpk, ps, RW), "bfloat16"))    # walk double buffer
+
+
+def _build_swa_prefill():
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional.mixed_attention import (
+        WindowRing, swa_prefill_attend)
+
+    H, G, dk, kl, dv, c, S, Wr, L = (_MIX[k] for k in
+                                     ("H", "Gw", "dk", "kl", "dv", "c", "S",
+                                      "Wr", "L"))
+
+    def fn(q, k, v, rk, rv, slot, start, lens, sink):
+        return swa_prefill_attend(q, k, v, WindowRing(rk, rv), 2, slot,
+                                  start, lens, sink)
+
+    return fn, (_sds((c, H, dk), jnp.bfloat16), _sds((c, G, dk), jnp.bfloat16),
+                _sds((c, G, dv), jnp.bfloat16),
+                _sds((L, S, G, Wr, kl), jnp.bfloat16),
+                _sds((L, S, G, Wr, dv), jnp.bfloat16),
+                _sds((1,), jnp.int32), _sds((1,), jnp.int32),
+                _sds((1,), jnp.int32), _sds((H,), jnp.float32))
+
+
+def _expected_swa_prefill():
+    # a grid step is a kv head's 8 query heads over 128 query rows; the
+    # kv head's window-plus-chunk keys and values (128 + 256 rows) stay
+    # resident while its query blocks pass; the sinks a column
+    H, G, kl, dv, c, Wr = (_MIX[k] for k in ("H", "Gw", "kl", "dv", "c",
+                                             "Wr"))
+    hpg, B = H // G, 128
+    return (2 * _B((B, hpg * kl), "bfloat16")      # query block
+            + 2 * _B((1, Wr + c, kl), "bfloat16")  # the kv head's keys
+            + 2 * _B((1, Wr + c, dv), "bfloat16")  # ... and values
+            + 2 * _B((1, hpg, 1), "float32")       # sinks
+            + 2 * _B((B, hpg * dv), "float32"))    # answer block
+
+
+def _build_swa_decode():
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional.mixed_attention import (
+        WindowRing, swa_decode_attend)
+
+    H, G, dk, kl, dv, S, Wr, L = (_MIX[k] for k in
+                                  ("H", "Gw", "dk", "kl", "dv", "S", "Wr",
+                                   "L"))
+
+    def fn(q, k, v, rk, rv, lens, active, sink):
+        return swa_decode_attend(q, k, v, WindowRing(rk, rv), 2, lens,
+                                 active, sink)
+
+    return fn, (_sds((S, H, dk), jnp.bfloat16), _sds((S, G, dk), jnp.bfloat16),
+                _sds((S, G, dv), jnp.bfloat16),
+                _sds((L, S, G, Wr, kl), jnp.bfloat16),
+                _sds((L, S, G, Wr, dv), jnp.bfloat16),
+                _sds((S,), jnp.int32), _sds((S,), jnp.bool_),
+                _sds((H,), jnp.float32))
+
+
+def _expected_swa_decode():
+    # a grid step a slot: its 64 query rows, its new K / V rows, its ring
+    # of the layer in (and, aliased, out), its answer; the sinks resident
+    H, G, kl, dv, Wr = (_MIX[k] for k in ("H", "Gw", "kl", "dv", "Wr"))
+    hpg = H // G
+    return (2 * _B((1, G, hpg, kl), "bfloat16")    # q rows
+            + 2 * _B((1, G, 1, kl), "bfloat16")    # new K row
+            + 2 * _B((1, G, 1, dv), "bfloat16")    # new V row
+            + _B((G, hpg, 1), "float32")           # sinks
+            + 2 * 2 * _B((1, 1, G, Wr, kl), "bfloat16")  # ring K in, out
+            + 2 * 2 * _B((1, 1, G, Wr, dv), "bfloat16")  # ring V in, out
+            + 2 * _B((1, G, hpg, dv), "float32"))  # out rows
+
+
 KERNEL_SITES: List[KernelSite] = [
     KernelSite("stream_linear.bf16", "nn/functional/stream_linear.py",
                _build_stream_linear, _expected_stream_linear),
@@ -759,6 +911,16 @@ KERNEL_SITES: List[KernelSite] = [
                _build_mla_paged_prefill, _expected_mla_paged_prefill),
     KernelSite("mla.paged_decode", "nn/functional/mla_attention.py",
                _build_mla_paged_decode, _expected_mla_paged_decode),
+    # window / full attention of MiMo-V2-Flash: the full
+    # layers' packed K|V rows paged, the window layers' slot ring
+    KernelSite("gqa.paged_prefill", "nn/functional/mixed_attention.py",
+               _build_gqa_paged_prefill, _expected_gqa_paged_prefill),
+    KernelSite("gqa.paged_decode", "nn/functional/mixed_attention.py",
+               _build_gqa_paged_decode, _expected_gqa_paged_decode),
+    KernelSite("swa.prefill", "nn/functional/mixed_attention.py",
+               _build_swa_prefill, _expected_swa_prefill),
+    KernelSite("swa.decode", "nn/functional/mixed_attention.py",
+               _build_swa_decode, _expected_swa_decode),
 ]
 
 

@@ -1,8 +1,9 @@
 """A served stack BUILT from a ``LayerPattern``: a period of layer kinds
-(Mamba-2 mixers, NoPE / rotary GQA attention, multi-head latent
-attention), each followed by an FFN of routed gated experts plus a
-shared gated MLP, RMSNorm, no biases, residual / attention multipliers
-from the description.
+(Mamba-2 mixers, NoPE / rotary GQA attention, sliding-window GQA
+attention, multi-head latent attention), each followed by an FFN of
+routed gated experts plus a shared gated MLP (or, in the pattern's
+``dense_lead`` layers, a dense gated MLP), RMSNorm, no biases, residual /
+attention multipliers from the description.
 
     h = h + r * Mixer(RMSNorm(h))
     h = h + r * (MoE(x) + SharedMLP(x)),   x = RMSNorm(h)
@@ -14,7 +15,13 @@ A weight stack a kind, a cache group a kind:
 ``"attention"``         ``qkv_weight`` / ``out_weight`` / ``a_norm``; the
     serving engine's paged pool ``PagedKV`` (layer-folded over
     ``n_attention`` layers, touched only by the three paged Pallas
-    kernels ``FusedMultiTransformer`` uses).
+    kernels ``FusedMultiTransformer`` uses), or, where V heads are
+    narrower than K heads, ``PackedKV`` (touched only by
+    ``pt_gqa_paged_prefill`` / ``pt_gqa_paged_decode``).
+``"window_attention"``  ``w_qkv`` / ``w_out`` / ``w_norm`` / ``w_sink``
+    (the per-head sink logits); the slot-indexed ``WindowRing`` beside the
+    pool (``pt_swa_prefill`` / ``pt_swa_decode``), rotary from the second
+    table the phases take (``rope_w``).
 ``"latent_attention"``  ``l_*`` (down / up projections of the queries,
     the latent + rope-key projection padded to the pool's row, the
     per-head ``W_uk`` / ``W_uv``: prefill expands a pool tile's K / V with
@@ -22,7 +29,10 @@ A weight stack a kind, a cache group a kind:
     paged latent pool ``LatentKV`` (one row a token and layer, touched
     only by ``pt_mla_paged_prefill`` / ``pt_mla_paged_decode``).
 
-``f_*`` / ``e_*`` / ``s_*`` are the FFN of every layer.
+``f_norm`` is every layer's FFN norm; ``d_*`` the dense gated MLP of the
+``dense_lead`` layers; ``f_router`` / ``e_*`` (and ``e_bias``, the
+selection bias of sigmoid routing) / ``s_*`` the FFN of every other
+layer, stacked over the expert layers alone.
 
 The two raw phases mirror ``FusedMultiTransformer``'s:
 
@@ -49,9 +59,11 @@ import jax
 import jax.numpy as jnp
 
 from ...nn.layer_base import Layer
+from ...nn.functional.mixed_attention import PackedKV
 from ...nn.functional.mla_attention import LatentKV
 from .fused_transformer import PagedKV, _apply_rope
-from .layer_pattern import LATENT, MAMBA, LayerPattern
+from .layer_pattern import (LATENT, MAMBA, SLOT_RECURRENT, SLOT_RING,
+                            WINDOW, LayerPattern)
 
 __all__ = ["HybridStack", "RecurrentState"]
 
@@ -75,7 +87,11 @@ class RecurrentState(NamedTuple):
 
 
 class HybridStack(Layer):
-    def __init__(self, pattern: LayerPattern, dtype=jnp.float32):
+    def __init__(self, pattern: LayerPattern, dtype=jnp.float32,
+                 draw: bool = True):
+        """``draw=False``: every stack that would be drawn starts as zeros,
+        for a caller that rebinds each parameter to weights of its own (no
+        draw, and no draw program to compile, a stack)."""
         super().__init__()
         if pattern.moe is None or not pattern.gated \
                 or pattern.norm != "rmsnorm" or pattern.bias:
@@ -84,7 +100,7 @@ class HybridStack(Layer):
                 "routed-expert blocks; the LayerNorm / biased GELU "
                 "one-kind pattern is FusedMultiTransformer's (kinds a "
                 "period may mix here: mamba, attention, "
-                "latent_attention)")
+                "window_attention, latent_attention)")
         self.pattern = pattern
         self.embed_dim = pattern.d_model
         self.num_layers = pattern.num_layers
@@ -97,6 +113,8 @@ class HybridStack(Layer):
         dtype = jnp.dtype(dtype)
 
         def normal(*s, std=0.02, dt=dtype):
+            if not draw:
+                return jnp.zeros(s, dt)
             return _draw(default_generator().next_key(), s, dt, std)
 
         def mk(name, arr):
@@ -144,20 +162,35 @@ class HybridStack(Layer):
             add("m_gnorm", ones(Lm, m.d_inner))
             add("m_out", normal(Lm, m.d_inner, d))
         if La:
-            qkv = (att.num_heads + 2 * att.num_kv_heads) * att.head_dim
+            qkv = (att.num_heads + att.num_kv_heads) * att.head_dim \
+                + att.num_kv_heads * att.v_dim
             add("a_norm", ones(La, d))
             add("qkv_weight", normal(La, d, qkv))
-            add("out_weight", normal(La, att.num_heads * att.head_dim, d))
+            add("out_weight", normal(La, att.num_heads * att.v_dim, d))
+        Lw = pattern.n_window
+        if Lw:
+            sw = pattern.window_attention
+            add("w_norm", ones(Lw, d))
+            add("w_qkv", normal(Lw, d, (sw.num_heads + sw.num_kv_heads)
+                                * sw.head_dim + sw.num_kv_heads * sw.v_dim))
+            add("w_out", normal(Lw, sw.num_heads * sw.v_dim, d))
+            add("w_sink", jnp.zeros((Lw, sw.num_heads), jnp.float32))
         moe = pattern.moe
         held = moe.held[1]
+        Le, Ld = pattern.n_moe, pattern.dense_lead
         add("f_norm", ones(L, d))
-        add("f_router", normal(L, d, moe.num_experts,
+        if Ld:
+            add("d_w1", normal(Ld, d, 2 * pattern.d_ff))
+            add("d_w2", normal(Ld, pattern.d_ff, d))
+        add("f_router", normal(Le, d, moe.num_experts,
                                dt=jnp.dtype(jnp.float32)))
-        add("e_w1", normal(L, held, d, 2 * moe.expert_dim))
-        add("e_w2", normal(L, held, moe.expert_dim, d))
+        if moe.routing == "sigmoid_bias":
+            add("e_bias", jnp.zeros((Le, moe.num_experts), jnp.float32))
+        add("e_w1", normal(Le, held, d, 2 * moe.expert_dim))
+        add("e_w2", normal(Le, held, moe.expert_dim, d))
         if moe.shared_dim:
-            add("s_w1", normal(L, d, 2 * moe.shared_dim))
-            add("s_w2", normal(L, moe.shared_dim, d))
+            add("s_w1", normal(Le, d, 2 * moe.shared_dim))
+            add("s_w2", normal(Le, moe.shared_dim, d))
 
     # ------------------------------------------------ functional core
 
@@ -195,18 +228,32 @@ class HybridStack(Layer):
             x, w[l], (((x.ndim - 1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    def _ffn(self, w, h, l, rows, decode, counts):
+    def _ffn(self, w, h, l, rows, decode, counts, stream=False):
         """``h + r * (MoE(x) + SharedMLP(x))`` over flat rows ``[T, d]``
         and the layer's pick counts (prefill rows: and the grouped
-        GEMMs' unit counts) added to ``counts``."""
+        GEMMs' unit counts) added to ``counts``; in a ``dense_lead``
+        layer ``h + r * DenseMLP(x)`` and nothing counted (``stream``:
+        decode rows stream its matrices)."""
         from ...nn.functional.moe_gated import (
             gated_mlp, moe_gated_grouped, moe_gated_stream, pick_counts,
-            route_topk_softmax)
+            route_topk_sigmoid, route_topk_softmax)
 
         p = self.pattern
         moe = p.moe
         x = self._rms(h, w["f_norm"][l], p.epsilon).astype(h.dtype)
-        gates, idx = route_topk_softmax(x, w["f_router"][l], moe.top_k)
+        if l < p.dense_lead:
+            f = p.d_ff
+            ab = self._proj(x, w["d_w1"], l, stream)
+            mid = (jax.nn.silu(ab[:, :f]) * ab[:, f:]).astype(h.dtype)
+            return self._residual(h, self._proj(mid, w["d_w2"], l,
+                                                stream)), counts
+        l = p.moe_index(l)
+        if moe.routing == "sigmoid_bias":
+            gates, idx = route_topk_sigmoid(x, w["f_router"][l],
+                                            w["e_bias"][l], moe.top_k,
+                                            moe.scaling)
+        else:
+            gates, idx = route_topk_softmax(x, w["f_router"][l], moe.top_k)
         units = []              # the streamed experts have no schedule
         if decode:
             y = moe_gated_stream(x, gates, idx, w["e_w1"], w["e_w2"], l,
@@ -288,30 +335,66 @@ class HybridStack(Layer):
 
     @staticmethod
     def _split_cache(cache):
-        """``(latent pool | None, K side | None, V side | None)``."""
-        if isinstance(cache, LatentKV):
+        """``(pool of rows | None, K side | None, V side | None)``."""
+        if isinstance(cache, (LatentKV, PackedKV)):
             return cache.rows, None, None
         if cache is None:
             return None, None, None
         return None, cache.k, cache.v
 
     @staticmethod
-    def _join_cache(pool, ck, cv):
+    def _join_cache(pool, ck, cv, rows_cls=LatentKV):
         if pool is not None:
-            return LatentKV(pool)
+            return rows_cls(pool)
         return PagedKV(ck, cv) if ck is not None else None
+
+    def _gqa_inputs(self, w, names, spec, li, h, positions, table, stream):
+        """Rows ``h [T, d]`` at ``positions [T]`` of one layer of a GQA
+        kind whose V heads may be narrower -> ``(q [T, H, dk]`` scaled,
+        ``k [T, G, dk]``, ``v [T, G, dv])``: rotate-half rotary over each
+        head's first ``rot_dim`` lanes from ``table = (cos, sin)``."""
+        norm, qkv = names
+        nq, nkv, hd, vd = (spec.num_heads, spec.num_kv_heads, spec.head_dim,
+                           spec.v_dim)
+        T = h.shape[0]
+        hn = self._rms(h, w[norm][li], self.pattern.epsilon).astype(h.dtype)
+        proj = self._proj(hn, w[qkv], li, stream)
+        q = proj[:, :nq * hd].reshape(T, nq, hd)
+        k = proj[:, nq * hd: (nq + nkv) * hd].reshape(T, nkv, hd)
+        v = proj[:, (nq + nkv) * hd:].reshape(T, nkv, vd)
+        if spec.rope_theta is not None:
+            r = spec.rot_dim
+            cos = table[0][positions][:, None, :]
+            sin = table[1][positions][:, None, :]
+            q = jnp.concatenate([_apply_rope(q[..., :r], cos, sin),
+                                 q[..., r:]], -1)
+            k = jnp.concatenate([_apply_rope(k[..., :r], cos, sin),
+                                 k[..., r:]], -1)
+        q = (q * spec.softmax_scale).astype(h.dtype)
+        return q, k.astype(h.dtype), v.astype(h.dtype)
+
+    def _gqa_finish(self, w, out_name, spec, li, h, o, stream):
+        """``o [T, H, dv]`` float32 -> the residual stream, times the
+        description's value scale (attention is linear in the values)."""
+        att = (o * spec.value_scale).reshape(h.shape[0], -1).astype(h.dtype)
+        return self._residual(h, self._proj(att, w[out_name], li, stream))
 
     # ------------------------------------------------------- prefill
 
     def prefill_chunk_raw(self, weights, x, cache, state, block_tables,
-                          start, chunk_lens, cos_t=None, sin_t=None):
+                          start, chunk_lens, cos_t=None, sin_t=None, *,
+                          slot=None, rope_w=None):
         """x ``[1, c, d]``; ``cache`` the paged pool of the attention
-        layers (``PagedKV``) or of the latent-attention layers
-        (``LatentKV``); ``state`` this sequence's recurrent arrays
+        layers (``PagedKV`` / ``PackedKV``) or of the latent-attention
+        layers (``LatentKV``); ``state`` this sequence's recurrent arrays
         (``ssm [Lm, N, d_inner]`` float32, ``conv [Lm, k-1, conv_dim]``)
-        as they stood before the chunk. Returns ``(hidden [1, c, d],
-        cache', state', counts int32 [6])``."""
+        as they stood before the chunk, or the WHOLE ``WindowRing`` of the
+        window layers with ``slot [1]`` the sequence's (``rope_w`` their
+        rotary ``(cos, sin)``). Returns ``(hidden [1, c, d], cache',
+        state', counts int32 [6])``."""
         from ...nn.functional.flash_varlen import paged_prefill_attention
+        from ...nn.functional.mixed_attention import (
+            gqa_prefill_attend, pack_rows, swa_prefill_attend)
         from ...nn.functional.mla_attention import mla_prefill_attend
         from ...nn.functional.paged_attention import (
             write_prefill_kv_inplace)
@@ -329,7 +412,11 @@ class HybridStack(Layer):
         valid = jnp.arange(c, dtype=jnp.int32) < chunk_lens[0]   # [c]
         positions = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None]
         pool, ck, cv = self._split_cache(cache)
-        ssm, conv = state if state is not None else (None, None)
+        rows_cls = type(cache) if pool is not None else LatentKV
+        ring = state if p.slot_state == SLOT_RING else None
+        ssm, conv = state if p.slot_state == SLOT_RECURRENT \
+            and state is not None \
+            else (None, None)
         paged = pool if pool is not None else ck
         npages = paged.shape[0] // max(p.n_paged, 1) \
             if paged is not None else 0
@@ -366,6 +453,24 @@ class HybridStack(Layer):
                     q, rows, pool, block_tables + li * npages, start,
                     chunk_lens, w["l_uk"][li], w["l_uv"][li])
                 h = self._latent_finish(w, li, h, o, False, absorbed=False)
+            elif kind == WINDOW:
+                sw = p.window_attention
+                q, k, v = self._gqa_inputs(w, ("w_norm", "w_qkv"), sw, li, h,
+                                           positions[0], rope_w, False)
+                o, ring = swa_prefill_attend(
+                    q, k, v, ring, li, slot, start, chunk_lens,
+                    w["w_sink"][li] if sw.sink else None)
+                h = self._gqa_finish(w, "w_out", sw, li, h, o, False)
+            elif p.attention.packed:
+                att = p.attention
+                q, k, v = self._gqa_inputs(w, ("a_norm", "qkv_weight"), att,
+                                           li, h, positions[0],
+                                           (cos_t, sin_t), False)
+                o, pool = gqa_prefill_attend(
+                    q, pack_rows(k, v, att.row_width), pool,
+                    block_tables + li * npages, start, chunk_lens,
+                    n_kv=att.num_kv_heads, v_dim=att.v_dim)
+                h = self._gqa_finish(w, "out_weight", att, li, h, o, False)
             else:
                 att = p.attention
                 hn = self._rms(h, w["a_norm"][li], p.epsilon) \
@@ -392,19 +497,23 @@ class HybridStack(Layer):
         # (is the expert stream's time free of the data?): a prefill
         # chunk reports its picks and its grouped GEMMs' units alone
         counts = counts * jnp.asarray([1, 1, 0, 0, 1, 1], jnp.int32)
-        state2 = (ssm, conv) if ssm is not None else None
-        return h[None], self._join_cache(pool, ck, cv), state2, counts
+        state2 = (ssm, conv) if ssm is not None else ring
+        return h[None], self._join_cache(pool, ck, cv, rows_cls), state2, \
+            counts
 
     # -------------------------------------------------------- decode
 
     def decode_raw(self, weights, x, cache, state, block_tables,
-                   seq_lens, active, cos_t=None, sin_t=None):
+                   seq_lens, active, cos_t=None, sin_t=None, *, rope_w=None):
         """One decode step for every slot: x ``[slots, d]``, ``seq_lens``
         the tokens already cached, ``active [slots]`` bool. ``state`` is
         the WHOLE ``RecurrentState`` (its ssm array is updated in place
-        by ``pt_ssm_decode_update``). Returns ``(hidden, cache',
-        state', counts int32 [4])``."""
+        by ``pt_ssm_decode_update``) or ``WindowRing`` (its rows by
+        ``pt_swa_decode``). Returns ``(hidden, cache', state', counts
+        int32 [4])``."""
         from ...device import chip as _chip
+        from ...nn.functional.mixed_attention import (gqa_decode_attend,
+                                                      swa_decode_attend)
         from ...nn.functional.mla_attention import mla_decode_attend
         from ...nn.functional.paged_attention import (
             decode_attend, plan_decode_attention)
@@ -417,7 +526,11 @@ class HybridStack(Layer):
         stream = _chip.on_tpu() and S % 8 == 0
         seq_lens = seq_lens.astype(jnp.int32)
         pool, ck, cv = self._split_cache(cache)
-        ssm, conv = state if state is not None else (None, None)
+        rows_cls = type(cache) if pool is not None else LatentKV
+        ring = state if p.slot_state == SLOT_RING else None
+        ssm, conv = state if p.slot_state == SLOT_RECURRENT \
+            and state is not None \
+            else (None, None)
         paged = pool if pool is not None else ck
         npages = paged.shape[0] // max(p.n_paged, 1) \
             if paged is not None else 0
@@ -456,6 +569,22 @@ class HybridStack(Layer):
                     q, rows, pool, block_tables, seq_lens, li * npages,
                     v_width=p.latent.kv_lora_rank)
                 h = self._latent_finish(w, li, h, o, stream, absorbed=True)
+            elif kind == WINDOW:
+                sw = p.window_attention
+                q, k, v = self._gqa_inputs(w, ("w_norm", "w_qkv"), sw, li, h,
+                                           seq_lens, rope_w, stream)
+                o, ring = swa_decode_attend(
+                    q, k, v, ring, li, seq_lens, active,
+                    w["w_sink"][li] if sw.sink else None)
+                h = self._gqa_finish(w, "w_out", sw, li, h, o, stream)
+            elif p.attention.packed:
+                att = p.attention
+                q, k, v = self._gqa_inputs(w, ("a_norm", "qkv_weight"), att,
+                                           li, h, seq_lens, (cos_t, sin_t),
+                                           stream)
+                o, pool = gqa_decode_attend(q, k, v, pool, block_tables,
+                                            seq_lens, li * npages)
+                h = self._gqa_finish(w, "out_weight", att, li, h, o, stream)
             else:
                 att = p.attention
                 nq, nkv, hd = att.num_heads, att.num_kv_heads, att.head_dim
@@ -474,6 +603,6 @@ class HybridStack(Layer):
                 o = o.reshape(S, nq * hd).astype(h.dtype)
                 h = self._residual(
                     h, self._proj(o, w["out_weight"], li, stream))
-            h, counts = self._ffn(w, h, l, active, True, counts)
-        state2 = RecurrentState(ssm, conv) if ssm is not None else None
-        return h, self._join_cache(pool, ck, cv), state2, counts
+            h, counts = self._ffn(w, h, l, active, True, counts, stream)
+        state2 = RecurrentState(ssm, conv) if ssm is not None else ring
+        return h, self._join_cache(pool, ck, cv, rows_cls), state2, counts

@@ -6,16 +6,22 @@ One description, two stacks. ``FusedMultiTransformer`` (pre-LN
 LayerNorm, biased GELU FFN, rotary GQA, one kind) reports itself as the
 one-kind pattern (``LayerPattern.uniform_attention``);
 ``HybridStack`` (``incubate/nn/hybrid_stack.py``) is BUILT from a
-pattern whose period mixes the three kinds below. The serving engines
+pattern whose layers mix the four kinds below. The serving engines
 read only the description: which layers need pages and of what shape
-(``n_paged`` / ``paged_kind``), whether slot-indexed recurrent state
-has to live beside the pool (``recurrent``), and the widths of each.
+(``n_paged`` / ``paged_kind`` / ``pool_row_width``), which slot-indexed
+state has to live beside the pool (``slot_state``: ``recurrent`` or
+``window_ring``), and the widths of each.
 
 The kinds, and the cache group each has:
 
 ``"attention"``         rotary or NoPE GQA; K and V per kv-head in the
     paged pool ``PagedKV`` (two arrays ``[layers x pages, n_kv, page,
-    head_dim]``).
+    head_dim]``), or, where V heads are narrower than K heads
+    (``AttentionSpec.v_head_dim``), ``PackedKV``: ONE array ``[layers x
+    pages, page, row]``, a row every kv head's V then every kv head's K.
+``"window_attention"``  GQA over the last ``window`` positions with an
+    optional per-head sink logit; no pages: a slot-indexed ring of
+    ``window`` rows a layer (``WindowRing``) beside the pool.
 ``"latent_attention"``  multi-head latent attention (queries through a
     low rank, keys and values expanded from ONE latent row a token, one
     rotary head shared by all query heads, YaRN frequencies, a
@@ -26,13 +32,19 @@ The kinds, and the cache group each has:
 ``"mamba"``             Mamba-2 mixer; no pages, the slot-indexed
     ``RecurrentState``.
 
-One engine owns ONE paged pool, so a pattern holds ``"attention"`` or
-``"latent_attention"`` layers, not both.
+One engine owns ONE paged pool and ONE slot-indexed state, so a pattern
+holds ``"attention"`` or ``"latent_attention"`` layers, not both, and
+``"mamba"`` or ``"window_attention"`` layers, not both.
 
-A weight stack a kind, a cache group a kind: layer ``i`` of the model
-is kind ``period[i % len(period)]`` and the ``kind_index(i)``-th entry
-of that kind's stacks, of the paged pool's layer fold (both attention
-kinds) or of the recurrent state's leading axis (mamba).
+The layers are ``lead`` (kinds before the first period) followed by
+``period`` repeated ``n_periods`` times, cut to ``depth`` layers where
+that is set. A weight stack a kind, a cache group a kind: layer ``i``
+is kind ``kinds()[i]`` and the ``kind_index(i)``-th entry of that kind's
+stacks, of the paged pool's layer fold (both paged kinds), of the
+recurrent state's leading axis (mamba) or of the window ring's (window
+attention). The first ``dense_lead`` layers take a dense gated FFN of
+width ``d_ff``; every other layer takes the routed experts, the
+``moe_index(i)``-th entry of their stacks.
 """
 from __future__ import annotations
 
@@ -42,12 +54,23 @@ from typing import Optional, Tuple
 
 __all__ = ["AttentionSpec", "LatentAttentionSpec", "YarnSpec",
            "MambaSpec", "MoESpec", "LayerPattern", "RecurrentSpec",
-           "ATTENTION", "LATENT", "MAMBA"]
+           "WindowRingSpec", "ATTENTION", "LATENT", "MAMBA", "WINDOW",
+           "SLOT_RECURRENT", "SLOT_RING"]
 
 ATTENTION = "attention"
 LATENT = "latent_attention"
 MAMBA = "mamba"
-KINDS = (ATTENTION, LATENT, MAMBA)
+WINDOW = "window_attention"
+KINDS = (ATTENTION, LATENT, MAMBA, WINDOW)
+ROUTINGS = ("softmax_topk", "sigmoid_bias")
+#: what a slot keeps beside the pool (``LayerPattern.slot_state``)
+SLOT_RECURRENT = "recurrent"
+SLOT_RING = "window_ring"
+
+
+def _lanes(n: int) -> int:
+    """``n`` rounded up to whole 128-lane tiles."""
+    return -(-n // 128) * 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,11 +82,48 @@ class AttentionSpec:
     scale: Optional[float] = None
     #: rotary base; None = no positional encoding (NoPE)
     rope_theta: Optional[float] = 10000.0
+    #: V head width where it differs from the q / k heads' (None: the same)
+    v_head_dim: Optional[int] = None
+    #: leading dims of a q / k head that rotate (rotate-half pairing over
+    #: them); None = all of ``head_dim``
+    rope_dim: Optional[int] = None
+    #: query ``i`` sees keys ``i - window < j <= i`` (None: all ``j <= i``)
+    window: Optional[int] = None
+    #: a learnt per-head logit that joins every softmax's denominator and
+    #: adds no value
+    sink: bool = False
+    #: what the attended values are multiplied by
+    value_scale: float = 1.0
 
     @property
     def softmax_scale(self) -> float:
         return self.head_dim ** -0.5 if self.scale is None \
             else float(self.scale)
+
+    @property
+    def v_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def rot_dim(self) -> int:
+        return self.rope_dim or self.head_dim
+
+    @property
+    def packed(self) -> bool:
+        """K and V of a token live in ONE row (``PackedKV``): V heads of
+        their own width cannot share ``PagedKV``'s one ``head_dim``."""
+        return self.v_head_dim is not None
+
+    @property
+    def row_width(self) -> int:
+        """Values a packed row STORES: every kv head's V, then every kv
+        head's K, padded to whole lane tiles."""
+        return _lanes(self.num_kv_heads * (self.head_dim + self.v_dim))
+
+    @property
+    def k_lanes(self) -> int:
+        """A K head as a window ring stores it: whole lane tiles."""
+        return _lanes(self.head_dim)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -159,16 +219,21 @@ class MambaSpec:
 
 @dataclasses.dataclass(frozen=True)
 class MoESpec:
-    """Routed experts of one FFN: the router scores ``num_experts``,
-    the ``top_k`` largest are taken and the gates are the softmax over
-    the chosen logits; ``experts_held = (first, count)`` names the
-    contiguous slice of experts this chip stores and computes (a pick
-    that names another expert adds nothing here)."""
+    """Routed experts of one FFN: the router scores ``num_experts``;
+    ``routing`` ``"softmax_topk"`` takes the ``top_k`` largest logits and
+    the softmax over them as gates, ``"sigmoid_bias"`` takes the top_k of
+    ``sigmoid(logits) + bias`` (a per-expert selection bias) and the
+    chosen sigmoid scores, renormalised, times ``scaling`` as gates;
+    ``experts_held = (first, count)`` names the contiguous slice of
+    experts this chip stores and computes (a pick that names another
+    expert adds nothing here)."""
     num_experts: int
     top_k: int
     expert_dim: int
     shared_dim: int = 0
     experts_held: Optional[Tuple[int, int]] = None
+    routing: str = "softmax_topk"
+    scaling: float = 1.0
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -193,6 +258,23 @@ class RecurrentSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class WindowRingSpec:
+    """Shapes of the slot-indexed ring the window layers keep beside the
+    paged pool: ``k [layers, slots, heads, rows, k_width]`` and ``v
+    [layers, slots, heads, rows, v_width]``; position ``p`` of a slot's
+    sequence lives in row ``p % rows``."""
+    layers: int
+    heads: int
+    rows: int
+    k_width: int
+    v_width: int
+
+    def bytes_per_slot(self, itemsize: int = 2) -> int:
+        return self.layers * self.heads * self.rows \
+            * (self.k_width + self.v_width) * itemsize
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerPattern:
     d_model: int
     period: Tuple[str, ...]
@@ -213,43 +295,88 @@ class LayerPattern:
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0        # logits are DIVIDED by it
+    #: the window_attention kind's widths, window and sink
+    window_attention: Optional[AttentionSpec] = None
+    #: kinds of the layers before the first period
+    lead: Tuple[str, ...] = ()
+    #: layers served (None: all of lead + period x n_periods)
+    depth: Optional[int] = None
+    #: the first layers whose FFN is dense (``d_ff``) instead of experts
+    dense_lead: int = 0
 
     def __post_init__(self):
-        bad = [k for k in self.period if k not in KINDS]
+        bad = [k for k in tuple(self.lead) + tuple(self.period)
+               if k not in KINDS]
         if bad or not self.period:
             raise ValueError(f"LayerPattern: unknown layer kinds {bad} "
                              f"(known: {', '.join(map(repr, KINDS))})")
-        if ATTENTION in self.period and self.attention is None:
+        full = len(self.lead) + len(self.period) * self.n_periods
+        if self.depth is not None and not 0 < self.depth <= full:
+            raise ValueError(f"LayerPattern: depth {self.depth} outside "
+                             f"1..{full}")
+        kinds = set(self.kinds())
+        if ATTENTION in kinds and self.attention is None:
             raise ValueError("LayerPattern: attention layers need an "
                              "AttentionSpec")
-        if LATENT in self.period and self.latent is None:
+        if LATENT in kinds and self.latent is None:
             raise ValueError("LayerPattern: latent_attention layers need "
                              "a LatentAttentionSpec")
-        if LATENT in self.period and ATTENTION in self.period:
+        if LATENT in kinds and ATTENTION in kinds:
             raise ValueError(
                 "LayerPattern: attention and latent_attention layers in "
                 "one pattern would need two paged pools; an engine owns "
                 "one")
-        if MAMBA in self.period and self.mamba is None:
+        if MAMBA in kinds and self.mamba is None:
             raise ValueError("LayerPattern: mamba layers need a "
                              "MambaSpec")
+        if WINDOW in kinds:
+            sw = self.window_attention
+            if sw is None or not sw.window:
+                raise ValueError("LayerPattern: window_attention layers "
+                                 "need an AttentionSpec with a window")
+            if MAMBA in kinds or LATENT in kinds:
+                raise ValueError(
+                    "LayerPattern: window_attention layers beside mamba "
+                    "or latent_attention layers would need two slot "
+                    "states or a latent pool beside the window ring; an "
+                    "engine owns one of each kind of cache")
+        if self.dense_lead and not (0 < self.dense_lead <= self.num_layers
+                                    and self.d_ff > 0):
+            raise ValueError("LayerPattern: dense_lead layers need d_ff "
+                             "and at most num_layers of them")
+        if self.moe is not None and self.moe.routing not in ROUTINGS:
+            raise ValueError(f"LayerPattern: routing {self.moe.routing!r} "
+                             f"(known: {', '.join(ROUTINGS)})")
 
     # ---------------------------------------------------- the layers
 
     @property
     def num_layers(self) -> int:
-        return len(self.period) * self.n_periods
+        return len(self.kinds())
 
     def kinds(self) -> Tuple[str, ...]:
-        return tuple(self.period) * self.n_periods
+        kinds = tuple(self.lead) + tuple(self.period) * self.n_periods
+        return kinds if self.depth is None else kinds[:self.depth]
 
     def count(self, kind: str) -> int:
-        return self.period.count(kind) * self.n_periods
+        return self.kinds().count(kind)
 
     def kind_index(self, layer: int) -> int:
         """Index of ``layer`` inside its own kind's stacks."""
         kinds = self.kinds()
         return sum(1 for k in kinds[:layer] if k == kinds[layer])
+
+    def moe_index(self, layer: int) -> int:
+        """Index of an expert layer inside the experts' stacks."""
+        return layer - self.dense_lead
+
+    @property
+    def n_moe(self) -> int:
+        return self.num_layers - self.dense_lead
+
+    @property
+    def n_window(self) -> int:
+        return self.count(WINDOW)
 
     @property
     def n_attention(self) -> int:
@@ -273,6 +400,37 @@ class LayerPattern:
     @property
     def n_paged(self) -> int:
         return self.n_latent or self.n_attention
+
+    @property
+    def pool_row_width(self) -> Optional[int]:
+        """Width of the pool's ONE array of rows (a latent pool, a pool
+        of packed K|V rows), or None for ``PagedKV``'s K and V sides."""
+        if self.n_latent:
+            return self.latent.row_width
+        if self.n_attention and self.attention.packed:
+            return self.attention.row_width
+        return None
+
+    @property
+    def slot_state(self) -> Optional[str]:
+        """What a slot keeps beside the pool, the one answer the stack,
+        its programs and the engines read: ``SLOT_RECURRENT`` (the mamba
+        layers' ``RecurrentState``, zeroed at a sequence's first chunk),
+        ``SLOT_RING`` (the window layers' ``WindowRing``, whose rows the
+        sequence's positions keep apart) or None."""
+        if self.n_mamba:
+            return SLOT_RECURRENT
+        return SLOT_RING if self.n_window else None
+
+    @property
+    def window_ring(self) -> Optional[WindowRingSpec]:
+        """What the cache manager holds a slot for the window layers, or
+        None for a pattern without any."""
+        if not self.n_window:
+            return None
+        sw = self.window_attention
+        return WindowRingSpec(self.n_window, sw.num_kv_heads, sw.window,
+                              sw.k_lanes, sw.v_dim)
 
     @property
     def recurrent(self) -> Optional[RecurrentSpec]:

@@ -21,7 +21,7 @@ import numpy as np
 from ..core.tensor import Tensor
 from ..incubate.nn.fused_transformer import (
     FusedMultiTransformer, PagedKV, rope_table)
-from ..incubate.nn.layer_pattern import LATENT
+from ..incubate.nn.layer_pattern import LATENT, SLOT_RECURRENT, SLOT_RING
 from ..nn.layer_base import Layer
 from ..profiler import RecordEvent
 from ..profiler import roofline as _roofline
@@ -31,7 +31,7 @@ from .kv_cache import BlockKVCacheManager, gather_rows, restore_scatter_jit
 __all__ = ["FusedCausalLM", "GenerationEngine",
            "ContinuousBatchingEngine", "GenRequest",
            "DEFAULT_DECODE_CHUNK", "RecurrentStateUnsupported",
-           "LatentPoolUnsupported"]
+           "LatentPoolUnsupported", "WindowCacheUnsupported"]
 
 
 class RecurrentStateUnsupported(NotImplementedError):
@@ -65,6 +65,24 @@ def _refuse_latent(what: str):
         f"{what} is written for K and V pages side by side; this model's "
         "pool holds one latent row a token and nothing here has been "
         "shown to move or share those")
+
+
+class WindowCacheUnsupported(NotImplementedError):
+    """A path that moves, shares or re-types K/V pages was asked of a model
+    whose window layers keep a slot-indexed ring beside a pool of packed
+    K|V rows (``WindowRing``, ``PackedKV``): prefix reuse, host-tier
+    spill, slot export / import, page streaming, speculative verify, the
+    int8 pool. Pages alone would leave the ring behind, and nothing here
+    moves packed rows, so each refuses instead of guessing. Counted in
+    ``serving.window.refusals``."""
+
+
+def _refuse_window(what: str):
+    _stats.inc("serving.window.refusals")
+    raise WindowCacheUnsupported(
+        f"{what} moves K/V pages only; this model's window layers keep a "
+        "slot-indexed ring beside a pool of packed K|V rows that it would "
+        "leave behind")
 
 #: auto-picked decode scan-chunk: 128 measured best on the 1.3B bench
 #: geometry (chunk 64 -> 128: +7% tok/s, bench_profile.json r5 — one
@@ -714,12 +732,19 @@ class ContinuousBatchingEngine:
         # None) after the pool and return pick counts beside their result
         self._pattern_built = getattr(gen_cls, "pattern_built", False)
         self._latent = pattern.paged_kind == LATENT
-        if self._latent and (kv_dtype == "int8" or kv_dtype == jnp.int8):
+        # the pool is ONE array of rows (latent, packed K|V)
+        self._rows = pattern.pool_row_width is not None
+        # window layers: a slot-indexed ring beside the pool
+        self._window = pattern.slot_state == SLOT_RING
+        int8 = kv_dtype == "int8" or kv_dtype == jnp.int8
+        if self._latent and int8:
             _refuse_latent("the int8 cache-KV pool")
+        if self._window and int8:
+            _refuse_window("the int8 cache-KV pool")
         self._pages_per_seq = -(-self.max_length // self.page_size)
         requested = (num_pages or self.max_batch * self._pages_per_seq) + 1
         tp = self._gen._tp
-        heads, width = (1, pattern.latent.row_width) if self._latent \
+        heads, width = (1, pattern.pool_row_width) if self._rows \
             else (att.num_kv_heads, att.head_dim)
         self._mgr = BlockKVCacheManager(
             pattern.n_paged, heads, width, self.page_size,
@@ -728,13 +753,14 @@ class ContinuousBatchingEngine:
             mp_degree=tp.mp if tp else 1,
             mesh=tp.mesh if tp else None,
             recurrent=recurrent, slots=self.max_batch,
-            latent=self._latent)
+            latent=self._latent, packed=self._rows and not self._latent,
+            window=pattern.window_ring)
         _stats.set_gauge("serving.pool_pages_requested", requested)
         _stats.set_gauge("serving.pool_pages", self._mgr.num_pages)
         cache = self._mgr.fresh_cache()
-        # the pool's two operands: K and V sides, or the one latent pool
+        # the pool's two operands: K and V sides, or the one pool of rows
         # and None
-        self._ck, self._cv = (cache.rows, None) if self._latent \
+        self._ck, self._cv = (cache.rows, None) if self._rows \
             else (cache.k, cache.v)
         # None on a model without recurrent layers
         self._rs = self._mgr.fresh_recurrent_state(self._gen._cdtype)
@@ -745,6 +771,11 @@ class ContinuousBatchingEngine:
         elif recurrent is not None:
             _stats.set_gauge("serving.recurrent.state_bytes",
                              recurrent.bytes_per_slot() * self.max_batch)
+        elif self._window:
+            _stats.set_gauge("serving.window.ring_bytes",
+                             pattern.window_ring.bytes_per_slot(
+                                 jnp.dtype(self._gen._kv_dtype).itemsize)
+                             * self.max_batch)
         self._gen._mgr = self._mgr
 
         self.waiting: list = []
@@ -782,6 +813,9 @@ class ContinuousBatchingEngine:
         # heads), a Drafter instance, or a small FusedCausalLM draft
         # model; ``spec_k`` defaults to FLAGS_spec_k.
         self._spec = None
+        if speculative and self._window:
+            _refuse_window("speculative verify (rejected drafts roll "
+                           "the page table back)")
         if speculative and recurrent is not None:
             _refuse_recurrent("speculative verify (rejected drafts roll "
                               "the page table back)")
@@ -918,14 +952,23 @@ class ContinuousBatchingEngine:
                        int(named.sum()) * layers)
             _stats.inc("serving.kv.pages_region",
                        self._mgr.num_pages * layers * k)
-            if self._latent:
-                # latent rows the decode kernel reads in this chunk, a
+            if self._rows:
+                # rows of the pool the decode kernel reads in this chunk, a
                 # step and layer: every cached token of every decoding
-                # row, once for all heads
+                # row, once for all heads (latent rows, or the full
+                # layers' packed rows)
                 rows = (cur[:, None] + np.arange(k)[None, :])[
                     [r is not None for r in self._slots]]
-                _stats.inc("serving.mla.rows_read",
+                _stats.inc("serving.mla.rows_read" if self._latent
+                           else "serving.attn.rows_read",
                            int(rows.sum()) * layers)
+            if self._window:
+                # ring rows the window decode kernel reads in this chunk:
+                # every slot's whole ring, a step and window layer,
+                # whatever the sequences' lengths
+                wr = self.model.stack.pattern.window_ring
+                _stats.inc("serving.window.rows_read",
+                           self.max_batch * wr.rows * wr.layers * k)
         lnf_s, lnf_b = self._gen._lnf()
         a_slots, a_banks = self._adapter_operands(active)
         adaptered = a_banks is not None
@@ -1066,9 +1109,11 @@ class ContinuousBatchingEngine:
 
     def _needs_kv_pages(self, what: str):
         """Refuse ``what`` by type where K/V pages are not the whole of a
-        sequence's cached state (recurrent layers) or the pool holds no K/V
-        pages at all (a latent pool)."""
-        if self._rs is not None:
+        sequence's cached state (recurrent layers, a window ring) or the
+        pool holds no K/V pages at all (a latent pool)."""
+        if self._window:
+            _refuse_window(what)
+        if self._mgr.slot_state == SLOT_RECURRENT:
             _refuse_recurrent(what)
         if self._latent:
             _refuse_latent(what)
@@ -1079,8 +1124,8 @@ class ContinuousBatchingEngine:
         planes and TP pools shard by kv-head — both fall back to the
         preemption-by-recompute path on a fleet drain."""
         return not isinstance(self._ck, tuple) \
-            and self._mgr._mesh is None and self._rs is None \
-            and not self._latent
+            and self._mgr._mesh is None and self._mgr.slot_state is None \
+            and not self._rows
 
     def export_slot(self, i: int) -> dict:
         """Export decode slot ``i``'s live state for page-granular
@@ -1262,8 +1307,8 @@ class ContinuousBatchingEngine:
         """Host-DRAM spill/restore supports plain AND int8 pools; only
         TP kv-head-sharded pools fall back (a one-shard blob could not
         restore into a differently-sharded peer pool)."""
-        return self._mgr._mesh is None and self._rs is None \
-            and not self._latent
+        return self._mgr._mesh is None and self._mgr.slot_state is None \
+            and not self._rows
 
     def _scale_cols(self, rows_np: np.ndarray) -> np.ndarray:
         """Scale-plane columns of the given pool rows: row r position t

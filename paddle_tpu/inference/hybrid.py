@@ -12,10 +12,12 @@ admit / plan / run / emit framing of a step.
 
 ``HybridPrograms`` is that family: the chunked-prefill and decode-chunk
 programs with one more donated operand, the slot-indexed
-``RecurrentState`` (None where the pattern has no recurrent layer),
-which both return rebound; the pool's two operands hold ``PagedKV``'s K
-and V sides or, for a latent-attention pattern, the ONE latent pool and
-None. Their XLA module names
+``RecurrentState`` or, for a pattern with window layers, ``WindowRing``
+(None where the pattern has neither), which both return rebound; the
+pool's two operands hold ``PagedKV``'s K and V sides or the ONE pool of
+rows (``LatentKV``, ``PackedKV``) and None. A pattern with window
+layers has two rotary tables: the full layers' and the window layers'.
+Their XLA module names
 are fixed here (``PREFILL_PROGRAM_NAME`` / ``DECODE_PROGRAM_NAME``), not
 taken from whatever the Python methods happen to be called, so a trace
 reader's pattern survives a rename.
@@ -29,7 +31,9 @@ import jax.numpy as jnp
 
 from ..incubate.nn.fused_transformer import PagedKV, rope_table
 from ..incubate.nn.hybrid_stack import HybridStack, RecurrentState
-from ..incubate.nn.layer_pattern import LATENT, LayerPattern
+from ..incubate.nn.layer_pattern import (LATENT, SLOT_RECURRENT,
+                                         SLOT_RING, LayerPattern)
+from ..nn.functional.mixed_attention import PackedKV
 from ..nn.functional.mla_attention import LatentKV, yarn_rope_table
 from ..nn.layer_base import Layer
 from ..profiler import roofline as _roofline
@@ -88,6 +92,8 @@ class HybridPrograms(GenerationEngine):
         pat = st.pattern
         self._latent = pat.paged_kind == LATENT
         att = pat.attention
+        self._rows_cls = LatentKV if self._latent else (
+            PackedKV if pat.pool_row_width is not None else None)
         if self._latent:
             lt = pat.latent
             self._cos, self._sin = yarn_rope_table(
@@ -95,15 +101,27 @@ class HybridPrograms(GenerationEngine):
                 lt.yarn)
         elif att is not None and att.rope_theta is not None:
             self._cos, self._sin = rope_table(
-                self.max_length + 1, att.head_dim, att.rope_theta)
+                self.max_length + 1, att.rot_dim, att.rope_theta)
         else:
             self._cos = self._sin = None
+        sw = pat.window_attention if pat.n_window else None
+        # the window layers' own table, or None
+        self._rope_w = rope_table(self.max_length + 1, sw.rot_dim,
+                                  sw.rope_theta) \
+            if sw is not None and sw.rope_theta is not None else None
 
     def _cache(self, ck, cv):
-        return LatentKV(ck) if self._latent else PagedKV(ck, cv)
+        return self._rows_cls(ck) if self._rows_cls is not None \
+            else PagedKV(ck, cv)
 
     def _sides(self, cache):
-        return (cache.rows, None) if self._latent else (cache.k, cache.v)
+        return (cache.rows, None) if self._rows_cls is not None \
+            else (cache.k, cache.v)
+
+    def _window_kw(self, **kw):
+        """The phases' window-layer arguments, none without such layers."""
+        return dict(kw, rope_w=self._rope_w) \
+            if self.model.stack.pattern.n_window else {}
 
     def _weights(self):
         return self.model.stack._stack()
@@ -156,9 +174,15 @@ class HybridPrograms(GenerationEngine):
         ``((token [1], counts), ck, cv, rs)``: the greedy pick at the
         last valid row (meaningful on a prompt's final chunk)."""
         st = self.model.stack
+        kind = st.pattern.slot_state
         s = slot[0]
         state = None
-        if rs is not None:
+        if kind == SLOT_RING:
+            # the ring is read and written at the slot's rows inside the
+            # layers: positions, not a reset, keep a new sequence off the
+            # last occupant's rows
+            state = rs
+        elif kind == SLOT_RECURRENT:
             ssm = jax.lax.dynamic_index_in_dim(rs.ssm, s, 1, False)
             conv = jax.lax.dynamic_index_in_dim(rs.conv, s, 1, False)
             state = (jnp.where(fresh[0], jnp.zeros_like(ssm), ssm),
@@ -166,8 +190,10 @@ class HybridPrograms(GenerationEngine):
         x = self._embed_rows(embed, ids)
         h, cache, state, counts = st.prefill_chunk_raw(
             weights, x, self._cache(ck, cv), state, tables, start,
-            chunk_len, self._cos, self._sin)
-        if rs is not None:
+            chunk_len, self._cos, self._sin, **self._window_kw(slot=slot))
+        if kind == SLOT_RING:
+            rs = state
+        elif kind == SLOT_RECURRENT:
             rs = RecurrentState(
                 jax.lax.dynamic_update_index_in_dim(rs.ssm, state[0], s, 1),
                 jax.lax.dynamic_update_index_in_dim(rs.conv, state[1], s,
@@ -188,7 +214,7 @@ class HybridPrograms(GenerationEngine):
             x = self._embed_rows(embed, tok)
             h, cache, rs, c = st.decode_raw(
                 weights, x, self._cache(ck, cv), rs, tables, lens, active,
-                self._cos, self._sin)
+                self._cos, self._sin, **self._window_kw())
             nxt = self._argmax(self._logits(h, head, norm_s))
             return (nxt, lens + 1, *self._sides(cache), rs,
                     counts + c), nxt
@@ -207,13 +233,14 @@ class HybridPrograms(GenerationEngine):
 class HybridCausalLM(Layer):
     """Token embedding + ``HybridStack`` + final RMSNorm + the head (the
     embedding itself, or ``head [vocab, d]`` where the pattern says
-    ``tie_embeddings=False``)."""
+    ``tie_embeddings=False``). ``draw=False`` starts every drawn
+    parameter as zeros (``HybridStack``)."""
 
     #: the program family the engines build for this model
     _gen_cls = HybridPrograms
 
     def __init__(self, vocab_size: int, pattern: LayerPattern,
-                 dtype=jnp.float32):
+                 dtype=jnp.float32, draw: bool = True):
         super().__init__()
         from ..core.generator import default_generator
         from ..core.tensor import Parameter
@@ -221,13 +248,15 @@ class HybridCausalLM(Layer):
         self.vocab_size = vocab_size
         from ..incubate.nn.hybrid_stack import _draw
 
-        self.embed = Parameter(_draw(
-            default_generator().next_key(),
-            (vocab_size, pattern.d_model), jnp.dtype(dtype), 0.02))
+        def table():
+            shape, dt = (vocab_size, pattern.d_model), jnp.dtype(dtype)
+            if not draw:
+                return jnp.zeros(shape, dt)
+            return _draw(default_generator().next_key(), shape, dt, 0.02)
+
+        self.embed = Parameter(table())
         if not pattern.tie_embeddings:
-            self.head = Parameter(_draw(
-                default_generator().next_key(),
-                (vocab_size, pattern.d_model), jnp.dtype(dtype), 0.02))
-        self.stack = HybridStack(pattern, dtype=dtype)
+            self.head = Parameter(table())
+        self.stack = HybridStack(pattern, dtype=dtype, draw=draw)
         self.norm_scale = Parameter(
             jnp.ones((pattern.d_model,), jnp.float32))

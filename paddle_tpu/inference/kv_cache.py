@@ -17,6 +17,16 @@ is the normed latent, the rope key and the pad to whole lane tiles),
 never K and V side by side. Allocation, growth, refcounts and block
 tables do not know the difference; the paths that move page CONTENTS
 (``phys_rows`` users) are the engines' and refuse a latent pool by type.
+``packed=True`` is the same one-array geometry holding packed K|V rows
+(``PackedKV``: full-attention layers whose V heads are narrower than
+their K heads).
+
+``window=`` (a ``LayerPattern.window_ring`` spec) adds the window
+layers' slot-indexed ring (``WindowRing``) beside the pool: ``window``
+rows a slot and layer whatever the sequence's length, allocated with
+the slot. A row's position follows from the sequence's own length, so
+admission, preemption and finishing clear nothing: rows of a slot's
+earlier occupant lie at positions the new sequence masks.
 """
 from __future__ import annotations
 
@@ -27,6 +37,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..incubate.nn.fused_transformer import PagedKV
+from ..incubate.nn.layer_pattern import SLOT_RECURRENT, SLOT_RING
+from ..nn.functional.mixed_attention import PackedKV, WindowRing
 from ..nn.functional.mla_attention import LatentKV
 
 __all__ = ["BlockKVCacheManager", "restore_scatter",
@@ -77,10 +89,14 @@ class BlockKVCacheManager:
                  page_size: int = 16, num_pages: int = 512,
                  dtype=jnp.float32, reserve_scratch: bool = False,
                  mp_degree: int = 1, mesh=None, mp_axis: str = "mp",
-                 recurrent=None, slots: int = 0, latent: bool = False):
+                 recurrent=None, slots: int = 0, latent: bool = False,
+                 packed: bool = False, window=None):
         self.num_layers = num_layers
-        # one array family a token row (``LatentKV``) instead of K and V
+        # one array family a token row (``LatentKV`` / ``PackedKV``)
+        # instead of K and V
         self.latent = bool(latent)
+        self.packed = bool(packed)
+        self.window = window
         # recurrent layers (a ``LayerPattern.recurrent`` spec): their
         # state is indexed by decode SLOT, not by page — allocated with
         # the slot, zeroed at admission, carried from prefill chunk to
@@ -124,11 +140,13 @@ class BlockKVCacheManager:
             self.kv_heads_per_shard = num_kv_heads
             self.kv_replication = 1
         self._pool_heads = self.kv_heads_per_shard * self.mp_degree
-        if self.latent and (self._mesh is not None or self.dtype == "int8"
-                            or self.dtype == jnp.int8):
+        if (self.latent or self.packed) and (
+                self._mesh is not None or self.dtype == "int8"
+                or self.dtype == jnp.int8):
             raise NotImplementedError(
-                "a latent pool is served unsharded in bf16 / f32: its row "
-                "has no kv-head axis to shard and no int8 kernel")
+                "a latent pool (or one of packed K|V rows) is served "
+                "unsharded in bf16 / f32: its row has no kv-head axis to "
+                "shard and no int8 kernel")
         if self._mesh is not None and \
                 (self.dtype == "int8" or self.dtype == jnp.int8):
             raise NotImplementedError(
@@ -148,10 +166,10 @@ class BlockKVCacheManager:
         self._faults = None
 
     def fresh_cache(self):
-        if self.latent:
-            return LatentKV(jnp.zeros(
-                (self.num_layers * self.num_pages, self.page_size,
-                 self.head_dim), self.dtype))
+        if self.latent or self.packed:
+            rows = jnp.zeros((self.num_layers * self.num_pages,
+                              self.page_size, self.head_dim), self.dtype)
+            return LatentKV(rows) if self.latent else PackedKV(rows)
         # layer-FOLDED page-major pool (see PagedKV): layer l's logical
         # page p is physical page l * num_pages + p — decode updates it
         # in place; each page is one contiguous DMA block.
@@ -189,11 +207,28 @@ class BlockKVCacheManager:
         return PagedKV(jnp.zeros(shape, self.dtype),
                        jnp.zeros(shape, self.dtype))
 
+    @property
+    def slot_state(self):
+        """What a slot keeps beside the pages, as the pattern that gave
+        ``recurrent=`` / ``window=`` says (``LayerPattern.slot_state``):
+        ``SLOT_RECURRENT``, ``SLOT_RING`` or None."""
+        if self.window is not None:
+            return SLOT_RING
+        return SLOT_RECURRENT if self.recurrent is not None else None
+
     def fresh_recurrent_state(self, conv_dtype=None):
         """The slot-indexed state of the recurrent layers, zeros, or None
         for a pattern without any (``RecurrentState``: ssm float32
         ``[layers, slots, d_state, d_inner]``, conv tail in the compute
-        dtype)."""
+        dtype); for a pattern with window layers their ring
+        (``WindowRing`` in the pool's dtype)."""
+        w = self.window
+        if w is not None:
+            return WindowRing(
+                jnp.zeros((w.layers, self.slots, w.heads, w.rows,
+                           w.k_width), self.dtype),
+                jnp.zeros((w.layers, self.slots, w.heads, w.rows,
+                           w.v_width), self.dtype))
         r = self.recurrent
         if r is None:
             return None
@@ -237,7 +272,7 @@ class BlockKVCacheManager:
         moves roughly half the bytes of its bf16 equivalent."""
         elems = (self.num_layers * self._pool_heads
                  * self.page_size * self.head_dim)
-        if self.latent:
+        if self.latent or self.packed:
             # one array: the row as STORED, pad lanes included
             return elems * jnp.dtype(self.dtype).itemsize
         if self.dtype == "int8" or self.dtype == jnp.int8:

@@ -45,7 +45,7 @@ from .paged_attention import _enable_x64, stream_chunk_pages
 __all__ = ["LatentKV", "yarn_inv_freq", "yarn_rope_table",
            "rope_interleaved", "query_temperature", "mla_prefill_attend",
            "prefill_tile_tokens", "prefill_keys_expanded",
-           "mla_decode_attend"]
+           "mla_decode_attend", "decode_rows"]
 
 _NEG = -1e30
 #: pool tokens a prefill tile expands and scores at a time (whole pages)
@@ -421,8 +421,27 @@ def mla_decode_attend(q, new_rows, pool, block_tables, seq_lens,
     no-op write (it still attends to its own token from the operand).
     Returns ``(out [S, H, v_width] float32, pool')``.
     """
+    def launch(kernel, spec, operands):
+        from jax.experimental import pallas as pl
+        with _enable_x64(False), jax.named_scope("pt_mla_paged_decode"):
+            return pl.pallas_call(kernel, name="pt_mla_paged_decode",
+                                  **spec)(*operands)
+
+    return decode_rows(q, new_rows, pool, block_tables, seq_lens,
+                       pool_base, v_width=v_width, backend=backend,
+                       launch=launch, who="mla_decode_attend")
+
+
+def decode_rows(q, new_rows, pool, block_tables, seq_lens, pool_base, *,
+                v_width: int, backend: str, launch, who: str):
+    """The decode of a layer-folded pool whose query scores a row with ONE
+    dot (arguments as ``mla_decode_attend``'s). Off the chip (``backend``
+    "auto") the XLA path; else the kernel, handed to ``launch(kernel,
+    spec, operands)``, which makes the ``pallas_call`` under its caller's
+    own name and returns ``(out, pool')``; ``who`` names the caller in an
+    error."""
     if backend not in ("auto", "interpret"):
-        raise ValueError(f"mla_decode_attend backend={backend!r}: "
+        raise ValueError(f"{who} backend={backend!r}: "
                          "expected 'auto' or 'interpret'")
     lens = seq_lens.astype(jnp.int32)
     tables = block_tables.astype(jnp.int32)
@@ -549,20 +568,16 @@ def mla_decode_attend(q, new_rows, pool, block_tables, seq_lens,
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ])
-    with _enable_x64(False), jax.named_scope("pt_mla_paged_decode"):
-        out, pool = pl.pallas_call(
-            kernel,
-            name="pt_mla_paged_decode",
-            grid_spec=grid_spec,
-            out_shape=[jax.ShapeDtypeStruct((S, H, R), jnp.float32),
-                       jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
-            # inputs are numbered with the two scalar-prefetch operands
-            # first: the pool is arg 5
-            input_output_aliases={5: 1},
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("arbitrary",),
-                vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
-            interpret=not _chip.on_tpu(),
-        )(meta, tables.reshape(-1), q.astype(pool.dtype), nr, slotmask,
-          pool)
-    return out, pool
+    return launch(
+        kernel,
+        dict(grid_spec=grid_spec,
+             out_shape=[jax.ShapeDtypeStruct((S, H, R), jnp.float32),
+                        jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+             # inputs are numbered with the two scalar-prefetch operands
+             # first: the pool is arg 5
+             input_output_aliases={5: 1},
+             compiler_params=pltpu.CompilerParams(
+                 dimension_semantics=("arbitrary",),
+                 vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
+             interpret=not _chip.on_tpu()),
+        (meta, tables.reshape(-1), q.astype(pool.dtype), nr, slotmask, pool))

@@ -2,7 +2,11 @@
 expert layer that is told which experts it holds.
 
 Routing: ``l = x W_r`` in float32 over ALL experts, the ``top_k``
-largest, gates = softmax over the chosen logits. An expert is
+largest, gates = softmax over the chosen logits (``route_topk_softmax``);
+or ``s = sigmoid(l)``, the ``top_k`` largest of ``s + b`` (a per-expert
+selection bias that picks experts and never weights them), gates = the
+chosen ``s`` over their sum times a scaling factor
+(``route_topk_sigmoid``). An expert is
 ``e(x) = W2_e (silu(a) * b)`` with ``[a | b] = x W1_e``; the layer's
 output is the gate-weighted sum over the picks that name an expert
 held HERE (``held = (first, count)``: the contiguous slice of the bank
@@ -35,7 +39,7 @@ from ...device.vmem import KERNEL_VMEM_LIMIT_BYTES
 from .grouped_gemm import grouped_gemm_banked
 from .paged_attention import _enable_x64
 
-__all__ = ["route_topk_softmax", "dense_gates", "pick_counts",
+__all__ = ["route_topk_softmax", "route_topk_sigmoid", "dense_gates", "pick_counts",
            "moe_gated_grouped", "moe_gated_stream", "gated_mlp"]
 
 
@@ -51,6 +55,22 @@ def route_topk_softmax(x, router_w, top_k: int):
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     val, idx = jax.lax.top_k(logits, top_k)
     return jax.nn.softmax(val, axis=-1), idx.astype(jnp.int32)
+
+
+def route_topk_sigmoid(x, router_w, bias, top_k: int, scaling=1.0):
+    """x ``[T, d]``, ``router_w [d, E]``, ``bias [E]`` -> ``(gates [T, k]
+    f32, idx [T, k] int32)``: float32 sigmoid scores over all E, the k
+    largest of scores + bias, the chosen SCORES (the bias left out) over
+    their sum, times ``scaling``."""
+    # tpu-lint: ok(X-PROMOTE) -- fp32 routing by design (top-k margins)
+    logits = jax.lax.dot_general(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    s = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32)[None, :], top_k)
+    val = jnp.take_along_axis(s, idx, axis=-1)
+    gates = val / jnp.sum(val, axis=-1, keepdims=True) * scaling
+    return gates, idx.astype(jnp.int32)
 
 
 def dense_gates(gates, idx, held):

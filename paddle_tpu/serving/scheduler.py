@@ -80,7 +80,9 @@ import numpy as np
 
 from ..core.flags import flag as _flag
 from ..incubate.nn.fused_transformer import PagedKV
+from ..incubate.nn.layer_pattern import SLOT_RECURRENT
 from ..inference.engine import ContinuousBatchingEngine, FusedCausalLM
+from ..nn.functional.mixed_attention import window_pairs
 from ..nn.functional.mla_attention import prefill_keys_expanded
 from ..profiler import RecordEvent
 from ..profiler import roofline as _roofline
@@ -91,7 +93,8 @@ from .faults import (DeadlineExceeded, PoolSizingError, ServerOverloaded,
                      TokenCorruption, WatchdogTimeout)
 from .journal import FlightRecorder
 from .prefix_cache import PrefixCache
-from ..inference.engine import _refuse_latent, _refuse_recurrent
+from ..inference.engine import (_refuse_latent, _refuse_recurrent,
+                                _refuse_window)
 from .request import Request
 from .slo import SLOMonitor
 
@@ -243,7 +246,12 @@ class ServingEngine(ContinuousBatchingEngine):
         self.last_crash_dump: Optional[str] = None
         self.prefix_cache: Optional[PrefixCache] = None
         want_prefix = slo.prefix_cache
-        if self._rs is not None:
+        if self._window:
+            if want_prefix:
+                _refuse_window("SLOConfig(prefix_cache=True): prefix "
+                               "reuse")
+            want_prefix = False
+        elif self._mgr.slot_state == SLOT_RECURRENT:
             if want_prefix:
                 _refuse_recurrent("SLOConfig(prefix_cache=True): prefix "
                                   "reuse")
@@ -1300,7 +1308,7 @@ class ServingEngine(ContinuousBatchingEngine):
                 # credit (the prefix-cache's own refs charge nobody)
                 u.credit_prefix(req, len(shared))
                 u.set_pages(req, len(shared), now=now)
-        if self._rs is not None:
+        if self._mgr.slot_state == SLOT_RECURRENT:
             # the slot's recurrent state is dead from here: this
             # sequence's first chunk starts from zeros, also when it is
             # a preempted request coming back for recompute
@@ -1591,7 +1599,7 @@ class ServingEngine(ContinuousBatchingEngine):
             # which slot's state the chunk continues, and whether it
             # starts from zeros (the first chunk after an admission)
             fresh = self._mgr.recurrent_is_fresh(i)
-            if self._rs is not None and not fresh:
+            if self._mgr.slot_state == SLOT_RECURRENT and not fresh:
                 _stats.inc("serving.recurrent.resumed_chunks")
             extra = (jnp.asarray([i], jnp.int32), jnp.asarray([fresh]))
         if self._latent:
@@ -1606,6 +1614,15 @@ class ServingEngine(ContinuousBatchingEngine):
                        prefill_keys_expanded(stt.pos, n, self.page_size,
                                              self._pages_per_seq)
                        * self.model.stack.pattern.latent.num_heads * layers)
+        if self._window:
+            # query-key pairs of this chunk's real rows, a layer: causal
+            # in the full layers, at most ``window`` a row in the others
+            pat = self.model.stack.pattern
+            _stats.inc("serving.attn.prefill_pairs",
+                       (n * stt.pos + n * (n + 1) // 2) * pat.n_attention)
+            _stats.inc("serving.window.prefill_pairs",
+                       window_pairs(stt.pos, n, pat.window_attention.window)
+                       * pat.n_window)
         lead = (self._gen._weights(), self._gen._embed(),
                 self._gen._head_t, lnf_s, lnf_b, jnp.asarray(ids),
                 jnp.asarray([stt.pos], jnp.int32),

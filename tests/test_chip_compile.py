@@ -58,6 +58,10 @@ KERNELS = [
     "moe.stream_experts",
     "mla.paged_prefill",
     "mla.paged_decode",
+    "gqa.paged_prefill",
+    "gqa.paged_decode",
+    "swa.prefill",
+    "swa.decode",
 ]
 
 L, D, DFF, NQ, HEADS, HEAD_DIM, PAGE = 24, 2048, 8192, 3 * 2048, 16, 128, 16
@@ -550,3 +554,97 @@ def test_both_stacks_launch_the_same_decode_attention(stack, monkeypatch):
     monkeypatch.setattr(chip, "on_tpu", lambda: False)
     attn, masks = trace()
     assert not attn and not masks
+
+
+def _mixed_program(phase: str, c: int):
+    """``HybridStack`` over MiMo-V2-Flash's first two published layers at
+    their widths and its cell's shapes (32 rows or a ``c``-token chunk,
+    tables of 2,176 pages, a packed pool of 69,696 pages a full layer,
+    ``[69696, 16, 1280]``, a ring of 32 slots x 8 heads x 128 rows a window
+    layer, both donated), cut to 4 held experts of the 256: layer 0 full
+    attention over the dense MLP, layer 1 window attention over the
+    experts; the cell's other layers only repeat these."""
+    from paddle_tpu.incubate.nn.hybrid_stack import HybridStack
+    from paddle_tpu.incubate.nn.layer_pattern import (
+        ATTENTION, WINDOW, AttentionSpec, LayerPattern, MoESpec)
+    from paddle_tpu.nn.functional.mixed_attention import (PackedKV,
+                                                          WindowRing)
+
+    d, held, pages, pp = 4096, 4, 69696, 2176
+    A, W = ATTENTION, WINDOW
+    full = AttentionSpec(64, 4, 192, None, 5e6, v_head_dim=128, rope_dim=64,
+                         value_scale=0.707)
+    win = AttentionSpec(64, 8, 192, None, 1e4, v_head_dim=128, rope_dim=64,
+                        window=128, sink=True, value_scale=0.707)
+    p = LayerPattern(
+        d_model=d, lead=(A, W, W, W, W), period=(A, W, W, W, W, W),
+        n_periods=1, depth=2, attention=full, window_attention=win,
+        d_ff=16384, dense_lead=1,
+        moe=MoESpec(256, 8, 2048, experts_held=(0, held),
+                    routing="sigmoid_bias"),
+        norm="rmsnorm", gated=True, bias=False, activation="silu",
+        epsilon=1e-5, tie_embeddings=False)
+    st = object.__new__(HybridStack)
+    object.__setattr__(st, "pattern", p)
+    bf, f32 = jnp.bfloat16, jnp.float32
+    w = {"a_norm": ((1, d), f32), "qkv_weight": ((1, d, 13568), bf),
+         "out_weight": ((1, 8192, d), bf), "w_norm": ((1, d), f32),
+         "w_qkv": ((1, d, 14848), bf), "w_out": ((1, 8192, d), bf),
+         "w_sink": ((1, 64), f32), "f_norm": ((2, d), f32),
+         "d_w1": ((1, d, 32768), bf), "d_w2": ((1, 16384, d), bf),
+         "f_router": ((1, d, 256), f32), "e_bias": ((1, 256), f32),
+         "e_w1": ((1, held, d, 4096), bf),
+         "e_w2": ((1, held, 2048, d), bf)}
+    w = {n: _sds(*sd) for n, sd in w.items()}
+    pool = _sds((pages, PAGE, 1280), bf)
+    ring = (_sds((1, 32, 8, 128, 256), bf), _sds((1, 32, 8, 128, 128), bf))
+    rope = _sds((34817, 32), f32)
+    if phase == "decode":
+        def fn(w, x, rows, rk, rv, tables, lens, active, cos, sin):
+            h, cache, r, counts = st.decode_raw(
+                w, x, PackedKV(rows), WindowRing(rk, rv), tables, lens,
+                active, cos, sin, rope_w=(cos, sin))
+            return h, cache.rows, r.k, r.v, counts
+
+        args = (w, _sds((32, d), bf), pool, *ring,
+                _sds((32, pp), jnp.int32), _sds((32,), jnp.int32),
+                _sds((32,), jnp.bool_), rope, rope)
+    else:
+        def fn(w, x, rows, rk, rv, tables, start, lens, slot, cos, sin):
+            h, cache, r, counts = st.prefill_chunk_raw(
+                w, x, PackedKV(rows), WindowRing(rk, rv), tables, start,
+                lens, cos, sin, slot=slot, rope_w=(cos, sin))
+            return h, cache.rows, r.k, r.v, counts
+
+        args = (w, _sds((1, c, d), bf), pool, *ring,
+                _sds((1, pp), jnp.int32), _sds((1,), jnp.int32),
+                _sds((1,), jnp.int32), _sds((1,), jnp.int32), rope, rope)
+    return jax.jit(fn, donate_argnums=(2, 3, 4)), args, pool, ring
+
+
+@pytest.mark.parametrize("phase,c", [("prefill", 1024), ("prefill", 512),
+                                     ("decode", 0)])
+def test_mixed_programs_never_copy_the_pool_or_the_ring(phase, c, one_chip,
+                                                        as_on_chip):
+    """Both phases of a window / full stack at MiMo-V2-Flash's published
+    widths and its cell's shapes: the packed pool passes through the full
+    layers' kernel (aliased) and the ring through the window layers' (the
+    decode kernel aliases it; a prefill chunk updates its slot's rows in
+    place), nothing of either shape is copied, both stay donated, and the
+    program's temp stays far under the pool."""
+    jitted, args, pool, ring = _mixed_program(phase, c)
+    compiled = jitted.lower(*_placed(args, one_chip)).compile()
+    text = compiled.as_text()
+    assert not _pool_copies(text, pool)
+    assert not any(_pool_copies(text, r) for r in ring)
+    names = _kernel_names(text)
+    if phase == "decode":
+        assert names == {"pt_gqa_paged_decode", "pt_swa_decode",
+                         "pt_moe_stream_experts", "pt_stream_linear_bf16"}
+    else:
+        assert names == {"pt_gqa_paged_prefill", "pt_swa_prefill",
+                         "pt_grouped_gemm_fwd"}
+    sizes = [2 * math.prod(a.shape) for a in (pool, *ring)]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < sizes[0] // 4, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes == sum(sizes)

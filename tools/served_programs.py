@@ -128,7 +128,7 @@ def hybrid_programs(cfg):
     p = model.stack.pattern
     att, m = p.attention, p.mamba
     gen = _bare(HybridPrograms, model=model, _cdtype=dt, _cos=None,
-                _sin=None, _latent=False)
+                _sin=None, _latent=False, _rows_cls=None, _rope_w=None)
     pool = _sds((p.n_attention * pages, att.num_kv_heads, ps,
                  att.head_dim), dt)
     rs = RecurrentState(
@@ -154,7 +154,8 @@ def latent_programs(cfg):
     over as the engine closes over them, an untied head."""
     from benchmark.models.mistral4_mla import pattern
     from paddle_tpu.inference.hybrid import HybridCausalLM, HybridPrograms
-    from paddle_tpu.nn.functional.mla_attention import yarn_rope_table
+    from paddle_tpu.nn.functional.mla_attention import (LatentKV,
+                                                        yarn_rope_table)
 
     slots, pp, pages, ps, k, c = _geometry(cfg["serving"])
     dt = jnp.dtype(cfg.get("weights_dtype", "bfloat16"))
@@ -166,7 +167,7 @@ def latent_programs(cfg):
     cos, sin = yarn_rope_table(cfg["serving"]["engine"]["max_length"] + 1,
                                lt.qk_rope_head_dim, lt.rope_theta, lt.yarn)
     gen = _bare(HybridPrograms, model=model, _cdtype=dt, _cos=cos, _sin=sin,
-                _latent=True)
+                _latent=True, _rows_cls=LatentKV, _rope_w=None)
     pool = _sds((p.n_latent * pages, ps, lt.row_width), dt)
     # the head has the embedding's shape and type
     lead = (w, embed, embed, _sds((p.d_model,), f32), None)
@@ -183,9 +184,50 @@ def latent_programs(cfg):
              _sds((1,), jnp.bool_)))}
 
 
+def mixed_programs(cfg):
+    """``family: mimo_v2_flash``: ``HybridPrograms``' two programs over the
+    pool of packed K|V rows and the window layers' slot ring (donated in
+    the recurrent state's place), both rotary tables closed over as the
+    engine closes over them, an untied head."""
+    from benchmark.models.mimo_v2_flash import pattern
+    from paddle_tpu.incubate.nn.fused_transformer import rope_table
+    from paddle_tpu.inference.hybrid import HybridCausalLM, HybridPrograms
+    from paddle_tpu.nn.functional.mixed_attention import PackedKV, WindowRing
+
+    slots, pp, pages, ps, k, c = _geometry(cfg["serving"])
+    dt = jnp.dtype(cfg.get("weights_dtype", "bfloat16"))
+    f32, i32 = jnp.float32, jnp.int32
+    model, w, embed = _abstract_model(lambda: HybridCausalLM(
+        int(cfg["vocab_size"]), pattern(cfg), dtype=dt))
+    p = model.stack.pattern
+    full, win, ring = p.attention, p.window_attention, p.window_ring
+    n = cfg["serving"]["engine"]["max_length"] + 1
+    cos, sin = rope_table(n, full.rot_dim, full.rope_theta)
+    gen = _bare(HybridPrograms, model=model, _cdtype=dt, _cos=cos, _sin=sin,
+                _latent=False, _rows_cls=PackedKV,
+                _rope_w=rope_table(n, win.rot_dim, win.rope_theta))
+    pool = _sds((p.n_attention * pages, ps, full.row_width), dt)
+    rs = WindowRing(
+        _sds((ring.layers, slots, ring.heads, ring.rows, ring.k_width), dt),
+        _sds((ring.layers, slots, ring.heads, ring.rows, ring.v_width), dt))
+    lead = (w, embed, embed, _sds((p.d_model,), f32), None)
+    return {
+        "decode_chunk": (
+            jax.jit(functools.partial(gen._decode_k_fn, k=k),
+                    donate_argnums=(7, 9)),
+            (*lead, _sds((slots,), i32), _sds((slots,), i32), pool, None,
+             rs, _sds((slots, pp), i32), _sds((slots,), jnp.bool_))),
+        "prefill_chunk": (
+            jax.jit(gen._chunk_prefill_fn, donate_argnums=(8, 10)),
+            (*lead, _sds((1, c), i32), _sds((1,), i32), _sds((1,), i32),
+             pool, None, rs, _sds((1, pp), i32), _sds((1,), i32),
+             _sds((1,), jnp.bool_)))}
+
+
 FAMILIES = {"fused_causal_lm": uniform_programs,
             "granite_hybrid": hybrid_programs,
-            "mistral4_mla": latent_programs}
+            "mistral4_mla": latent_programs,
+            "mimo_v2_flash": mixed_programs}
 
 
 def lowered_text(jitted, args) -> str:
@@ -220,8 +262,8 @@ def lowered_text(jitted, args) -> str:
 
 
 def write_programs(out: str, configs=None) -> dict:
-    """Write every program of ``configs`` (names; default: all) under
-    ``out``; returns path -> text."""
+    """Write every program of ``configs`` (names; default: all whose family
+    this tree serves) under ``out``; returns path -> text."""
     os.makedirs(out, exist_ok=True)
     cfg_dir = os.path.join(ROOT, "benchmark", "configs")
     written = {}
