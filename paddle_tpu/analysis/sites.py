@@ -614,6 +614,31 @@ def _expected_moe_stream_experts():
             + _B((M, d), "float32"))             # the accumulated output
 
 
+# the routed experts' combine of a prefill chunk at MiMo-V2-Flash's
+# cell: 1,024 tokens x top-8 sorted rows of the second GEMM, d 4,096
+_COMBINE = dict(T=1024, k=8, d=4096, bm=128)
+
+
+def _build_moe_combine():
+    import jax.numpy as jnp
+
+    from paddle_tpu.nn.functional.moe_gated import moe_combine
+
+    T, k, d = (_COMBINE[n] for n in ("T", "k", "d"))
+
+    def fn(out, g, tok, live):
+        return moe_combine(out, g, tok, live, T)
+
+    return fn, (_sds((T * k, d), jnp.float32), _sds((T * k,), jnp.float32),
+                _sds((T * k,), jnp.int32), _sds((1,), jnp.int32))
+
+
+def _expected_moe_combine():
+    T, d, bm = (_COMBINE[n] for n in ("T", "d", "bm"))
+    return (_B((bm, d), "float32")               # sorted row tile (dynamic)
+            + _B((T, d), "float32"))             # the result (resident)
+
+
 # latent attention over the paged latent pool (ISSUE 37) at the published
 # widths of the configuration that drives it: 32 heads, a 384-lane row
 # (256 latent + 64 rope + 64 pad), page 16; a 256-token chunk attended in
@@ -904,6 +929,9 @@ KERNEL_SITES: List[KernelSite] = [
                _build_ssm_decode_update, _expected_ssm_decode_update),
     KernelSite("moe.stream_experts", "nn/functional/moe_gated.py",
                _build_moe_stream_experts, _expected_moe_stream_experts),
+    # the prefill rows' combine over the picks held here
+    KernelSite("moe.combine", "nn/functional/moe_gated.py",
+               _build_moe_combine, _expected_moe_combine),
     # latent attention over the paged latent pool (ISSUE 37): a prefill
     # chunk written in place and attended over its prefix, and the
     # one-token append + attend, both in the absorbed form

@@ -17,7 +17,8 @@ Two paths over the same weights ``w1 [E_held, d, 2f]`` /
 
 ``moe_gated_grouped``  prefill rows: the picks that landed here, sorted
     by expert, through the ragged grouped GEMM
-    (``pt_grouped_gemm_fwd``, twice).
+    (``pt_grouped_gemm_fwd``, twice), then combined into their tokens'
+    rows by ``pt_moe_combine``, which reads only those picks.
 ``moe_gated_stream``   decode rows: EVERY held expert's weights streamed
     once past all rows with the gates as a dense ``[rows, E_held]``
     matrix of mostly zeros (``pt_moe_stream_experts``). At 64 rows and
@@ -26,8 +27,9 @@ Two paths over the same weights ``w1 [E_held, d, 2f]`` /
     depend on the data; the MXU work over rows that did not pick the
     expert hides under the weight stream.
 
-Off the chip the stream path is a plain XLA loop over experts and the
-grouped GEMM takes its XLA tile walk: tier-1 needs no interpreter.
+Off the chip the stream path is a plain XLA loop over experts, the
+grouped GEMM takes its XLA tile walk and the combine scatters and sums
+all ``T x k`` picks: tier-1 needs no interpreter.
 """
 from __future__ import annotations
 
@@ -40,7 +42,7 @@ from .grouped_gemm import grouped_gemm_banked
 from .paged_attention import _enable_x64
 
 __all__ = ["route_topk_softmax", "route_topk_sigmoid", "dense_gates", "pick_counts",
-           "moe_gated_grouped", "moe_gated_stream", "gated_mlp"]
+           "moe_gated_grouped", "moe_combine", "moe_gated_stream", "gated_mlp"]
 
 
 def route_topk_softmax(x, router_w, top_k: int):
@@ -141,8 +143,93 @@ def moe_gated_grouped(x, gates, idx, w1, w2, layer: int, held,
     out, units2 = grouped_gemm_banked(mid, w2, offsets, base,
                                       backend=backend)         # [T*k, d]
     g = jnp.where(here, gates, 0.0).reshape(-1)[order]
-    y = jnp.zeros((T * k, d), jnp.float32).at[order].set(out * g[:, None])
-    return jnp.sum(y.reshape(T, k, d), axis=1), units1 + units2
+    # off the chip every pick is scattered and summed, the absent ones as
+    # zeros; the kernel reads only the rows below offsets[E]
+    if backend == "xla" or (backend == "auto" and not _chip.on_tpu()):
+        y = jnp.zeros((T * k, d), jnp.float32).at[order].set(
+            out * g[:, None])
+        return jnp.sum(y.reshape(T, k, d), axis=1), units1 + units2
+    y = moe_combine(out, g, order // k, offsets[count:], T,
+                    interpret=backend == "interpret" or not _chip.on_tpu())
+    return y, units1 + units2
+
+
+#: sorted rows a grid step of ``pt_moe_combine``
+_COMBINE_ROWS = 128
+
+
+def _combine_bn(T: int, d: int) -> int:
+    """The widest column block whose resident ``[T, bn]`` float32 result
+    and ``[128, bn]`` row tile, both double-buffered, take at most half of
+    ``KERNEL_VMEM_LIMIT_BYTES``: the whole width at the cells' shapes (36
+    MB at T 1,024, d 4,096). A narrower block costs a walk of the rows a
+    column block."""
+    bn = d
+    while bn % 256 == 0 and \
+            8 * (T + _COMBINE_ROWS) * bn > KERNEL_VMEM_LIMIT_BYTES // 2:
+        bn //= 2
+    return bn
+
+
+def moe_combine(out, g, tok, live, T: int, *, interpret=False):
+    """The routed experts' combine over the picks held here
+    (``pt_moe_combine``). ``out [R, d]`` float32: the second GEMM's rows,
+    sorted by expert; ``g [R]`` float32 their gates; ``tok [R]`` int32 the
+    token each row belongs to; ``live`` int32 ``[1]``: rows ``[0, live)``
+    name a held expert (``offsets[E]``). Returns ``[T, d]`` float32, each
+    live row's ``g[r] * out[r]`` added into row ``tok[r]`` in sorted
+    order.
+
+    The grid walks 128-row tiles of ``out`` with the ``[T, bn]`` result
+    resident in VMEM (zeroed at the first tile, written back once) and
+    adds one row at a time through a dynamic row store: the work follows
+    the live rows, not ``T x k``. A row at or past ``live`` is never read;
+    a tile past the last live row is never fetched (its block index
+    repeats the last live tile's, as the grouped GEMM's ``x`` tiles do)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    R, d = out.shape
+    bm = _COMBINE_ROWS
+    rp = -(-R // bm) * bm
+    if rp != R:
+        out = jnp.pad(out, ((0, rp - R), (0, 0)))
+    bn = _combine_bn(T, d)
+
+    def kernel(live_r, tok_r, g_r, out_ref, y_ref):
+        i = pl.program_id(1)
+
+        @pl.when(i == 0)
+        def _():
+            y_ref[...] = jnp.zeros_like(y_ref)
+
+        def add_row(r, carry):
+            s = i * bm + r
+            y_ref[pl.ds(tok_r[s], 1), :] += out_ref[pl.ds(r, 1), :] * g_r[s]
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(live_r[0] - i * bm, 0, bm), add_row,
+                          0)
+
+    def tile(j, i, live_r, tok_r, g_r):
+        return jnp.minimum(i, jnp.maximum(live_r[0] - 1, 0) // bm), j
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(d // bn, rp // bm),
+        in_specs=[pl.BlockSpec((bm, bn), tile)],
+        out_specs=pl.BlockSpec((T, bn), lambda j, i, *_: (0, j)))
+    with _enable_x64(False), jax.named_scope("pt_moe_combine"):
+        return pl.pallas_call(
+            kernel,
+            name="pt_moe_combine",
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((T, d), jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=KERNEL_VMEM_LIMIT_BYTES),
+            interpret=interpret,
+        )(live, tok, g, out)
 
 
 def _f_tile(f: int) -> int:

@@ -56,6 +56,7 @@ KERNELS = [
     "ssd.chunk_scan",
     "ssm.decode_update",
     "moe.stream_experts",
+    "moe.combine",
     "mla.paged_prefill",
     "mla.paged_decode",
     "gqa.paged_prefill",
@@ -503,7 +504,8 @@ def test_latent_programs_never_copy_the_pool(phase, c, one_chip,
         assert names == {"pt_mla_paged_decode", "pt_moe_stream_experts",
                          "pt_stream_linear_bf16"}
     else:
-        assert names == {"pt_mla_paged_prefill", "pt_grouped_gemm_fwd"}
+        assert names == {"pt_mla_paged_prefill", "pt_grouped_gemm_fwd",
+                         "pt_moe_combine"}
     size = 2 * math.prod(pool.shape)                # bf16 bytes
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < size // 4, mem.temp_size_in_bytes
@@ -643,8 +645,53 @@ def test_mixed_programs_never_copy_the_pool_or_the_ring(phase, c, one_chip,
                          "pt_moe_stream_experts", "pt_stream_linear_bf16"}
     else:
         assert names == {"pt_gqa_paged_prefill", "pt_swa_prefill",
-                         "pt_grouped_gemm_fwd"}
+                         "pt_grouped_gemm_fwd", "pt_moe_combine"}
     sizes = [2 * math.prod(a.shape) for a in (pool, *ring)]
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < sizes[0] // 4, mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes == sum(sizes)
+
+
+#: the combine's shapes in the three pattern-built cells: T x top-k sorted
+#: rows of d 4,096 into T tokens (MiMo-V2-Flash, Mistral-Small-4, granite)
+_COMBINE_CELLS = {"mixed": (1024, 8), "longdoc": (1024, 4), "rag": (256, 10)}
+
+
+@pytest.mark.parametrize("cell", sorted(_COMBINE_CELLS))
+def test_moe_combine_compiles_at_the_cells_shapes(cell, one_chip,
+                                                  as_on_chip):
+    """``pt_moe_combine`` at each cell's prefill chunk compiles for the
+    chip as one launch; its blocks (the resident ``[T, d]`` result and a
+    128-row tile, each in two buffers) stay under
+    ``KERNEL_VMEM_LIMIT_BYTES``, the whole width in one column block."""
+    from paddle_tpu.device.vmem import KERNEL_VMEM_LIMIT_BYTES
+    from paddle_tpu.nn.functional.moe_gated import _combine_bn, moe_combine
+
+    T, k = _COMBINE_CELLS[cell]
+    d = 4096
+    assert _combine_bn(T, d) == d
+    assert 2 * (T * d * 4 + 128 * d * 4) < KERNEL_VMEM_LIMIT_BYTES
+
+    def fn(out, g, tok, live):
+        return moe_combine(out, g, tok, live, T)
+
+    args = (_sds((T * k, d), jnp.float32), _sds((T * k,), jnp.float32),
+            _sds((T * k,), jnp.int32), _sds((1,), jnp.int32))
+    text = jax.jit(fn).lower(*_placed(args, one_chip)).compile().as_text()
+    assert _kernel_names(text) == {"pt_moe_combine"}
+
+
+def test_mixed_prefill_combines_no_pick_array(one_chip, as_on_chip):
+    """MiMo-V2-Flash's prefill program at c 1,024 (8,192 picks a chunk):
+    the only ``f32[8192,4096]`` arrays are the two grouped GEMMs' outputs
+    (and the SiLU fusion's read of the first): no fusion fills, multiplies
+    or sums a pick-shaped array and no scatter writes float rows. The XLA
+    combine that scatters every pick (``backend="xla"``) compiles to seven
+    such fusions and scatters."""
+    jitted, args, _, _ = _mixed_program("prefill", 1024)
+    text = jitted.lower(*_placed(args, one_chip)).compile().as_text()
+    picks = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= f32\[8192,4096\]\S* (fusion|scatter)\(", line)]
+    assert not picks, picks
+    assert not re.search(r"= f\d+\[[\d,]+\]\S* scatter\(", text)
+    assert len(re.findall(r"= f32\[8192,4096\]\S* custom-call\(", text)) == 2

@@ -184,3 +184,154 @@ class TestPrefillChunkShape:
 
         found = set(names(closed.jaxpr))
         assert "sort" in found and "scatter-add" not in found
+
+    def test_the_interpreted_kernels_equal_the_dense_per_expert_reference(
+            self):
+        """The chip's path, interpreted: both grouped GEMMs and the combine
+        (``pt_moe_combine``) as Pallas kernels."""
+        x, gates, idx, w1, w2 = self._layer(2)
+        got, _ = mg.moe_gated_grouped(x, gates, idx, w1, w2, 1, self.HELD,
+                                      backend="interpret")
+        want, _ = mg.moe_gated_grouped(x, gates, idx, w1, w2, 1, self.HELD,
+                                       backend="xla")
+        xs, g, ix = (np.asarray(a) for a in (x, gates, idx))
+        dense = np.zeros((self.T, self.D), np.float32)
+        for e in range(self.HELD[1]):
+            t, slot = np.nonzero(ix == e)
+            ab = xs[t] @ np.asarray(w1[1, e])
+            a, b = ab[:, :self.F], ab[:, self.F:]
+            np.add.at(dense, t, g[t, slot][:, None]
+                      * (((a / (1 + np.exp(-a))) * b) @ np.asarray(w2[1, e])))
+        np.testing.assert_allclose(got, dense, atol=2e-4)
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+def _sorted_picks(idx, gates, held):
+    """What ``moe_gated_grouped`` hands its combine: the picks sorted by
+    held expert (absent ones last), their gates, their tokens and the
+    number of live rows, ``offsets[E]``."""
+    first, count = held
+    k = idx.shape[1]
+    local = idx - first
+    here = (local >= 0) & (local < count)
+    key = jnp.where(here, local, count).reshape(-1)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    g = jnp.where(here, gates, 0.0).reshape(-1)[order]
+    live = jnp.sum(here, dtype=jnp.int32).reshape(1)
+    return order, g, order // k, live
+
+
+def _xla_combine(out, g, order, T, k):
+    """Today's XLA combine, as ``backend="xla"`` keeps it."""
+    y = jnp.zeros((T * k, out.shape[1]), jnp.float32).at[order].set(
+        out * g[:, None])
+    return jnp.sum(y.reshape(T, k, -1), axis=1)
+
+
+class TestCombine:
+    """``pt_moe_combine`` through the interpreter against today's XLA
+    combine, at the three hybrid cells' prefill routing shapes (their T x
+    k, top-k and share of held experts; d cut to 256)."""
+
+    CELLS = {"mixed": (1024, 8, 256, (0, 16)),
+             "longdoc": (1024, 4, 128, (0, 32)),
+             "rag": (256, 10, 72, (0, 36))}
+    D = 256
+
+    def _picks(self, T, k, E, seed):
+        rng = np.random.RandomState(seed)
+        idx = jnp.asarray(np.argsort(rng.rand(T, E), axis=1)[:, :k],
+                          jnp.int32)
+        gates = jnp.asarray(rng.rand(T, k), jnp.float32)
+        return idx, gates
+
+    def _out(self, T, k, live, seed):
+        """The second GEMM's rows: rows past ``live`` are exact zeros, as
+        the grouped GEMM leaves them."""
+        rng = np.random.RandomState(seed)
+        out = rng.randn(T * k, self.D).astype(np.float32)
+        out[live:] = 0.0
+        return jnp.asarray(out)
+
+    def _check(self, idx, gates, held, seed=0):
+        T, k = idx.shape
+        order, g, tok, live = _sorted_picks(idx, gates, held)
+        out = self._out(T, k, int(live[0]), seed)
+        got = mg.moe_combine(out, g, tok, live, T, interpret=True)
+        want = _xla_combine(out, g, order, T, k)
+        np.testing.assert_allclose(
+            got, want, rtol=1e-6, atol=1e-6 * float(jnp.abs(out).max()))
+        return got, int(live[0])
+
+    @pytest.mark.parametrize("cell", sorted(CELLS))
+    def test_equals_the_xla_combine_at_the_cells_shapes(self, cell):
+        T, k, E, held = self.CELLS[cell]
+        idx, gates = self._picks(T, k, E, seed=5)
+        _, live = self._check(idx, gates, held)
+        share = held[1] / E
+        assert 0.7 * share < live / (T * k) < 1.3 * share
+
+    def test_a_tail_chunk_whose_picks_do_not_fill_a_tile(self):
+        """A 16-token tail (the engine's default bucket) at top-10: 160
+        sorted rows, padded to two tiles of 128 for the walk."""
+        idx, gates = self._picks(16, 10, 72, seed=9)
+        self._check(idx, gates, (0, 36))
+
+    def test_no_pick_held_reads_exact_zeros(self):
+        idx, gates = self._picks(256, 10, 72, seed=1)
+        got, live = self._check(idx + 72, gates, (0, 36))
+        assert live == 0
+        np.testing.assert_array_equal(got, np.zeros((256, self.D)))
+
+    def test_every_pick_held(self):
+        idx, gates = self._picks(256, 10, 72, seed=2)
+        _, live = self._check(idx, gates, (0, 72))
+        assert live == 256 * 10
+
+    def test_one_expert_takes_every_live_pick(self):
+        idx, gates = self._picks(1024, 8, 256, seed=3)
+        # expert 5 in slot 0 of every token; no other held expert picked
+        idx = jnp.where(idx < 16, idx + 16, idx).at[:, 0].set(5)
+        _, live = self._check(idx, gates, (0, 16))
+        assert live == 1024
+
+    def test_live_rows_end_on_a_tile_boundary(self):
+        idx, gates = self._picks(1024, 4, 128, seed=4)
+        # exactly 256 picks held: slot 0 of tokens 0..255, none elsewhere
+        idx = jnp.where(idx < 32, idx + 32, idx)
+        idx = idx.at[:256, 0].set(jnp.arange(256, dtype=jnp.int32) % 32)
+        _, live = self._check(idx, gates, (0, 32))
+        assert live == 256 and live % 128 == 0
+
+    @pytest.mark.parametrize("cell", ["mixed", "rag"])
+    def test_rows_past_the_live_ones_are_never_read(self, cell):
+        """``out``'s rows at and past ``offsets[E]``, and whole tiles past
+        the last live one, hold NaN: the kernel's result is the clean
+        run's, bit for bit. Today's combine multiplies them by a zero gate
+        and returns NaN."""
+        T, k, E, held = self.CELLS[cell]
+        idx, gates = self._picks(T, k, E, seed=6)
+        order, g, tok, live = _sorted_picks(idx, gates, held)
+        clean = self._out(T, k, int(live[0]), seed=7)
+        dirty = clean.at[int(live[0]):].set(jnp.nan)
+        assert (T * k) // 128 > -(-int(live[0]) // 128)  # dead tiles exist
+        np.testing.assert_array_equal(
+            mg.moe_combine(dirty, g, tok, live, T, interpret=True),
+            mg.moe_combine(clean, g, tok, live, T, interpret=True))
+        assert bool(jnp.isnan(_xla_combine(dirty, g, order, T, k)).any())
+
+    @pytest.mark.parametrize("cell", sorted(CELLS))
+    def test_rows_accumulated_equal_the_picks_here_counter(self, cell):
+        """With every row and gate 1.0 the result counts, row by row, the
+        rows the kernel added: in all, exactly the picks held here that
+        ``pick_counts`` reports (``serving.moe.picks_here``)."""
+        T, k, E, held = self.CELLS[cell]
+        idx, gates = self._picks(T, k, E, seed=8)
+        _, _, tok, live = _sorted_picks(idx, gates, held)
+        ones = jnp.ones((T * k, self.D), jnp.float32)
+        got = mg.moe_combine(ones, jnp.ones((T * k,), jnp.float32), tok,
+                             live, T, interpret=True)
+        counts = mg.pick_counts(idx, jnp.ones((T,), bool), held)
+        assert float(jnp.sum(got[:, 0])) == int(counts[1]) == int(live[0])
+        np.testing.assert_array_equal(got, jnp.broadcast_to(
+            got[:, :1], got.shape))

@@ -110,7 +110,7 @@ class TestRepoIsClean:
 
 class TestKernelSites:
     def test_all_sites_dry_trace(self):
-        assert len(KERNEL_SITES) == 22
+        assert len(KERNEL_SITES) == 23
         for site in KERNEL_SITES:
             records = trace_site(site)
             assert len(records) == site.n_calls
