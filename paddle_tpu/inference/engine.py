@@ -929,9 +929,6 @@ class ContinuousBatchingEngine:
             self._pages_per_seq, allow_missing=True)
         _stats.inc("serving.decode_steps", k)
         self._gen._count_a8w8(k)
-        _stats.set_gauge("serving.kv_pages_in_use",
-                         self._mgr.num_pages - self._mgr.free_pages)
-        _stats.set_gauge("serving.active_slots", len(active))
         if self._gen._tp is not None:
             # survives post-warmup stats.reset() in the benches
             _stats.set_gauge("dist.mp_degree", self._gen._tp.mp)

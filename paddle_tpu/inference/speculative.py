@@ -486,9 +486,6 @@ class SpeculativeDecoder:
         tables = mgr.block_tables(
             [("slot", i) for i in range(B)], eng._pages_per_seq,
             allow_missing=True)
-        _stats.set_gauge("serving.kv_pages_in_use",
-                         mgr.num_pages - mgr.free_pages)
-        _stats.set_gauge("serving.active_slots", len(active))
         # re-stamped per round: benches reset the registry after
         # warmup, and the window size must survive into telemetry
         _stats.set_gauge("spec.k", k)

@@ -201,9 +201,10 @@ def _format_rates(name, flops_s, mfu, bytes_s, bw_util) -> str:
 def analyze(name: str, wall_s: float, *, calls: int = 1,
             device=None) -> Optional[RooflineResult]:
     """Turn a measured wall time for ``calls`` executions of a recorded
-    program into achieved rates; publishes the ``roofline.*`` gauges
-    (achieved_flops_per_s, achieved_bytes_per_s, mfu, bw_util for the
-    most recently analyzed program) and updates the per-program table.
+    program into achieved rates; publishes the ``roofline.mfu`` and
+    ``roofline.bw_util`` gauges (the most recently analyzed program,
+    where the chip's peaks are known) and updates the per-program table
+    with the achieved rates.
     Returns None when the program was never recorded or timing is
     degenerate."""
     entry = _PROGRAMS.get(name)
@@ -214,10 +215,6 @@ def analyze(name: str, wall_s: float, *, calls: int = 1,
     res = RooflineResult(name, entry["flops"], entry["bytes"],
                          per_call, peak_flops, peak_bw)
     entry.update(res.as_dict())
-    _stats.set_gauge("roofline.achieved_flops_per_s",
-                     res.achieved_flops_per_s)
-    _stats.set_gauge("roofline.achieved_bytes_per_s",
-                     res.achieved_bytes_per_s)
     if res.mfu is not None:
         _stats.set_gauge("roofline.mfu", res.mfu)
         _stats.set_gauge("roofline.bw_util", res.bw_util)

@@ -88,8 +88,8 @@ Conventions for the built-in instrumentation (all optional reading):
   (serving/host_tier.py): ``tier.host_{pages,bytes,
   capacity_bytes}``, summed over every engine's tier in the
   process — the serve_top fleet tier view's source
-- ``roofline.*``               achieved FLOP/s / bytes/s / MFU / BW
-  utilization vs device peaks (profiler/roofline.py)
+- ``roofline.*``               MFU / BW utilization vs device peaks
+  (profiler/roofline.py; the achieved rates are in its table)
 - ``hbm.*``                    device memory telemetry
   (profiler/memory.py)
 - ``serve.step.*_ms``          per-step serving-time ATTRIBUTION

@@ -15,6 +15,11 @@ child they start: a test run must not depend on what an earlier run
 left in ``.jax_cache``, nor fill the checkout with it (and XLA:CPU
 reloads its cached executables with a screen of machine-feature errors).
 
+The run is held to a wall-clock limit, so two things here only make it
+shorter and change no result: CPU programs compile at LLVM's lowest
+optimisation level (about a quarter less CPU over the suite), and under
+``--dist loadfile`` the longest files are handed out first.
+
 Shared mesh fixtures (session-scoped — the mesh objects are immutable
 value types): ``virtual_devices`` (the 8 CPU devices), ``mesh8`` /
 ``mesh2x4`` (plain ProcessMeshes) and ``fleet_mesh`` (the dp4 x mp2
@@ -36,6 +41,29 @@ jax.config.update("jax_platforms", "cpu")
 import pytest  # noqa: E402
 
 
+def _cpu_compiles_without_llvm_optimisation():
+    """Compile for the CPU at LLVM's lowest level: the tests' programs are
+    small and run a few times each, so the CPU spends most of the run
+    compiling them. XLA's HLO passes run as before; compiles for a
+    described TPU (tests/test_chip_compile.py) keep their options."""
+    from jax._src import compiler
+
+    compile_and_load = compiler.backend_compile_and_load
+
+    def backend_compile_and_load(backend, module, executable_devices,
+                                 options, host_callbacks):
+        if backend.platform == "cpu":
+            options.executable_build_options.debug_options \
+                .xla_backend_optimization_level = 0
+        return compile_and_load(backend, module, executable_devices,
+                                options, host_callbacks)
+
+    compiler.backend_compile_and_load = backend_compile_and_load
+
+
+_cpu_compiles_without_llvm_optimisation()
+
+
 def pytest_configure(config):
     # registered markers (no pytest.ini in this repo): ``slow`` is
     # excluded from tier-1 (`-m 'not slow'`); ``chaos`` tags the
@@ -50,6 +78,76 @@ def pytest_configure(config):
         "markers", "chaos: deterministic fault-injection serving "
                    "tests (ISSUE 11) — in tier-1, selectable alone "
                    "via -m chaos")
+
+
+#: Test files that take the longest on CPU (each over 60 s of test time
+#: with six xdist workers), longest first. ``--dist loadfile`` runs a file
+#: on one worker, and xdist queues files by test count, so a long file
+#: with few tests would start last and run on alone long after the other
+#: workers have finished; handing these out first lets the short files
+#: fill in around them.
+HEAVY_TEST_FILES = (
+    "tests/test_vision_models.py",
+    "tests/test_pipeline.py",
+    "tests/benchmark/test_granite_reference.py",
+    "tests/test_chip_compile.py",
+    "tests/test_serving_frontend.py",
+    "tests/test_paged_backends.py",
+    "tests/benchmark/test_mimo_rehearsal.py",
+    "tests/test_hybrid_serving.py",
+    "tests/benchmark/test_mimo_reference.py",
+    "tests/benchmark/test_harness_rehearsal.py",
+    "tests/benchmark/test_train_reference.py",
+    "tests/test_serving_continuous.py",
+    "tests/benchmark/test_granite_rehearsal.py",
+    "tests/test_lora_serving.py",
+    "tests/test_window_serving.py",
+    "tests/test_tpu_lint.py",
+    "tests/test_bert.py",
+    "tests/benchmark/test_mistral4_rehearsal.py",
+    "tests/test_paged_kv_write.py",
+    "tests/test_serving_faults.py",
+    "tests/test_flash_varlen.py",
+    "tests/test_speculative.py",
+    "tests/test_latent_serving.py",
+    "tests/benchmark/test_mistral4_reference.py",
+    "tests/test_usage_metering.py",
+    "tests/test_fleet_router.py",
+    "tests/test_jit.py",
+    "tests/test_window_attention.py",
+    "tests/test_chip_smoke.py",
+    "tests/test_nn.py",
+    "tests/test_mla_attention.py",
+    "tests/test_moe_nodrop.py",
+    "tests/benchmark/test_span_readers.py",
+    "tests/test_hybrid_pp_mp.py",
+    "tests/test_parallelism.py",
+)
+
+
+@pytest.hookimpl(optionalhook=True, tryfirst=True)
+def pytest_xdist_make_scheduler(config, log):
+    """Under ``--dist loadfile``, hand out ``HEAVY_TEST_FILES`` before the
+    rest; every other file keeps xdist's own order."""
+    if config.getvalue("dist") != "loadfile":
+        return None
+    from collections import OrderedDict
+
+    from xdist.scheduler import LoadFileScheduling
+
+    rank = {f: i for i, f in enumerate(HEAVY_TEST_FILES)}
+
+    class HeavyFirst(LoadFileScheduling):
+        def _assign_work_unit(self, node):
+            if not getattr(self, "_heavy_first", False):
+                self._heavy_first = True
+                order = sorted(self.workqueue,
+                               key=lambda f: rank.get(f, len(rank)))
+                self.workqueue = OrderedDict(
+                    (f, self.workqueue[f]) for f in order)
+            super()._assign_work_unit(node)
+
+    return HeavyFirst(config, log)
 
 
 @pytest.fixture(autouse=True)
