@@ -893,6 +893,14 @@ class ContinuousBatchingEngine:
             _stats.inc("serving.moe." + name, int(n))
         return first
 
+    def _settle(self, out):
+        """Wait for a program whose first result nobody reads: a
+        pattern-built model's pick counts still reach the counters."""
+        if self._pattern_built:
+            self._fetch(out)
+        else:
+            jax.block_until_ready(out)
+
     def _plan_decode(self, k: int):
         """The host's work before a decode chunk's program call: page
         growth (which may evict or preempt), block tables, operands.

@@ -16,6 +16,9 @@ per-site hit counter advancing once per call):
 - ``prefill.dispatch`` — one chunk-prefill program dispatch
   (``serving/scheduler.py``; ``corrupt`` specs poke the chunk's
   emitted token);
+- ``prefill.wait`` — the wait for a prefill chunk that was not its
+  prompt's last (``serving/scheduler.py``; a raise is charged to that
+  chunk's request, whichever step waited);
 - ``decode.step`` — one continuous-batching decode chunk
   (``inference/engine.py``; ``corrupt`` specs poke the token matrix
   BEFORE any request state mutates, so detection → retry is clean);
@@ -253,8 +256,9 @@ def now() -> float:
 #: the named-site vocabulary (sites outside it still work — the list
 #: documents what the stack wires today)
 FAULT_SITES = ("kv.alloc", "kv.grow", "prefill.dispatch",
-               "decode.step", "prefix.insert", "journal.dump",
-               "router.dispatch", "replica.step", "replica.heartbeat")
+               "prefill.wait", "decode.step", "prefix.insert",
+               "journal.dump", "router.dispatch", "replica.step",
+               "replica.heartbeat")
 
 _KINDS = ("raise", "delay", "corrupt", "squeeze", "release", "kill",
           "hang")

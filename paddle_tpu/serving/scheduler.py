@@ -7,7 +7,9 @@ PREFILL with grouped decode — a 4k-token prompt fills the paged pool
 in fixed-size chunks BETWEEN decode chunks, so admitting it never
 stalls the decode batch for its whole prompt length — plus prefix/KV
 reuse (serving/prefix_cache.py) so requests sharing a system prompt
-map the prefix's pages instead of recomputing them.
+map the prefix's pages instead of recomputing them. Only a prompt's
+last chunk is waited for; the next dispatch waits for any other, so
+the host plans that step while the device still runs the chunk.
 
 Scheduling policy (``SLOConfig``): admission uses the engine's bounded
 skip-ahead (head-of-line fix) ordered by request priority; the
@@ -25,8 +27,8 @@ Telemetry (the PR 1–2 stats/roofline stack): per-request
 ``serve.{ttft_ms,tpot_ms,queue_wait_ms}`` histograms,
 ``serving.prefix_{hit,miss,pages_saved}`` + chunk counters, and every
 scheduler phase reports under its own roofline rung —
-``serve.prefill[c=N]`` per chunk size (honest post-sync timing) next
-to the engine's ``decode.*[k=N]`` rungs.
+``serve.prefill[c=N]`` per chunk size (honest post-sync timing of a
+prompt's last chunk) next to the engine's ``decode.*[k=N]`` rungs.
 
 Observability (PR 9): every lifecycle transition additionally lands in
 the FLIGHT RECORDER (``serving/journal.py``, ``FLAGS_serve_journal``)
@@ -299,6 +301,9 @@ class ServingEngine(ContinuousBatchingEngine):
         # budget is spent)
         self._admitting = None            # (req, slot) mid-_admit_into
         self._prefill_active = None       # (req, slot) mid-chunk
+        # (req, slot, result) of the one prefill chunk the host did not
+        # wait for (not its prompt's last): the next dispatch waits for it
+        self._deferred = None
         self._decode_retries = 0
         # fault injection (serving/faults.py): installed on the engine,
         # the page manager (kv.alloc/kv.grow sites + squeeze target)
@@ -527,6 +532,7 @@ class ServingEngine(ContinuousBatchingEngine):
         prefilling = [self._prefilling[i].req
                       for i in sorted(self._prefilling)]
         self._prefilling.clear()
+        self._deferred = None
         decoding = [r for r in self._slots if r is not None]
         self._slots = [None] * self.max_batch
         self._lens[:] = 0
@@ -593,7 +599,7 @@ class ServingEngine(ContinuousBatchingEngine):
             except Exception as e:
                 return self._recover_prefill(e)
             tgt, self._prefill_active = self._prefill_active, None
-            if tgt is not None:
+            if tgt is not None and self._deferred is None:
                 tgt[0].n_retries = 0  # chunk landed — budget restored
             ts_work = _faults.now()
             u = self.usage
@@ -662,8 +668,9 @@ class ServingEngine(ContinuousBatchingEngine):
         observes, finish hooks). The work phase splits once more, at
         the two stamps its program call left in ``_run_ts``: plan
         (action picked .. operands built), run (the program call until
-        its token fetch returned: the only interval in which the
-        device has this step's work) and emit (tokens -> requests,
+        its token fetch returned, or, for a chunk not waited for, until
+        the chunk deferred before it was: the host's waits on the
+        device all fall here) and emit (tokens -> requests,
         callbacks, finish hooks, page release); a work phase that
         never reached its program (a stalled chunk) is all plan.
         admit + plan + run + emit + host_overhead == total EXACTLY
@@ -760,6 +767,7 @@ class ServingEngine(ContinuousBatchingEngine):
     def _drop_prefill_slot(self, i: int):
         """Vacate prefill slot ``i`` and free its pages (no requeue —
         the caller decides the request's fate)."""
+        self._settle_deferred(i)
         stt = self._prefilling.pop(i, None)
         self._mgr.recurrent_free(i)
         if ("prefill", i) in self._mgr._owned:
@@ -879,6 +887,18 @@ class ServingEngine(ContinuousBatchingEngine):
             self._release(i)
         self._fail_request(req, i, "error", e)
         return []
+
+    def _recover_deferred(self, e: Exception, req, i: int):
+        """An exception while a deferred chunk was waited for: charged
+        to that chunk's request (``req``, still prefilling on slot
+        ``i``), not to the step that waited. Its prompt restarts from
+        the queue, as the pool and state the chunk wrote are not
+        trusted, or, the retry budget spent, it errors out."""
+        if self._note_retry(req, i, e, "prefill"):
+            self._requeue_prefill(i)
+        else:
+            self._drop_prefill_slot(i)
+            self._fail_request(req, i, "error", e)
 
     def _recover_decode(self, e: Exception):
         """An exception inside the decode chunk: not attributable to
@@ -1457,9 +1477,8 @@ class ServingEngine(ContinuousBatchingEngine):
                           adapter_slots=None, adapter_banks=None):
         """Compiled chunk program: prefill ``ids`` at positions
         ``start..`` against the cached prefix + in-chunk causal
-        triangle, returning the last VALID position's logits (used only
-        by the final chunk — one [1, d] @ [d, vocab] head matmul per
-        chunk buys an honest per-chunk device sync). With adapter
+        triangle, returning the last VALID position's logits (read
+        only on the prompt's final chunk). With adapter
         operands set, every projection adds its ragged grouped LoRA
         delta (one launch per projection per layer)."""
         g = self._gen
@@ -1479,30 +1498,80 @@ class ServingEngine(ContinuousBatchingEngine):
     def _prefill_step(self):
         """Run ONE prefill chunk for the most urgent prefilling slot;
         on prompt completion the request joins the decode batch with
-        its first token emitted. Returns requests finished this step
+        its first token emitted. A chunk that is not its prompt's last
+        is not waited for: nothing reads its token, so its result is
+        kept (``_deferred``) and the next program's dispatch waits for
+        it, while the host admits, plans and dispatches that program
+        with the device still busy. Returns requests finished this step
         (a one-token request can finish straight out of prefill)."""
         with RecordEvent("serve.plan"):
             plan = self._plan_prefill()
         if plan is None:
             return []
         i, stt, c, n, program, lead, tail = plan
+        last = self._last_chunk(stt, n)
+        tok = None
         t_run0 = self._now()
         with RecordEvent("serve.run", program=program.name,
                          rid=stt.req.id):
             t0 = time.perf_counter()
             out = self._run_program(program, lead, tail)
-            if not self._pattern_built:
+            # the wait for the chunk deferred before this one may have
+            # sent this request back to the queue or failed it
+            alive = self._prefilling.get(i) is stt
+            if alive and not last:
+                self._deferred = (stt.req, i, out)
+                _stats.inc("serving.prefill.deferred")
+            elif alive and not self._pattern_built:
                 tok = int(np.asarray(
                     self._gen._argmax(jnp.asarray(out)))[0])
-            else:
+            elif alive:
                 # a pattern-built model's chunk program picks the token
                 # itself and returns it with its pick counts
                 tok = int(self._fetch(out)[0])
         self._run_ts = (t_run0, self._now())
-        # the argmax fetch synced the chunk — honest phase roofline
-        _roofline.analyze(program.name, time.perf_counter() - t0)
+        if not alive:
+            self._prefill_active = None
+            return []
+        if last:
+            # the fetch synced the chunk — honest phase roofline
+            _roofline.analyze(program.name, time.perf_counter() - t0)
         with RecordEvent("serve.emit"):
             return self._emit_prefill(i, stt, c, n, tok)
+
+    def _last_chunk(self, stt, n: int) -> bool:
+        """Whether a chunk of ``n`` real tokens completes ``stt``'s
+        prompt: only that chunk's token is ever read."""
+        return stt.pos + n >= len(stt.tokens)
+
+    def _run_program(self, program, lead, tail):
+        """Dispatch, then wait for the chunk deferred before this
+        program: the device goes from that one straight to this one."""
+        out = super()._run_program(program, lead, tail)
+        self._settle_deferred()
+        return out
+
+    def _settle_deferred(self, slot: Optional[int] = None):
+        """Wait for the deferred prefill chunk (only where it is
+        ``slot``'s, if given): its pick counts reach the counters, and
+        a fault the wait raises is charged to the chunk's own request.
+        A slot that leaves prefill settles its own chunk first; a fault
+        there is moot, as the caller decides the request's fate."""
+        d = self._deferred
+        if d is None or slot not in (None, d[1]):
+            return
+        self._deferred = None
+        req, i, out = d
+        try:
+            fi = self.faults
+            if fi is not None:
+                fi.fire("prefill.wait", rid=req.id)
+            self._settle(out)
+        except Exception as e:
+            if slot is None:
+                self._recover_deferred(e, req, i)
+            return
+        req.n_retries = 0  # chunk landed — budget restored
 
     def _plan_prefill(self):
         """The host's work before a prefill chunk's program call: pick
@@ -1634,21 +1703,24 @@ class ServingEngine(ContinuousBatchingEngine):
         """The chunk's token -> its request: corruption check, journal,
         and on prompt completion the handoff to the decode batch with
         the first token emitted (``on_token``, finish hook, page
-        release for a one-token request). Returns the requests that
-        finished."""
+        release for a one-token request). ``tok`` is None for a chunk
+        that was not waited for (not its prompt's last). Returns the
+        requests that finished."""
         req, toks, L = stt.req, stt.tokens, len(stt.tokens)
         key = ("prefill", i)
-        fi = self.faults
-        if fi is not None:
-            tok = fi.corrupt("prefill.dispatch", tok)
-        if not 0 <= tok < self.model.vocab_size:
-            # corrupt-and-DETECT: the poisoned token never reaches the
-            # request's stream; the raise happens before any host-side
-            # mutation, so the crash-isolated retry re-runs this chunk
-            # cleanly (same KV pages rewritten with identical values)
-            raise TokenCorruption(
-                f"prefill chunk for request {req.id} produced token "
-                f"{tok} outside [0, {self.model.vocab_size})")
+        if tok is not None:
+            fi = self.faults
+            if fi is not None:
+                tok = fi.corrupt("prefill.dispatch", tok)
+            if not 0 <= tok < self.model.vocab_size:
+                # corrupt-and-DETECT: the poisoned token never reaches
+                # the request's stream; the raise happens before any
+                # host-side mutation, so the crash-isolated retry
+                # re-runs this chunk cleanly (same KV pages rewritten
+                # with identical values)
+                raise TokenCorruption(
+                    f"prefill chunk for request {req.id} produced token "
+                    f"{tok} outside [0, {self.model.vocab_size})")
         _stats.inc("serve.prefill_chunks")
         _stats.inc("serve.prefill_tokens", n)
         self._mgr.recurrent_landed(i)
@@ -1704,6 +1776,7 @@ class ServingEngine(ContinuousBatchingEngine):
         freeing its pages (its _resume_tokens, if any, survive so a
         preempted request still resumes mid-stream). Progress is kept
         by the surviving prefilling slots, which can now grow."""
+        self._settle_deferred(i)
         stt = self._prefilling.pop(i)
         self._mgr.free(("prefill", i))
         self._mgr.recurrent_free(i)

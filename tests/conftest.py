@@ -112,6 +112,7 @@ HEAVY_TEST_FILES = (
     "tests/test_latent_serving.py",
     "tests/benchmark/test_mistral4_reference.py",
     "tests/test_usage_metering.py",
+    "tests/test_deferred_prefill.py",
     "tests/test_fleet_router.py",
     "tests/test_jit.py",
     "tests/test_window_attention.py",
